@@ -105,9 +105,9 @@ def run(argv: List[str]) -> int:
                      args.compare_l2)
         return 1
 
-    from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
+    from photon_ml_tpu.utils.runtime import init_runtime
 
-    enable_compilation_cache()
+    init_runtime(logger)
     model, task, index_maps, entity_indexes = _load_dir(args.model_dir)
 
     from photon_ml_tpu.models.game import (CompactRandomEffectModel,

@@ -216,9 +216,9 @@ def run(argv: List[str]) -> int:
         logger.error("--batch-size must be >= 1, got %d", args.batch_size)
         return 1
 
-    from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
+    from photon_ml_tpu.utils.runtime import init_runtime
 
-    enable_compilation_cache()
+    init_runtime(logger)
 
     if args.trace or args.trace_out:
         from photon_ml_tpu import obs

@@ -203,9 +203,9 @@ def run(argv: List[str]) -> int:
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(message)s")
     args = build_parser().parse_args(argv)
 
-    from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
+    from photon_ml_tpu.utils.runtime import init_runtime
 
-    enable_compilation_cache()
+    init_runtime(logger)
     if args.trace_out:
         from photon_ml_tpu import obs
 

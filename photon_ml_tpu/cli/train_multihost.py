@@ -135,14 +135,6 @@ def run(argv: Optional[List[str]] = None) -> int:
 
     import jax
 
-    # honor JAX_PLATFORMS even where site hooks pre-import jax (the env var
-    # is only read at import time, so on such hosts it would otherwise be
-    # silently ignored and the cluster would try to form on the site's
-    # default accelerator platform)
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
-
     from photon_ml_tpu.parallel import multihost as mh
 
     mh.initialize(coordinator_address=args.coordinator_address,

@@ -185,19 +185,12 @@ def score_compact_dense(w_idx: Array, w_val: Array, slots: Array,
     return jnp.where(slots >= 0, s, 0.0)
 
 
-def score_compact_sparse(w_idx: Array, w_val: Array, slots: Array,
-                         f_idx: Array, f_val: Array) -> Array:
+def score_compact_sparse_xla(w_idx: Array, w_val: Array, slots: Array,
+                             f_idx: Array, f_val: Array) -> Array:
     """Sparse-features x sparse-model margins: binary-search each sample
     feature id into its entity's sorted coefficient columns (miss -> 0).
-    Plain traceable math (see score_compact_dense).  On TPU the
-    searchsorted/take_along_axis chain is replaced by the pallas match-dot
-    kernel (ops/compact_score.py — same math, one VMEM pass, parity-tested
-    in interpret mode; PHOTON_COMPACT_DISABLE_PALLAS=1 escape hatch)."""
-    from photon_ml_tpu.ops import compact_score
-
-    if compact_score.eligible(w_idx.shape[1], f_idx.shape[1]):
-        return compact_score.score_sparse_compact(w_idx, w_val, slots,
-                                                  f_idx, f_val)
+    Plain traceable math (see score_compact_dense), and the reference the
+    pallas match-dot is checked against."""
     e = jnp.where(slots >= 0, slots, 0)
     rows_idx = w_idx[e]  # [n, k_model] sorted, padded with dim
     rows_val = w_val[e]
@@ -207,6 +200,21 @@ def score_compact_sparse(w_idx: Array, w_val: Array, slots: Array,
     wv = jnp.where(hit, jnp.take_along_axis(rows_val, pos_c, axis=1), 0.0)
     s = jnp.sum(f_val * wv, axis=1)
     return jnp.where(slots >= 0, s, 0.0)
+
+
+def score_compact_sparse(w_idx: Array, w_val: Array, slots: Array,
+                         f_idx: Array, f_val: Array) -> Array:
+    """``score_compact_sparse_xla``'s margins; on TPU, shapes inside the
+    kernel's gate take the pallas match-dot instead (ops/compact_score.py —
+    same math, one VMEM pass; PHOTON_COMPACT_DISABLE_PALLAS=1 escape
+    hatch)."""
+    from photon_ml_tpu.ops import compact_score
+
+    if compact_score.eligible(w_idx.shape[1], f_idx.shape[1],
+                              w_val.dtype.itemsize):
+        return compact_score.score_sparse_compact(w_idx, w_val, slots,
+                                                  f_idx, f_val)
+    return score_compact_sparse_xla(w_idx, w_val, slots, f_idx, f_val)
 
 
 _score_dense_compact = jax.jit(score_compact_dense)

@@ -1,12 +1,18 @@
 """Lazy g++ compilation of the native components.
 
-One .so per translation unit, cached next to the source with an mtime check.
+One .so per translation unit, cached next to the source under a name that
+carries a hash of the source's CONTENT (and of the build flags): a copy or a
+fresh clone of the tree rebuilds exactly when the source it holds differs
+from what the library was built from.  File times say nothing after a copy.
 No pybind11 in this image — C ABI + ctypes only (plain-C signatures keep the
 boundary trivially stable).
 """
 
 from __future__ import annotations
 
+import contextlib
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -22,21 +28,26 @@ _EXTRA = {"avro_loader": ["-lz"]}
 
 
 def library_path(name: str) -> str:
-    return os.path.join(_DIR, f"_lib{name}.so")
+    """native/_lib<name>.<source hash>.so for the source as it is now."""
+    h = hashlib.sha256(" ".join(_FLAGS + _EXTRA.get(name, [])).encode())
+    with open(os.path.join(_DIR, f"{name}.cpp"), "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, f"_lib{name}.{h.hexdigest()[:16]}.so")
 
 
 def compile_library(name: str, force: bool = False) -> Optional[str]:
-    """Compile native/<name>.cpp -> native/_lib<name>.so; None if unavailable.
+    """Compile native/<name>.cpp -> its ``library_path``; None if unavailable.
 
-    Rebuilds when the source is newer than the cached .so.  Compiles to a
-    temp file then renames (atomic on POSIX) so concurrent processes never
-    load a half-written library.
+    Reuses the library only when one built from this very source exists.
+    Compiles to a temp file then renames (atomic on POSIX) so concurrent
+    processes never load a half-written library; libraries of older sources
+    are removed.
     """
     src = os.path.join(_DIR, f"{name}.cpp")
-    out = library_path(name)
     if not os.path.exists(src):
         return None
-    if not force and os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
+    out = library_path(name)
+    if not force and os.path.exists(out):
         return out
     tmp = None
     try:
@@ -45,6 +56,10 @@ def compile_library(name: str, force: bool = False) -> Optional[str]:
         subprocess.run(["g++", *_FLAGS, "-o", tmp, src, *_EXTRA.get(name, [])],
                        check=True, capture_output=True, text=True)
         os.replace(tmp, out)
+        for stale in glob.glob(os.path.join(_DIR, f"_lib{name}*.so")):
+            if stale != out:
+                with contextlib.suppress(FileNotFoundError):  # a racing twin
+                    os.unlink(stale)
         return out
     except (subprocess.CalledProcessError, OSError) as e:
         # OSError covers both a missing g++ and an unwritable package dir —

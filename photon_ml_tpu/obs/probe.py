@@ -15,7 +15,7 @@ Instrumented sites:
   - ``utils/compile_cache.enable_compilation_cache`` — reports cache
     residency as a gauge (a disabled cache means every process pays full
     first-compiles; that should be visible, not inferred);
-  - ``utils/transfer.chunked_device_put`` — per-chunk transfer bytes;
+  - ``utils/transfer.device_put_counted`` — design-array transfer bytes;
   - ``utils/transfer.stream_device_put`` — streaming-ingest batch uploads
     (``site="stream_feed"``), the bench's ingest-bytes axis.
 

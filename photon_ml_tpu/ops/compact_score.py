@@ -36,19 +36,35 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from photon_ml_tpu.ops.fused_glm import has_tpu
+from photon_ml_tpu.ops.fused_glm import VMEM_BLOCK_BUDGET_BYTES, has_tpu
 
 Array = jax.Array
 
 _LANE = 128
+_MAX_BLOCK_LANES = 512
 _MAX_MATCH_WORK = 4096  # k_model * k_feat above this: keep the searchsorted
 # chain (the match-dot's elementwise work grows with the product while the
 # search grows with k_feat * log2(k_model))
+_MAX_FEAT_UNROLL = 512  # the k_feat loop unrolls statically: compile time is
+# linear in it (9 s at 1024 when compiled for a v5e)
 
 
-def eligible(k_model: int, k_feat: int, interpret: bool = False) -> bool:
+def _bytes_per_lane(k_model: int, k_feat: int, itemsize: int) -> int:
+    """Pipelined VMEM bytes per sample lane: an int32 id row and a value row
+    per coefficient and per feature slot (sublane axis padded to 8), twice
+    for double buffering — a (4096, 512) block pair asked for 32 MiB."""
+    rows = (-(-k_model // 8) + -(-k_feat // 8)) * 8
+    return 2 * rows * (4 + itemsize)
+
+
+def eligible(k_model: int, k_feat: int, itemsize: int = 4,
+             interpret: bool = False) -> bool:
     """True when the pallas match-dot can replace the searchsorted chain.
-    Callers (models/game._score_sparse_compact) keep the XLA path otherwise.
+    Callers (models/game.score_compact_sparse) keep the XLA path otherwise.
+
+    Shape rules: k_model * k_feat <= 4096 (match work), k_feat <= 512
+    (static unroll), and a 128-lane block of both operand pairs within the
+    VMEM block budget (k_model + k_feat <= ~4096 rows in f32).
 
     PHOTON_COMPACT_DISABLE_PALLAS=1 forces the XLA path everywhere — the
     bench's pallas-vs-XLA A/B knob (and an escape hatch)."""
@@ -56,9 +72,12 @@ def eligible(k_model: int, k_feat: int, interpret: bool = False) -> bool:
         return False
     if k_model < 1 or k_feat < 1 or k_model * k_feat > _MAX_MATCH_WORK:
         return False
-    if interpret:
-        return True
-    return has_tpu()
+    if k_feat > _MAX_FEAT_UNROLL:
+        return False
+    if _LANE * _bytes_per_lane(k_model, k_feat, itemsize) \
+            > VMEM_BLOCK_BUDGET_BYTES:
+        return False
+    return interpret or has_tpu()
 
 
 def _match_dot_kernel(k_feat: int, w_idx_ref, w_val_ref, f_idx_ref, f_val_ref,
@@ -97,10 +116,13 @@ def match_dot(rows_idx_t: Array, rows_val_t: Array, f_idx_t: Array,
     """
     k_model, n = rows_idx_t.shape
     k_feat = f_idx_t.shape[0]
-    if not eligible(k_model, k_feat, interpret):
+    itemsize = np.dtype(rows_val_t.dtype).itemsize
+    if not eligible(k_model, k_feat, itemsize, interpret):
         raise ValueError("compact_score.match_dot called on an ineligible "
                          "shape; gate on ops.compact_score.eligible()")
-    bl = block_lanes or min(512, max(_LANE, 1 << (max(n - 1, 0)).bit_length()))
+    fit = VMEM_BLOCK_BUDGET_BYTES // _bytes_per_lane(k_model, k_feat, itemsize)
+    bl = block_lanes or min(_MAX_BLOCK_LANES, fit,
+                            1 << (max(n - 1, 0)).bit_length())
     bl = max(_LANE, (bl // _LANE) * _LANE)
     n_pad = -(-max(n, 1) // bl) * bl
     args = (_pad_lanes(rows_idx_t, n_pad), _pad_lanes(rows_val_t, n_pad),
@@ -118,6 +140,7 @@ def match_dot(rows_idx_t: Array, rows_val_t: Array, f_idx_t: Array,
         out_specs=pl.BlockSpec((1, bl), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), rows_val_t.dtype),
         interpret=interpret,
+        name="compact_match_dot",
     )(*args)
     return out[0, :n]
 

@@ -37,14 +37,23 @@ _LANE = 128  # TPU lane width: last dim of X blocks must be a multiple
 
 @functools.cache
 def has_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    """Whether the default backend is a TPU.  A backend that fails to
+    initialise raises here — it must not read as "no TPU" and quietly route
+    every kernel to XLA."""
+    return jax.devices()[0].platform == "tpu"
 
 
-_MAX_DIM = 8192  # VMEM cap: the whole-array (_NACC, d) accumulator block plus
-# the double-buffered (block_rows, d) X tile must fit ~16MB/core.
+# Mosaic's default scoped VMEM is 16 MiB per core.  The lane-blocked kernels
+# (ops/soa_newton.py, ops/compact_score.py) budget half of it for their
+# pipelined (double-buffered) operand blocks; the rest is headroom for the
+# kernel's own temporaries.
+VMEM_BLOCK_BUDGET_BYTES = 8 << 20
+
+_MAX_ROW_BYTES = 16 << 10  # VMEM rule: one design row at storage width —
+# d <= 4096 in f32, 8192 in bf16.  Besides the double-buffered (128, d) X
+# tile and the (_NACC, d) accumulator, the (d, 1)/(d, 2) coefficient block
+# pads its lane axis to 128, so it costs as much as the X tile: compiling
+# d=8192 in f32 for a v5e asked for 23.9 MiB of the 16 MiB scoped VMEM.
 
 
 def _pick_block_rows(n: int, d: int, itemsize: int = 4,
@@ -209,9 +218,10 @@ def storage_narrowing_ok(x_dtype, w_dtype) -> bool:
 
 def eligible(batch, interpret: bool = False) -> bool:
     """True when the pallas kernel path can run: TPU present, lane-aligned
-    dim, and dim small enough that the (_NACC, d) accumulators + X tile fit
-    VMEM.  Callers (GLMObjective) use their plain-XLA path otherwise — the
-    kernels raise rather than silently duplicating that math here.
+    dim, and a design row within ``_MAX_ROW_BYTES`` so the X tile,
+    coefficient block and accumulators fit VMEM.  Callers (GLMObjective)
+    use their plain-XLA path otherwise — the kernels raise rather than
+    silently duplicating that math here.
 
     PHOTON_GLM_DISABLE_PALLAS=1 forces the plain-XLA path everywhere —
     the bench's pallas-vs-XLA A/B knob (and an escape hatch)."""
@@ -223,7 +233,9 @@ def eligible(batch, interpret: bool = False) -> bool:
         return False
     if interpret:
         return True
-    return has_tpu() and batch.dim % _LANE == 0 and batch.dim <= _MAX_DIM
+    row_bytes = batch.dim * np.dtype(batch.x.dtype).itemsize
+    return (has_tpu() and batch.dim % _LANE == 0
+            and row_bytes <= _MAX_ROW_BYTES)
 
 
 def fused_value_and_grad(
@@ -279,6 +291,7 @@ def fused_value_and_grad(
             jax.ShapeDtypeStruct((_NACC, d), acc),
         ],
         interpret=interpret,
+        name="fused_glm_value_grad",
     )(shift, w_eff.reshape(-1, 1), batch.x, yow)
     return jnp.sum(val), jnp.sum(grad, axis=0), jnp.sum(rsum)
 
@@ -336,5 +349,6 @@ def fused_hvp(
             jax.ShapeDtypeStruct((_NACC, 1), acc),
         ],
         interpret=interpret,
+        name="fused_glm_hvp",
     )(shift, vshift, wv, batch.x, yow)
     return jnp.sum(hv, axis=0), jnp.sum(qsum)

@@ -17,9 +17,9 @@ all 128 VPU lanes, and there is no dot_general anywhere (d <= 16 is far
 below the MXU's useful width; the VPU column products ARE the fast path).
 
 Gating follows ops/fused_glm.py: TPU-only (``eligible``), CPU correctness
-via ``interpret=True`` (tests) or the PHOTON_SOA_PALLAS_INTERPRET=1 env
-knob (drives the WHOLE solver through the kernel in interpret mode), and a
-PHOTON_SOA_DISABLE_PALLAS=1 escape hatch — also the bench's A/B knob.
+via the ``interpret=True`` arguments (tests only — no environment variable
+reaches interpret mode), and a PHOTON_SOA_DISABLE_PALLAS=1 escape hatch —
+also the bench's A/B knob.
 """
 
 from __future__ import annotations
@@ -34,46 +34,68 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from photon_ml_tpu.core.losses import PointwiseLoss
-from photon_ml_tpu.ops.fused_glm import has_tpu
+from photon_ml_tpu.ops.fused_glm import VMEM_BLOCK_BUDGET_BYTES, has_tpu
 
 Array = jax.Array
 
 _LANE = 128  # TPU lane width: lane blocks must be a multiple
+_MAX_DIM = 16  # the Hessian triangle and the Cholesky unroll statically:
+# O(d^3) straight-line ops (the SoA solver's own bound, opt/newton_soa.py)
 
-# VMEM budget for the design block (cap, d, BL): the SoA gate already bounds
-# cap*d^2/2 <= 1280 so cap*d <= 2560/d <= 640 at d>=4 — a 512-lane block is
-# ~1.3MB, comfortably inside ~16MB/core with double buffering.
-_X_BLOCK_BUDGET_BYTES = 4 << 20
-
-
-def interpret_forced() -> bool:
-    """CPU end-to-end testing knob: run the kernel in interpret mode inside
-    the real solver (slow — tests only)."""
-    return os.environ.get("PHOTON_SOA_PALLAS_INTERPRET") == "1"
+# (the block budget's headroom, measured: compiling an 8192-lane (32, 4) f32
+# block for a v5e asked for 28.8 MiB — 24 MiB of blocks + ~5 MiB of
+# temporaries)
 
 
-def eligible(d: int, num_lanes: int, interpret: bool = False) -> bool:
+def _ceil_to(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _bytes_per_lane(cap: int, d: int, itemsize: int) -> int:
+    """Pipelined VMEM bytes one lane of a block costs: every operand block
+    twice (double buffering), each with its second-minor axis padded to the
+    dtype's sublane tile (8 rows of 32 bits; 16 of bf16) — d=4 pads to 8 in
+    f32 and to 16 in bf16, which the logical cap*d*itemsize never showed."""
+    sub_x = 8 * max(1, 4 // itemsize)
+    x = cap * _ceil_to(d, sub_x) * itemsize          # (cap, d, BL) design
+    rows = 3 * _ceil_to(cap, 8) * 4                  # y, offset, weight
+    state = 3 * _ceil_to(d, 8) * 4 + 8 * 4           # w, g, out; l2 row
+    return 2 * (x + rows + state)
+
+
+def eligible(d: int, num_lanes: int, cap: int, itemsize: int = 4,
+             interpret: bool = False) -> bool:
     """True when the pallas Newton-step kernel can run.  Callers
     (opt/newton_soa.solve_newton_soa) keep the XLA path otherwise — the
     kernel raises rather than duplicating that math here.
+
+    Shape rules: lanes a multiple of 128, 1 <= d <= 16 (static unroll), and
+    the smallest (128-lane) block of a (cap, d) design at ``itemsize`` must
+    fit the VMEM block budget — cap <= 427 at d=16 in f32, 741 at d=4.
 
     PHOTON_SOA_DISABLE_PALLAS=1 forces the XLA path everywhere — the bench's
     pallas-vs-XLA A/B knob (and an escape hatch)."""
     if os.environ.get("PHOTON_SOA_DISABLE_PALLAS") == "1":
         return False
-    if d < 1 or num_lanes < 1 or num_lanes % _LANE != 0:
+    if not 1 <= d <= _MAX_DIM or cap < 1:
         return False
-    if interpret or interpret_forced():
-        return True
-    return has_tpu()
+    if num_lanes < 1 or num_lanes % _LANE != 0:
+        return False
+    if _LANE * _bytes_per_lane(cap, d, itemsize) > VMEM_BLOCK_BUDGET_BYTES:
+        return False
+    return interpret or has_tpu()
 
 
 def _pick_block_lanes(cap: int, d: int, num_lanes: int, itemsize: int) -> int:
-    """Largest 128-multiple block whose (cap, d, BL) design tile fits the
-    VMEM budget, capped at the lane count (which is already a multiple)."""
-    per_lane = max(1, cap * d * itemsize)
-    bl = max(_LANE, (_X_BLOCK_BUDGET_BYTES // per_lane // _LANE) * _LANE)
-    return int(min(bl, num_lanes))
+    """Largest 128-multiple block that DIVIDES the lane count and whose
+    pipelined operand blocks fit the VMEM budget.  128 always divides (the
+    gate), so any lane count the gate admits gets a block — 1152 lanes
+    take 1152 or 384, never a non-dividing 1024."""
+    units = num_lanes // _LANE
+    fit = max(1, VMEM_BLOCK_BUDGET_BYTES // _bytes_per_lane(cap, d, itemsize)
+              // _LANE)
+    k = next(k for k in range(min(fit, units), 0, -1) if units % k == 0)
+    return k * _LANE
 
 
 def _newton_step_kernel(loss: PointwiseLoss, d: int, eps: float,
@@ -154,11 +176,11 @@ def newton_step(loss: PointwiseLoss, w: Array, g: Array, x_t: Array,
     """
     d, num_l = w.shape
     cap = x_t.shape[0]
-    if not eligible(d, num_l, interpret):
+    itemsize = np.dtype(x_t.dtype).itemsize
+    if not eligible(d, num_l, cap, itemsize, interpret):
         raise ValueError("soa_newton.newton_step called on an ineligible "
                          "shape; gate on ops.soa_newton.eligible()")
-    bl = block_lanes or _pick_block_lanes(
-        cap, d, num_l, np.dtype(x_t.dtype).itemsize)
+    bl = block_lanes or _pick_block_lanes(cap, d, num_l, itemsize)
     if num_l % bl != 0:
         raise ValueError(f"block_lanes {bl} must divide num_lanes {num_l}")
     eps = float(np.finfo(np.dtype(w.dtype)).eps)
@@ -177,6 +199,7 @@ def newton_step(loss: PointwiseLoss, w: Array, g: Array, x_t: Array,
         ],
         out_specs=pl.BlockSpec((d, bl), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((d, num_l), w.dtype),
-        interpret=interpret or interpret_forced(),
+        interpret=interpret,
+        name="soa_newton_step",
     )(w, g, x_t, y_t, off_t, wt_t,
       jnp.broadcast_to(jnp.asarray(l2), (num_l,)).reshape(1, num_l))

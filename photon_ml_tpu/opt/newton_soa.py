@@ -149,13 +149,15 @@ def _hess(loss: PointwiseLoss, w, x_t, y_t, off_t, wt_t, l2):
 
 def solve_newton_soa(loss: PointwiseLoss, w0_t: Array, x_t: Array,
                      y_t: Array, off_t: Array, wt_t: Array, l2: Array,
-                     config: SolverConfig) -> SolverResult:
+                     config: SolverConfig,
+                     interpret: bool = False) -> SolverResult:
     """Per-lane Newton descent; all arrays lanes-last.
 
     w0_t: [d, L] start; x_t: [cap, d, L]; y/off/wt_t: [cap, L]; l2: [L]
     (per-lane traced regularization — lambda sweeps reuse the compile).
     Returns SolverResult with lanes-last ``w`` ([d, L]); the caller
-    transposes at its boundary.
+    transposes at its boundary.  ``interpret``: run the pallas step in
+    interpret mode on any backend (tests only).
     """
     d, num_l = w0_t.shape
     dtype = w0_t.dtype
@@ -168,7 +170,8 @@ def solve_newton_soa(loss: PointwiseLoss, w0_t: Array, x_t: Array,
     # parity-tested in interpret mode; PHOTON_SOA_DISABLE_PALLAS=1 escape).
     from photon_ml_tpu.ops import soa_newton
 
-    use_pallas = soa_newton.eligible(d, num_l)
+    use_pallas = soa_newton.eligible(d, num_l, x_t.shape[0],
+                                     x_t.dtype.itemsize, interpret)
 
     def gnorm(g):
         # L2 norm, matching the vmapped L-BFGS/TRON convergence inputs
@@ -195,7 +198,7 @@ def solve_newton_soa(loss: PointwiseLoss, w0_t: Array, x_t: Array,
         active = reason == 0
         if use_pallas:
             step = soa_newton.newton_step(loss, w, g, x_t, y_t, off_t,
-                                          wt_t, l2)
+                                          wt_t, l2, interpret=interpret)
         else:
             hh = _hess(loss, w, x_t, y_t, off_t, wt_t, l2)
             step = _cholesky_solve_soa(

@@ -19,10 +19,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from photon_ml_tpu.core.batch import Batch, DenseBatch, SparseBatch
-from photon_ml_tpu.parallel.compat import shard_map
 from photon_ml_tpu.core.objective import GLMObjective
 from photon_ml_tpu.opt.solve import make_solver
 from photon_ml_tpu.opt.types import SolverConfig, SolverResult
@@ -55,6 +55,14 @@ class ShardMapObjective:
     Presents the same (reg / value_and_grad / hvp) surface the solvers bind
     (opt/solve.make_solver), so it drops into any of them.  The batch must
     arrive sharded on the leading example axis (parallel/mesh.shard_batch).
+
+    ``value_and_grad`` and ``hvp`` — the two sites a pallas kernel can run
+    in — pass ``check_vma=False``: a ``pallas_call`` declares no
+    varying-manual-axes type for its outputs, and tracing one under the
+    check raises ("vma on jax.ShapeDtypeStruct must not be None"; in
+    interpret mode the interpreter's own loop carries fail it as well).
+    Every output of those sites is an explicit psum, so the replication
+    that the check would have verified holds by construction.
     """
 
     def __init__(self, objective: GLMObjective, mesh: Mesh, axis: str = DATA_AXIS):
@@ -84,7 +92,7 @@ class ShardMapObjective:
 
         rv, gr, rs = shard_map(
             local, mesh=self.mesh, in_specs=(P(), self._specs(batch)),
-            out_specs=(P(), P(), P()))(w, batch)
+            out_specs=(P(), P(), P()), check_vma=False)(w, batch)
         return obj.finish_value_and_grad(w, rv, gr, rs)
 
     def hvp(self, w: Array, batch: Batch, v: Array) -> Array:
@@ -95,7 +103,7 @@ class ShardMapObjective:
 
         hv, qs = shard_map(
             local, mesh=self.mesh, in_specs=(P(), self._specs(batch), P()),
-            out_specs=(P(), P()))(w, batch, v)
+            out_specs=(P(), P()), check_vma=False)(w, batch, v)
         return obj.finish_hvp(v, hv, qs)
 
     # Variance computation (opt/solve.compute_variances) needs the Hessian

@@ -85,20 +85,21 @@ def replicate(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def _pad_rows(a: np.ndarray, target: int) -> np.ndarray:
-    pad = target - a.shape[0]
+def _pad_axis(a, target: int, axis: int):
+    """Zero-pad ``axis`` up to ``target`` where the array lives: a host array
+    pads on the host, a device array on its devices (no host round trip)."""
+    pad = target - a.shape[axis]
     if pad < 0:
-        raise ValueError(f"array has {a.shape[0]} rows > target {target}")
+        raise ValueError(
+            f"array has {a.shape[axis]} entries on axis {axis} > target {target}")
     if pad == 0:
         return a
-    return np.concatenate([a, np.zeros((pad,) + a.shape[1:], a.dtype)], axis=0)
+    widths = [(0, pad if i == axis else 0) for i in range(a.ndim)]
+    if isinstance(a, jax.Array):
+        import jax.numpy as jnp
 
-
-def _pad_cols(a: np.ndarray, target: int) -> np.ndarray:
-    pad = target - a.shape[1]
-    if pad == 0:
-        return a
-    return np.concatenate([a, np.zeros((a.shape[0], pad), a.dtype)], axis=1)
+        return jnp.pad(a, widths)
+    return np.pad(np.asarray(a), widths)
 
 
 def padded_dim(d: int, mesh: Mesh, axis: str = FEATURE_AXIS) -> int:
@@ -143,13 +144,17 @@ def shard_coefficients(w, mesh: Mesh, axis: str = FEATURE_AXIS):
 
 
 def shard_batch(batch: Batch, mesh: Mesh, axis: str = DATA_AXIS,
-                feature_axis: Optional[str] = None) -> Batch:
+                feature_axis: Optional[str] = None,
+                pad_to: Optional[int] = None) -> Batch:
     """Place a batch with its example dimension sharded over ``axis``.
 
-    Pads the example count up to a multiple of the axis size with weight-0
-    rows (inert by the core masking contract), then device_puts each leaf with
-    a NamedSharding.  This is the one-time data layout step that replaces the
-    reference's per-step broadcast + shuffle choreography.
+    Pads the example count up to a multiple of the axis size (at least
+    ``pad_to`` rows when given) with weight-0 rows (inert by the core masking
+    contract), then device_puts each leaf with a NamedSharding.  This is the
+    one-time data layout step that replaces the reference's per-step
+    broadcast + shuffle choreography.  Host leaves are padded on the host and
+    each shard is transferred to its own device; device leaves are padded and
+    resharded device to device.  No device ever receives the whole batch.
 
     ``feature_axis``: additionally shard the feature dimension of a dense
     design matrix (zero-padding d up to a multiple of the axis size) so the
@@ -159,31 +164,23 @@ def shard_batch(batch: Batch, mesh: Mesh, axis: str = DATA_AXIS,
     features (their w stays replicated; see parallel/fixed.py).
     """
     size = mesh.shape[axis]
-    n = batch.num_examples
+    n = max(batch.num_examples, pad_to or 0)
     target = ((n + size - 1) // size) * size
 
-    def place(x, spec):
-        return jax.device_put(x, NamedSharding(mesh, spec))
+    def place(a, spec):
+        return jax.device_put(_pad_axis(a, target, 0),
+                              NamedSharding(mesh, spec))
 
     row = P(axis)
-
+    vectors = dict(y=place(batch.y, row), offset=place(batch.offset, row),
+                   weight=place(batch.weight, row))
     if isinstance(batch, DenseBatch):
-        x = _pad_rows(np.asarray(batch.x), target)
+        x = batch.x
         if feature_axis is not None:
-            x = _pad_cols(x, padded_dim(x.shape[1], mesh, feature_axis))
-        return DenseBatch(
-            x=place(x, P(axis, feature_axis)),
-            y=place(_pad_rows(np.asarray(batch.y), target), row),
-            offset=place(_pad_rows(np.asarray(batch.offset), target), row),
-            weight=place(_pad_rows(np.asarray(batch.weight), target), row),
-        )
+            x = _pad_axis(x, padded_dim(x.shape[1], mesh, feature_axis), 1)
+        return DenseBatch(x=place(x, P(axis, feature_axis)), **vectors)
     if isinstance(batch, SparseBatch):
-        return SparseBatch(
-            indices=place(_pad_rows(np.asarray(batch.indices), target), P(axis, None)),
-            values=place(_pad_rows(np.asarray(batch.values), target), P(axis, None)),
-            y=place(_pad_rows(np.asarray(batch.y), target), row),
-            offset=place(_pad_rows(np.asarray(batch.offset), target), row),
-            weight=place(_pad_rows(np.asarray(batch.weight), target), row),
-            dim=batch.dim,
-        )
+        return SparseBatch(indices=place(batch.indices, P(axis, None)),
+                           values=place(batch.values, P(axis, None)),
+                           dim=batch.dim, **vectors)
     raise TypeError(f"unknown batch type {type(batch)!r}")

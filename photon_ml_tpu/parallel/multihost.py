@@ -169,12 +169,12 @@ def padded_per_host_rows(n: int, mesh: Mesh,
 def pad_local_rows(block: Dict[str, np.ndarray], rows: int) -> Dict[str, np.ndarray]:
     """Zero-pad every column's leading dim to ``rows`` (weight columns pad
     with 0, making the extra rows inert everywhere)."""
-    from photon_ml_tpu.parallel.mesh import _pad_rows
+    from photon_ml_tpu.parallel.mesh import _pad_axis
 
     out = {}
     for name, a in block.items():
         try:
-            out[name] = _pad_rows(np.asarray(a), rows)
+            out[name] = _pad_axis(np.asarray(a), rows, 0)
         except ValueError as e:
             raise ValueError(f"column {name!r}: {e}") from e
     return out

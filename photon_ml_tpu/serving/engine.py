@@ -8,9 +8,7 @@ online twin differs in three accelerator-driven ways:
      is lowered and compiled ONCE up front (``jax.jit(...).lower(...)
      .compile()``); requests only ever call finished executables, so the
      tail latency of a first-compile (tens of seconds on TPU) can never
-     land on a user request.  Per-request input buffers are donated to the
-     executable on accelerator backends (the coefficient tables are NOT —
-     they are reused across every request of a model generation).
+     land on a user request.
   2. **Bucketed shapes.**  The batcher pads each micro-batch to a fixed
      ladder of bucket sizes, so the executable cache stays small and the
      second-and-later request at any bucket size triggers zero recompiles
@@ -235,9 +233,9 @@ class ScoringEngine:
             # folds the per-shard partial margins — the [bucket] score
             # vector is the only thing that crosses ICI; coefficient rows
             # never leave their shard (no all-gather, by construction)
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
-            from photon_ml_tpu.parallel.compat import shard_map
             from photon_ml_tpu.parallel.mesh import SHARD_AXIS
 
             def _localize(s, cap):
@@ -324,18 +322,15 @@ class ScoringEngine:
         if exe is not None:
             return exe
         fn = self._build_fn(store, bucket)
-        # donate the per-request buffers (features, slots, overflow) — they
-        # are rebuilt every request, so the executable may reuse their
-        # device memory for outputs; coefficient tables (argnums 1, 2) live
-        # across requests and must NOT be donated.  CPU has no donation
-        # support (it would only warn), so gate on backend.
-        donate = (0, 3, 4) if jax.default_backend() != "cpu" else ()
+        # No buffer is donated: the per-request inputs ([bucket, d] features,
+        # slots, overflow rows) share no shape with the [bucket] output, so
+        # XLA has nothing to alias — on a v5e it answered a donation with
+        # "Some donated buffers were not usable" for every executable.
         # probe accounting: every AOT compile is counted + timed under the
         # "serving.engine" site, so "did serving recompile after warm" is a
         # registry query that must agree with compile_count
         with get_probe().compile_span("serving.engine", bucket=bucket):
-            jitted = jax.jit(fn, donate_argnums=donate)
-            lowered = jitted.lower(*self._abstract_args(store, bucket))
+            lowered = jax.jit(fn).lower(*self._abstract_args(store, bucket))
             exe = lowered.compile()
         self.kernels.put(key, exe)
         with self._lock:

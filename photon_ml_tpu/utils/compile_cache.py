@@ -6,16 +6,19 @@ benchmarks) hit compiled binaries instead.  The reference's analog is the
 JVM warming Spark executors once per application — here the warmth survives
 across processes on disk.
 
-Env override: ``PHOTON_COMPILE_CACHE=<dir>`` relocates it, ``=0`` disables.
+One variable places the cache: where ``JAX_COMPILATION_CACHE_DIR`` is set,
+JAX itself keeps the cache there and this module sets no directory in code
+(the path is part of the cache key's world — a directory chosen here would
+hide the one the machine came with).  Unset, the cache lives at the fixed
+``<checkout>/.xla_cache/<cpu tag>``.  JAX's own
+``JAX_ENABLE_COMPILATION_CACHE=false`` turns it off.
 """
 
 from __future__ import annotations
 
-import logging
 import os
 from typing import Optional
 
-logger = logging.getLogger(__name__)
 
 def _host_tag() -> str:
     """Short stable id of THIS machine's CPU capabilities.
@@ -62,31 +65,31 @@ def _default_dir() -> str:
                         "xla", _host_tag())
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Turn on jax's persistent compilation cache; returns the dir (or None
-    when disabled).  Safe to call multiple times / after jax is initialized.
+def enable_compilation_cache() -> Optional[str]:
+    """Turn on jax's persistent compilation cache; returns the directory in
+    use (None when JAX's own switch has it disabled).  Safe to call more
+    than once.  A directory that cannot be created raises: a process that
+    silently runs uncached pays every first-compile again, and on the chip
+    that is minutes.
 
     Cache residency reports into the observability registry (gauge
-    ``xla_compile_cache_enabled`` + a trace instant): a silently-disabled
-    cache means every process pays full first-compiles, which must be
-    visible next to the ``jax_compiles_total`` counters it inflates."""
+    ``xla_compile_cache_enabled`` + a trace instant), next to the
+    ``jax_compiles_total`` counters a missing cache inflates."""
+    import jax
+
     from photon_ml_tpu.obs import get_probe
 
-    env = os.environ.get("PHOTON_COMPILE_CACHE")
-    if env == "0":
+    if not jax.config.jax_enable_compilation_cache:
         get_probe().record_compile_cache(False)
         return None
-    cache_dir = cache_dir or env or _default_dir()
-    try:
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache everything that took noticeable compile time
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        get_probe().record_compile_cache(True, cache_dir)
-        return cache_dir
-    except Exception as e:  # never let cache setup break a run
-        logger.warning("compilation cache unavailable: %s", e)
-        get_probe().record_compile_cache(False)
-        return None
+    # cache everything that took noticeable compile time
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        get_probe().record_compile_cache(True, placed)
+        return placed
+    cache_dir = _default_dir()
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    get_probe().record_compile_cache(True, cache_dir)
+    return cache_dir

@@ -298,24 +298,35 @@ class TestProbe:
         # zero-recompile guarantee holds in BOTH ledgers
         assert probe.compile_count("serving.engine") == eng.compile_count
 
-    def test_transfer_accounting_chunked(self, fresh_registry, monkeypatch):
-        from photon_ml_tpu.utils.transfer import chunked_device_put
+    def test_transfer_accounting_device_put(self, fresh_registry):
+        from photon_ml_tpu.utils.transfer import device_put_counted
 
-        monkeypatch.setenv("PHOTON_CHUNKED_PUT_MIN_MB", "0.001")
-        arr = np.ones((64, 128), np.float32)  # 32KB > 1KB threshold
-        out = chunked_device_put(arr, chunk_bytes=8 * 1024)
-        np.testing.assert_array_equal(np.asarray(out), arr)
+        arr = np.ones((64, 128), np.float32)
+        out = device_put_counted(arr, "bfloat16")
+        np.testing.assert_array_equal(np.asarray(out, np.float32), arr)
         probe = obs.get_probe()
-        assert probe.transfer_bytes("h2d") == arr.nbytes
-        n_chunks = fresh_registry.counter("jax_transfers_total",
-                                          direction="h2d", site="chunked_put")
-        assert n_chunks == 4  # 32KB in 8KB chunks
+        # counted at the width that crossed: narrowed on the host first
+        assert probe.transfer_bytes("h2d") == arr.nbytes // 2
+        assert fresh_registry.counter("jax_transfers_total", direction="h2d",
+                                      site="device_put") == 1
+        device_put_counted(out)  # already resident: nothing crosses
+        assert probe.transfer_bytes("h2d") == arr.nbytes // 2
 
-    def test_compile_cache_gauge(self, fresh_registry, monkeypatch):
-        from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
+    def test_compile_cache_gauge(self, fresh_registry, monkeypatch, tmp_path):
+        import jax
 
-        monkeypatch.setenv("PHOTON_COMPILE_CACHE", "0")
-        assert enable_compilation_cache() is None
+        from photon_ml_tpu.utils import compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compilation_cache() == str(tmp_path)
+        assert fresh_registry.gauge("xla_compile_cache_enabled") == 1
+        # jax's own switch is the only off switch left
+        before = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            assert compile_cache.enable_compilation_cache() is None
+        finally:
+            jax.config.update("jax_enable_compilation_cache", before)
         assert fresh_registry.gauge("xla_compile_cache_enabled") == 0
 
 

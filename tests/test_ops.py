@@ -130,41 +130,42 @@ def test_objective_fused_flag_cpu_fallback(rng):
                                rtol=1e-12)
 
 
-def test_tpu_checklist_pallas_snippet_interpret():
-    """The one-command TPU capture (tools/tpu_checklist.py) embeds a
-    non-interpret pallas parity snippet that only ever runs on real
-    hardware — keep its MATH pinned green here by executing it in
-    interpret mode (same kernels, interpreter backend)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import tools.tpu_checklist as tc
+def test_has_tpu_does_not_swallow_a_broken_backend(monkeypatch):
+    """A backend that fails to initialise must raise — it used to read as
+    "no TPU", and all three kernels then yielded to XLA without a word."""
+    from photon_ml_tpu.ops import fused_glm
 
-    def patch(src, old, new):
-        # a reworded snippet must fail HERE (stale patch), not as a
-        # confusing eligibility/kernel error after a silent no-op replace
-        out = src.replace(old, new)
-        assert out != src, f"snippet no longer contains: {old!r}"
-        return out
+    def broken():
+        raise RuntimeError("TPU backend setup error")
 
-    src = tc._PALLAS_SRC
-    src = patch(src, "fused_value_and_grad(loss, jnp.asarray(w), b)",
-                "fused_value_and_grad(loss, jnp.asarray(w), b, interpret=True)")
-    src = patch(src, "fused_hvp(loss, jnp.asarray(w), jnp.asarray(v), b)",
-                "fused_hvp(loss, jnp.asarray(w), jnp.asarray(v), b, interpret=True)")
-    src = patch(src, "assert eligible(b)",
-                "assert eligible(b, interpret=True)")
-    src = patch(src, "fused_value_and_grad(logistic_loss, w16, b)",
-                "fused_value_and_grad(logistic_loss, w16, b, interpret=True)")
-    captured = {}
-    src = patch(src, "print(json.dumps(out))", "captured['out'] = out")
-    g = {"captured": captured}
-    exec(src, g)
-    out = captured["out"]
-    assert out["pass"], out
-    assert {c["loss"] for c in out["cases"]} == {"logistic", "squared",
-                                                "poisson", "logistic_bf16"}
+    fused_glm.has_tpu.cache_clear()
+    monkeypatch.setattr(fused_glm.jax, "devices", broken)
+    try:
+        with pytest.raises(RuntimeError, match="backend setup error"):
+            fused_glm.has_tpu()
+    finally:
+        fused_glm.has_tpu.cache_clear()
+
+
+def test_gate_is_a_vmem_shape_rule(rng, monkeypatch):
+    """eligible() on a TPU: lane-aligned dim and a design row of at most
+    16 KiB at storage width — d=8192 compiles in bf16 and not in f32 (its
+    (d, 1) coefficient block pads to 128 lanes: 23.9 MiB of 16 asked)."""
+    from photon_ml_tpu.ops import fused_glm
+
+    monkeypatch.setattr(fused_glm, "has_tpu", lambda: True)
+
+    def b(d, dtype):
+        return DenseBatch(x=jnp.zeros((8, d), dtype), y=jnp.zeros(8),
+                          offset=jnp.zeros(8), weight=jnp.ones(8))
+
+    assert fused_glm.eligible(b(4096, jnp.float32))
+    assert not fused_glm.eligible(b(4096 + 128, jnp.float32))
+    assert fused_glm.eligible(b(8192, jnp.bfloat16))
+    assert not fused_glm.eligible(b(8192 + 128, jnp.bfloat16))
+    assert not fused_glm.eligible(b(500, jnp.float32))  # not lane-aligned
+    monkeypatch.setenv("PHOTON_GLM_DISABLE_PALLAS", "1")
+    assert not fused_glm.eligible(b(512, jnp.float32))
 
 
 @pytest.mark.parametrize("loss", [logistic_loss, poisson_loss], ids=lambda l: l.name)

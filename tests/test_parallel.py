@@ -1248,3 +1248,92 @@ class TestMultihostGuards:
             multihost_glmix_sweep(mesh, dense_batch(x, y), {"user": gb},
                                   obj, obj, num_samples=n,
                                   re_scoring={"users": None})
+
+
+class TestKernelsInsideShardMap:
+    """Every kernel's ``eligible()`` is False off-TPU, so no CPU test ever
+    traced a ``pallas_call`` where a multi-chip run does: inside the repo's
+    ``shard_map`` call sites.  There ``jax.shard_map``'s ``check_vma``
+    rejects a pallas_call outright ("vma on jax.ShapeDtypeStruct must not
+    be None"), and under plain GSPMD Mosaic refuses to be partitioned.
+    These trace the kernels (interpret mode) inside the two sites that host
+    one — ``ShardMapObjective`` (fused_glm) and the random-effect
+    coordinate's per-lane solve (soa_newton).  ``compact_score`` runs under
+    no shard_map: its one caller is single-device batch scoring."""
+
+    def test_fused_glm_inside_shard_map_objective(self, devices, rng,
+                                                  monkeypatch):
+        import functools
+
+        from photon_ml_tpu.core.batch import DenseBatch
+        from photon_ml_tpu.ops import fused_glm
+        from photon_ml_tpu.parallel.fixed import ShardMapObjective
+        from photon_ml_tpu.parallel.mesh import shard_batch
+
+        monkeypatch.setattr(fused_glm, "eligible",
+                            lambda b, interpret=False: isinstance(b, DenseBatch))
+        for name in ("fused_value_and_grad", "fused_hvp"):
+            monkeypatch.setattr(fused_glm, name, functools.partial(
+                getattr(fused_glm, name), interpret=True))
+
+        n, d = 512, 128
+        batch = dense_batch(rng.normal(size=(n, d)) * 0.3,
+                            (rng.random(n) < 0.5).astype(float),
+                            offset=rng.normal(size=n) * 0.1,
+                            weight=rng.uniform(0.5, 2.0, size=n))
+        w = jnp.asarray(rng.normal(size=d) * 0.2)
+        v = jnp.asarray(rng.normal(size=d))
+        plain = GLMObjective(loss=losses.logistic_loss,
+                             reg=Regularization(l2=0.1))
+        mesh = make_mesh(n_data=4, devices=devices[:4])
+        sm = ShardMapObjective(plain.replace(fused=True), mesh)
+        sharded = shard_batch(batch, mesh)
+
+        assert "pallas_call" in str(jax.make_jaxpr(sm.value_and_grad)(
+            w, sharded))
+        val, grad = jax.jit(sm.value_and_grad)(w, sharded)
+        ref_val, ref_grad = plain.value_and_grad(w, batch)
+        np.testing.assert_allclose(val, ref_val, rtol=1e-12)
+        np.testing.assert_allclose(grad, ref_grad, rtol=1e-10, atol=1e-12)
+        assert "pallas_call" in str(jax.make_jaxpr(sm.hvp)(w, sharded, v))
+        np.testing.assert_allclose(jax.jit(sm.hvp)(w, sharded, v),
+                                   plain.hvp(w, batch, v),
+                                   rtol=1e-10, atol=1e-12)
+
+    def test_soa_newton_inside_the_coordinate_solve(self, devices, rng,
+                                                    monkeypatch):
+        import functools
+
+        from photon_ml_tpu.game import GameData, RandomEffectConfig
+        from photon_ml_tpu.game.coordinate import build_coordinate
+        from photon_ml_tpu.opt import newton_soa
+        from photon_ml_tpu.types import TaskType
+
+        n_users, per_user, d = 512, 6, 3  # 128 lanes on each of 4 devices
+        n = n_users * per_user
+        data = GameData(y=(rng.random(n) < 0.5).astype(float),
+                        features={"u": rng.normal(size=(n, d))},
+                        id_tags={"userId": np.repeat(np.arange(n_users),
+                                                     per_user)})
+        cfg = RandomEffectConfig(
+            random_effect_type="userId", feature_shard="u",
+            solver=SolverConfig(max_iters=6, tolerance=1e-9),
+            reg=Regularization(l2=1.0))
+        task = TaskType.LOGISTIC_REGRESSION
+        ref, _ = build_coordinate("user", data, cfg, task).update(np.zeros(n))
+
+        monkeypatch.setattr(newton_soa, "solve_newton_soa", functools.partial(
+            newton_soa.solve_newton_soa, interpret=True))
+        mesh = make_mesh(n_data=4, devices=devices[:4])
+        coord = build_coordinate("user", data, cfg, task, mesh=mesh)
+        assert coord._use_soa
+        dev, lanes = coord._dev[0], coord._dev[0]["x"].shape[0]
+        jaxpr = str(jax.make_jaxpr(coord._vsolve)(
+            jnp.zeros((lanes, d)), dev["x"], dev["y"],
+            jnp.zeros_like(dev["y"]), dev["w"],
+            coord._lane_regs(cfg.reg)[0]))
+        assert "shard_map" in jaxpr and "pallas_call" in jaxpr
+        got, _ = coord.update(np.zeros(n))
+        assert got.slot_of == ref.slot_of
+        np.testing.assert_allclose(got.w_stack, ref.w_stack,
+                                   rtol=1e-8, atol=1e-10)

@@ -15,6 +15,7 @@ Three contracts from the raw-speed pass:
     order: k observed columns vs the d-wide einsum).
 """
 
+import functools
 import os
 
 import jax
@@ -270,7 +271,7 @@ class TestSoaNewtonKernel:
                                    rtol=1e-11, atol=1e-12)
 
     def test_full_solver_parity_including_rejection_lanes(self, rng):
-        """solve_newton_soa with the kernel (interpret knob) == pure XLA,
+        """solve_newton_soa with the kernel (interpret=True) == pure XLA,
         end to end — convergence reasons, iterates and line-search
         REJECTION lanes included (max_linesearch=1 + an aggressive
         objective makes some lanes reject and stall)."""
@@ -285,12 +286,10 @@ class TestSoaNewtonKernel:
         assert (np.asarray(ref.reason)
                 == int(ConvergenceReason.OBJECTIVE_NOT_IMPROVING)).any(), \
             "fixture no longer produces line-search-rejection lanes"
-        os.environ["PHOTON_SOA_PALLAS_INTERPRET"] = "1"
-        try:
-            assert soa_newton.eligible(w.shape[0], w.shape[1])
-            got = solve_newton_soa(logistic_loss, w, x, y, off, wt, l2, cfg)
-        finally:
-            del os.environ["PHOTON_SOA_PALLAS_INTERPRET"]
+        assert soa_newton.eligible(w.shape[0], w.shape[1], x.shape[0],
+                                   x.dtype.itemsize, interpret=True)
+        got = solve_newton_soa(logistic_loss, w, x, y, off, wt, l2, cfg,
+                               interpret=True)
         np.testing.assert_array_equal(np.asarray(got.reason),
                                       np.asarray(ref.reason))
         np.testing.assert_array_equal(np.asarray(got.iterations),
@@ -298,18 +297,35 @@ class TestSoaNewtonKernel:
         np.testing.assert_allclose(np.asarray(got.w), np.asarray(ref.w),
                                    rtol=1e-9, atol=1e-11)
 
-    def test_gating(self):
-        assert not soa_newton.eligible(4, 100)  # not lane-aligned
-        os.environ["PHOTON_SOA_DISABLE_PALLAS"] = "1"
-        try:
-            assert not soa_newton.eligible(4, 256, interpret=True)
-        finally:
-            del os.environ["PHOTON_SOA_DISABLE_PALLAS"]
+    def test_gating(self, monkeypatch):
+        ok = functools.partial(soa_newton.eligible, interpret=True)
+        assert ok(4, 256, 32)
+        assert not ok(4, 100, 32)  # not lane-aligned
+        assert not ok(17, 256, 4)  # past the static Cholesky unroll
+        # VMEM shape rule: a 128-lane block of the (cap, d) design must fit
+        assert ok(16, 128, 427) and not ok(16, 128, 428)
+        # no environment variable reaches interpret mode, and off-TPU the
+        # production gate says no
+        monkeypatch.setenv("PHOTON_SOA_PALLAS_INTERPRET", "1")
+        assert not soa_newton.eligible(4, 256, 32)
+        monkeypatch.setenv("PHOTON_SOA_DISABLE_PALLAS", "1")
+        assert not ok(4, 256, 32)
         with pytest.raises(ValueError, match="eligible"):
             soa_newton.newton_step(
                 logistic_loss, jnp.zeros((4, 100)), jnp.zeros((4, 100)),
                 jnp.zeros((3, 4, 100)), jnp.zeros((3, 100)),
                 jnp.zeros((3, 100)), jnp.ones((3, 100)), jnp.ones(100))
+
+    @pytest.mark.parametrize("lanes,want", [(1152, 1152), (16384, 2048),
+                                            (131072, 2048), (128, 128)])
+    def test_block_lanes_divide_and_fit(self, lanes, want):
+        """The picker's block always divides the lane count (1152 used to
+        pick a non-dividing 1024 and raise) and is the same at the smoke's
+        16k lanes as at glmix_chip's 131k."""
+        bl = soa_newton._pick_block_lanes(32, 4, lanes, 4)
+        assert bl == want and lanes % bl == 0
+        assert bl * soa_newton._bytes_per_lane(32, 4, 4) \
+            <= soa_newton.VMEM_BLOCK_BUDGET_BYTES
 
 
 class TestCompactScoreKernel:
@@ -327,7 +343,7 @@ class TestCompactScoreKernel:
         """Kernel == the searchsorted/take_along_axis chain: missing
         entities, dim-padded model rows, zero-valued padded feature slots
         and DUPLICATE feature ids (which accumulate) all covered."""
-        from photon_ml_tpu.models.game import _score_sparse_compact
+        from photon_ml_tpu.models.game import score_compact_sparse_xla
 
         dim, k_f, n = 60, 9, 300
         w_idx, w_val = self._compact_model_arrays(rng, dim=dim)
@@ -336,7 +352,7 @@ class TestCompactScoreKernel:
         f_idx[:, 3] = f_idx[:, 2]  # duplicates accumulate
         f_val = rng.normal(size=(n, k_f))
         f_val[:, -2:] = 0.0        # padded COO slots carry value 0
-        ref = _score_sparse_compact(
+        ref = score_compact_sparse_xla(
             jnp.asarray(w_idx), jnp.asarray(w_val), jnp.asarray(slots),
             jnp.asarray(np.asarray(f_idx, np.int32)), jnp.asarray(f_val))
         got = compact_score.score_sparse_compact(
@@ -346,13 +362,14 @@ class TestCompactScoreKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-10, atol=1e-12)
 
-    def test_gating(self):
-        assert not compact_score.eligible(128, 128)  # match work too big
-        os.environ["PHOTON_COMPACT_DISABLE_PALLAS"] = "1"
-        try:
-            assert not compact_score.eligible(4, 4, interpret=True)
-        finally:
-            del os.environ["PHOTON_COMPACT_DISABLE_PALLAS"]
+    def test_gating(self, monkeypatch):
+        ok = functools.partial(compact_score.eligible, interpret=True)
+        assert ok(64, 64) and ok(8, 512) and ok(2048, 2)
+        assert not ok(128, 128)   # match work too big
+        assert not ok(1, 1024)    # past the static feature unroll
+        assert not ok(4096, 1)    # a 128-lane block would not fit VMEM
+        monkeypatch.setenv("PHOTON_COMPACT_DISABLE_PALLAS", "1")
+        assert not ok(4, 4)
 
 
 # ---------------------------------------------------------------------------

@@ -126,18 +126,73 @@ def test_input_paths_within_date_range(tmp_path):
             "20180101-20180102"))
 
 
-def test_compilation_cache_setup(tmp_path, monkeypatch):
-    from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
+@pytest.fixture
+def cache_config():
+    """Restore jax's cache-dir setting after a test moved it."""
+    import jax
 
-    d = str(tmp_path / "cache")
-    assert enable_compilation_cache(d) == d
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compilation_cache_default_is_fixed_under_checkout(
+        tmp_path, monkeypatch, cache_config):
+    """Unset JAX_COMPILATION_CACHE_DIR: the cache is <checkout>/.xla_cache
+    (+ a machine-derived tag) — a fixed path, the same in every process."""
     import os
 
-    assert os.path.isdir(d)
-    monkeypatch.setenv("PHOTON_COMPILE_CACHE", "0")
-    assert enable_compilation_cache() is None
-    monkeypatch.setenv("PHOTON_COMPILE_CACHE", str(tmp_path / "env"))
-    assert enable_compilation_cache() == str(tmp_path / "env")
+    import jax
+
+    from photon_ml_tpu.utils import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = compile_cache._default_dir()
+    assert want == compile_cache._default_dir()
+    assert os.path.dirname(want) == os.path.join(repo, ".xla_cache")
+    monkeypatch.setattr(compile_cache, "_default_dir",
+                        lambda: str(tmp_path / "cache"))
+    got = compile_cache.enable_compilation_cache()
+    assert got == str(tmp_path / "cache") and os.path.isdir(got)
+    assert jax.config.jax_compilation_cache_dir == got
+
+
+def test_compilation_cache_placed_from_outside(tmp_path, monkeypatch,
+                                               cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: the function sets NO directory in
+    code (jax's own setting is left alone), creates none, and reports the
+    outside one.  PHOTON_COMPILE_CACHE no longer places or disables
+    anything."""
+    import jax
+
+    from photon_ml_tpu.utils import compile_cache
+
+    jax.config.update("jax_compilation_cache_dir", "/set/by/jax")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    monkeypatch.setenv("PHOTON_COMPILE_CACHE", str(tmp_path / "old_knob"))
+    monkeypatch.setattr(compile_cache, "_default_dir",
+                        lambda: str(tmp_path / "default"))
+    assert compile_cache.enable_compilation_cache() == str(tmp_path / "outside")
+    assert jax.config.jax_compilation_cache_dir == "/set/by/jax"
+    assert list(tmp_path.iterdir()) == []
+    monkeypatch.setenv("PHOTON_COMPILE_CACHE", "0")  # the old off switch
+    assert compile_cache.enable_compilation_cache() == str(tmp_path / "outside")
+
+
+def test_compilation_cache_that_cannot_be_set_up_raises(tmp_path, monkeypatch,
+                                                        cache_config):
+    """A cache directory that cannot be created is an error, not a warning:
+    an uncached process pays every first-compile again."""
+    from photon_ml_tpu.utils import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    monkeypatch.setattr(compile_cache, "_default_dir",
+                        lambda: str(blocker / "cache"))
+    with pytest.raises(OSError):
+        compile_cache.enable_compilation_cache()
 
 
 def test_sparse_feature_stats_match_dense():
@@ -170,76 +225,58 @@ def test_sparse_feature_stats_match_dense():
                                    atol=1e-4, rtol=1e-3, err_msg=f)
 
 
-class TestChunkedDevicePut:
-    """Bounded-RPC host->device transfer (utils/transfer.py): byte-identical
-    to a direct jnp.asarray, whatever the chunk/threshold geometry."""
+class TestDevicePutCounted:
+    """Host->device placement of design arrays (utils/transfer.py):
+    byte-identical to a direct jnp.asarray, narrowed on the host, counted
+    by the probe, one transfer whatever the size."""
 
-    def test_matches_direct_path(self, monkeypatch):
+    def test_matches_direct_path_and_narrows_on_host(self):
         import numpy as np
 
-        from photon_ml_tpu.utils.transfer import chunked_device_put
+        from photon_ml_tpu.utils.transfer import device_put_counted
 
         rng = np.random.default_rng(0)
         a = rng.normal(size=(1000, 7)).astype(np.float32)
-        # force chunking: 1KB threshold, 4KB chunks -> ~36 slices
-        monkeypatch.setenv("PHOTON_CHUNKED_PUT_MIN_MB", str(1 / 1024))
-        out = chunked_device_put(a, chunk_bytes=4096)
-        np.testing.assert_array_equal(np.asarray(out), a)
-        # dtype narrowing happens host-side before transfer
-        out16 = chunked_device_put(a, "bfloat16", chunk_bytes=4096)
+        np.testing.assert_array_equal(np.asarray(device_put_counted(a)), a)
+        out16 = device_put_counted(a, "bfloat16")
         assert str(out16.dtype) == "bfloat16"
+        np.testing.assert_array_equal(
+            np.asarray(out16), np.asarray(a.astype(out16.dtype)))
 
-    def test_chunks_along_largest_axis(self, monkeypatch):
-        """A transposed narrow array ([d, n] — score_samples_t layout) has a
-        tiny leading axis; chunking must slice the LARGEST axis or the
-        upload degenerates to the one giant RPC the helper exists to
-        prevent."""
+    def test_one_transfer_whatever_the_size(self, monkeypatch):
+        """There is no chunking on a local chip: a large array, a transposed
+        narrow one and a small one each cross in exactly ONE jnp.asarray,
+        and the old PHOTON_CHUNKED_PUT_MIN_MB knob changes nothing."""
         import numpy as np
 
         from photon_ml_tpu.utils import transfer
 
+        calls = []
+        real = transfer.jnp.asarray
+
+        def counting(a, *args, **kw):
+            calls.append(np.shape(a))
+            return real(a, *args, **kw)
+
+        monkeypatch.setattr(transfer, "jnp",
+                            type("J", (), {"asarray": staticmethod(counting),
+                                           "dtype": transfer.jnp.dtype}))
         monkeypatch.setenv("PHOTON_CHUNKED_PUT_MIN_MB", str(1 / 1024))
-        calls = []
-        real = transfer.jnp.asarray
-
-        def counting(a, *args, **kw):
-            calls.append(np.shape(a))
-            return real(a, *args, **kw)
-
-        monkeypatch.setattr(transfer, "jnp",
-                            type("J", (), {"asarray": staticmethod(counting),
-                                           "zeros": transfer.jnp.zeros}))
-        a = np.arange(2 * 5000, dtype=np.float32).reshape(2, 5000)
-        out = np.asarray(transfer.chunked_device_put(a.T.copy().T,
-                                                     chunk_bytes=4096))
-        np.testing.assert_array_equal(out, a)
-        assert len(calls) > 1 and all(s[0] == 2 for s in calls)
-
-    def test_small_and_disabled_take_direct_path(self, monkeypatch):
-        """Byte-identity can't distinguish the paths, so count the transfer
-        calls: the direct path is exactly ONE jnp.asarray of the whole
-        array — a regression that chunks small/disabled inputs fails here."""
-        import numpy as np
-
-        from photon_ml_tpu.utils import transfer
-
-        calls = []
-        real = transfer.jnp.asarray
-
-        def counting(a, *args, **kw):
-            calls.append(np.shape(a))
-            return real(a, *args, **kw)
-
-        monkeypatch.setattr(transfer, "jnp",
-                            type("J", (), {"asarray": staticmethod(counting),
-                                           "zeros": transfer.jnp.zeros}))
-        a = np.arange(12, dtype=np.float32).reshape(3, 4)
-        np.testing.assert_array_equal(
-            np.asarray(transfer.chunked_device_put(a, chunk_bytes=8)), a)
-        assert calls == [(3, 4)]  # small: one whole-array transfer
-        calls.clear()
-        monkeypatch.setenv("PHOTON_CHUNKED_PUT_MIN_MB", "0")
         big = np.zeros((1000, 7), np.float32)
-        np.testing.assert_array_equal(
-            np.asarray(transfer.chunked_device_put(big, chunk_bytes=8)), big)
-        assert calls == [(1000, 7)]  # disabled: one whole-array transfer
+        wide = np.arange(2 * 5000, dtype=np.float32).reshape(2, 5000)
+        for a in (big, wide, np.ones((3, 4), np.float32)):
+            np.testing.assert_array_equal(
+                np.asarray(transfer.device_put_counted(a)), a)
+        assert calls == [(1000, 7), (2, 5000), (3, 4)]
+
+    def test_device_array_passes_through(self):
+        """An already-resident array (a streamed shard, one upload shared
+        by several coordinates) never round-trips through the host."""
+        import jax.numpy as jnp
+
+        from photon_ml_tpu.utils.transfer import device_put_counted
+
+        x = jnp.arange(12.0).reshape(3, 4)
+        assert device_put_counted(x) is x
+        assert device_put_counted(x, x.dtype) is x
+        assert str(device_put_counted(x, "bfloat16").dtype) == "bfloat16"
