@@ -122,9 +122,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "photonwatch /watchz federation pull on this "
                         "localhost port via a sidecar thread (0 = off)")
     p.add_argument("--watch", action="store_true",
-                   help="photonwatch: enable span-aligned XLA device-time "
-                        "attribution (xla_device_seconds{site=} + "
-                        "device_us/host_us span attrs on solve.bucket)")
+                   help="accepted and ignored: GET /watchz on "
+                        "--metrics-port is always on, and --slo runs the "
+                        "burn-rate sidecar")
     p.add_argument("--slo", default="", metavar="FILE",
                    help="photonwatch SLO objectives (JSON list, "
                         "obs/watch/slo.py) evaluated against this "
@@ -271,16 +271,11 @@ def run(argv: List[str]) -> int:
                 engine.store.version, engine.store.task.value,
                 coords or "auto")
 
-    # photonwatch: identity gauges always; attribution / SLO eval /
-    # federation pull opt-in
+    # photonwatch: identity gauges always; SLO eval / federation pull
+    # opt-in
     from photon_ml_tpu.obs.registry import export_build_info
 
     export_build_info(engine.metrics.registry, role="owner")
-    if args.watch:
-        from photon_ml_tpu.obs.watch import enable_attribution
-
-        enable_attribution(engine.metrics.registry)
-        logger.info("photonwatch: device-time attribution enabled")
     slo_thread = None
     if args.slo:
         from photon_ml_tpu.obs.watch import SLOEngine, SLOEvalThread, load_slos

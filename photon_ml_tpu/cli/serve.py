@@ -347,11 +347,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="on-disk byte bound for the flight spool "
                         "(oldest dumps evicted first)")
     p.add_argument("--watch", action="store_true",
-                   help="photonwatch: enable span-aligned XLA device-time "
-                        "attribution (xla_device_seconds{site=} + "
-                        "device_us/host_us span attrs on serve.execute) — "
-                        "the {\"cmd\": \"watch\"} federation stream and "
-                        "GET /watchz are always on")
+                   help="accepted and ignored: the {\"cmd\": \"watch\"} "
+                        "federation stream and GET /watchz are always on, "
+                        "and --slo runs the burn-rate sidecar")
     p.add_argument("--slo", default="", metavar="FILE",
                    help="photonwatch SLO objectives (JSON list, "
                         "obs/watch/slo.py): evaluate multi-window burn "
@@ -988,17 +986,11 @@ def run(argv: List[str]) -> int:
                 engine.store.generation, engine.store.version,
                 engine.store.task.value)
 
-    # photonwatch: every process exports who it is; --watch additionally
-    # turns on span-aligned device-time attribution for serve.execute
+    # photonwatch: every process exports who it is
     from photon_ml_tpu.obs.registry import export_build_info
 
     export_build_info(engine.metrics.registry,
                       role="replica" if args.subscribe else "frontend")
-    if args.watch:
-        from photon_ml_tpu.obs.watch import enable_attribution
-
-        enable_attribution(engine.metrics.registry)
-        logger.info("photonwatch: device-time attribution enabled")
 
     if client is not None:
         swapper.set_base(model_dir, client.floor or 0)

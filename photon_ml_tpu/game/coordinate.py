@@ -20,6 +20,7 @@ TPU-native shape:
 
 from __future__ import annotations
 
+import contextlib
 import time as _time
 from typing import Dict, List, Optional, Tuple, Union
 
@@ -37,9 +38,9 @@ from photon_ml_tpu.game.config import CoordinateConfig, FixedEffectConfig, Rando
 from photon_ml_tpu.game.data import GameData, SparseShard
 from photon_ml_tpu.models.game import DatumScoringModel, FixedEffectModel, RandomEffectModel
 from photon_ml_tpu.models.glm import Coefficients
-from photon_ml_tpu.obs import get_registry, set_family_bounds
+from photon_ml_tpu.obs import get_probe, get_registry, set_family_bounds
+from photon_ml_tpu.obs.trace import device_scope
 from photon_ml_tpu.obs.trace import span as obs_span
-from photon_ml_tpu.obs.watch.attribution import attribute as obs_attribute
 from photon_ml_tpu.opt.solve import make_solver
 from photon_ml_tpu.opt.types import SolverResult
 from photon_ml_tpu.parallel.bucketing import bucket_by_entity, stacked_coefficients
@@ -229,6 +230,18 @@ def _storage_np_dtype(storage_dtype: Optional[str]):
     return np.dtype(storage_dtype)
 
 
+@contextlib.contextmanager
+def _upload_span(coordinate_id: str):
+    """``coord.upload``: a coordinate's arrays on their way to the device;
+    ``bytes`` is what ``device_put_counted`` counted inside (0 where the
+    design was already there — the span is recorded all the same)."""
+    with obs_span("coord.upload", coordinate=coordinate_id) as sp:
+        probe = get_probe()
+        before = probe.transfer_bytes("h2d", site="device_put")
+        yield
+        sp.set(bytes=probe.transfer_bytes("h2d", site="device_put") - before)
+
+
 def _where_it_is(a, dtype=None):
     """``a`` at ``dtype`` without moving it: a device array stays on its
     devices, anything else becomes a host array (narrowed on the host)."""
@@ -263,66 +276,67 @@ class FixedEffectCoordinate(Coordinate):
                                                  padded_dim)
         from photon_ml_tpu.utils.transfer import device_put_counted
 
-        # Under a mesh every leaf goes from where it is (host, for loaded
-        # data) straight to its shards: staging the whole design on the
-        # default device first would need one chip to hold all of it.
-        put = device_put_counted if mesh is None else _where_it_is
-        if mesh is None:
-            y = jnp.asarray(np.asarray(data.y, dtype))
-            # default offsets (all-zero) / weights (all-one) are created on
-            # device: an [n]-sized constant needs no transfer
-            offs_np = np.asarray(data.offset, dtype)
-            offs0 = (jnp.zeros(self._n, dtype) if not offs_np.any()
-                     else jnp.asarray(offs_np))
-            wt_np = np.asarray(data.weight, dtype)
-            wt0 = (jnp.ones(self._n, dtype) if np.all(wt_np == 1.0)
-                   else jnp.asarray(wt_np))
-        else:
-            y = np.asarray(data.y, dtype)
-            offs0 = np.asarray(data.offset, dtype)
-            wt0 = np.asarray(data.weight, dtype)
-        if isinstance(shard_data, SparseShard):
-            batch = SparseBatch(
-                indices=put(shard_data.indices),
-                values=put(shard_data.values, x_dtype),
-                y=y, offset=offs0, weight=wt0, dim=shard_data.dim)
-        else:
-            batch = DenseBatch(x=put(shard_data, x_dtype),
-                               y=y, offset=offs0, weight=wt0)
+        with _upload_span(coordinate_id):
+            # Under a mesh every leaf goes from where it is (host, for loaded
+            # data) straight to its shards: staging the whole design on the
+            # default device first would need one chip to hold all of it.
+            put = device_put_counted if mesh is None else _where_it_is
+            if mesh is None:
+                y = jnp.asarray(np.asarray(data.y, dtype))
+                # default offsets (all-zero) / weights (all-one) are created on
+                # device: an [n]-sized constant needs no transfer
+                offs_np = np.asarray(data.offset, dtype)
+                offs0 = (jnp.zeros(self._n, dtype) if not offs_np.any()
+                         else jnp.asarray(offs_np))
+                wt_np = np.asarray(data.weight, dtype)
+                wt0 = (jnp.ones(self._n, dtype) if np.all(wt_np == 1.0)
+                       else jnp.asarray(wt_np))
+            else:
+                y = np.asarray(data.y, dtype)
+                offs0 = np.asarray(data.offset, dtype)
+                wt0 = np.asarray(data.weight, dtype)
+            if isinstance(shard_data, SparseShard):
+                batch = SparseBatch(
+                    indices=put(shard_data.indices),
+                    values=put(shard_data.values, x_dtype),
+                    y=y, offset=offs0, weight=wt0, dim=shard_data.dim)
+            else:
+                batch = DenseBatch(x=put(shard_data, x_dtype),
+                                   y=y, offset=offs0, weight=wt0)
 
-        # Feature-axis (model-parallel) sharding: active only when the mesh
-        # actually has a feature axis > 1, so the same config is valid on any
-        # mesh (mesh-agnostic property, SURVEY §4).
-        self._fs = bool(getattr(config, "feature_sharded", False)) \
-            and mesh is not None and mesh.shape[FEATURE_AXIS] > 1
-        self._d_pad = padded_dim(self.dim, mesh) if self._fs else self.dim
-        # One-time row padding to the fused-kernel block granule so the
-        # pallas path never re-pads (and re-copies X) per solver call.
-        # Narrow float storage (bf16/f16) keeps the pallas path — the
-        # kernels take storage-width MXU operands with f32 accumulation
-        # (GLMObjective._fused_eligible).  Wider-than-solver storage (f64)
-        # falls back to XLA.  Same predicate GLMObjective._fused_eligible
-        # consults at solve time — the pre-pad must never disagree with the
-        # per-call gate.
-        fused_ok = (storage_narrowing_ok(x_dtype, dtype) and eligible(batch)
-                    and not self._fs)  # pallas kernels assume full-width w
-        n_dev = 1 if mesh is None else mesh.shape[DATA_AXIS]
-        pad_to = None
-        if fused_ok:
-            # pad so each device's LOCAL shard is a block multiple
-            local = -(-batch.num_examples // n_dev)
-            bn = _pick_block_rows(local, batch.dim,
-                                  np.dtype(batch.x.dtype).itemsize)
-            pad_to = (-(-local // bn) * bn) * n_dev
-        if mesh is not None:
-            batch = shard_batch(
-                batch, mesh, pad_to=pad_to,
-                feature_axis=FEATURE_AXIS
-                if (self._fs and isinstance(batch, DenseBatch)) else None)
-        elif pad_to is not None:
-            from photon_ml_tpu.ops.fused_glm import _pad_rows
+            # Feature-axis (model-parallel) sharding: active only when the mesh
+            # actually has a feature axis > 1, so the same config is valid on any
+            # mesh (mesh-agnostic property, SURVEY §4).
+            self._fs = bool(getattr(config, "feature_sharded", False)) \
+                and mesh is not None and mesh.shape[FEATURE_AXIS] > 1
+            self._d_pad = padded_dim(self.dim, mesh) if self._fs else self.dim
+            # One-time row padding to the fused-kernel block granule so the
+            # pallas path never re-pads (and re-copies X) per solver call.
+            # Narrow float storage (bf16/f16) keeps the pallas path — the
+            # kernels take storage-width MXU operands with f32 accumulation
+            # (GLMObjective._fused_eligible).  Wider-than-solver storage (f64)
+            # falls back to XLA.  Same predicate GLMObjective._fused_eligible
+            # consults at solve time — the pre-pad must never disagree with the
+            # per-call gate.
+            fused_ok = (storage_narrowing_ok(x_dtype, dtype) and eligible(batch)
+                        and not self._fs)  # pallas kernels assume full-width w
+            n_dev = 1 if mesh is None else mesh.shape[DATA_AXIS]
+            pad_to = None
+            if fused_ok:
+                # pad so each device's LOCAL shard is a block multiple
+                local = -(-batch.num_examples // n_dev)
+                bn = _pick_block_rows(local, batch.dim,
+                                      np.dtype(batch.x.dtype).itemsize)
+                pad_to = (-(-local // bn) * bn) * n_dev
+            if mesh is not None:
+                batch = shard_batch(
+                    batch, mesh, pad_to=pad_to,
+                    feature_axis=FEATURE_AXIS
+                    if (self._fs and isinstance(batch, DenseBatch)) else None)
+            elif pad_to is not None:
+                from photon_ml_tpu.ops.fused_glm import _pad_rows
 
-            batch = _pad_rows(batch, pad_to)
+                batch = _pad_rows(batch, pad_to)
         self._batch = batch
         self._padded_n = batch.num_examples
         self._base_weight = batch.weight
@@ -559,19 +573,25 @@ class FixedEffectCoordinate(Coordinate):
                      reg: Optional[Regularization] = None,
                      key=None, data=None) -> Tuple[Array, Array]:
         batch = self._batch if data is None else data
-        offs, weights = self._sweep_batch_inputs(offsets, key, batch)
-        res = self._solve(state, batch.replace(offset=offs, weight=weights),
-                          self.config.reg if reg is None else reg)
+        with device_scope("fixed_solve"):
+            offs, weights = self._sweep_batch_inputs(offsets, key, batch)
+            res = self._solve(state,
+                              batch.replace(offset=offs, weight=weights),
+                              self.config.reg if reg is None else reg)
         w_pub = self.trace_publish(res.w)
-        if self._fs and isinstance(batch, SparseBatch):
-            # pinned communication: one [n_local] feature-axis psum instead
-            # of GSPMD all-gathering the full sharded coefficient vector
-            return res.w, self._objective.margins(w_pub, batch)[: self._n]
-        return res.w, batch.margins(w_pub)[: self._n]
+        with device_scope("rescore"):
+            if self._fs and isinstance(batch, SparseBatch):
+                # pinned communication: one [n_local] feature-axis psum
+                # instead of GSPMD all-gathering the full sharded
+                # coefficient vector
+                return res.w, self._objective.margins(w_pub,
+                                                      batch)[: self._n]
+            return res.w, batch.margins(w_pub)[: self._n]
 
     def trace_publish(self, state: Array, data=None) -> Array:
-        return self._norm.model_to_original_space(state,
-                                                  self.config.intercept_index)
+        with device_scope("publish"):
+            return self._norm.model_to_original_space(
+                state, self.config.intercept_index)
 
     def export_model(self, published: np.ndarray) -> FixedEffectModel:
         return FixedEffectModel(
@@ -769,174 +789,179 @@ class RandomEffectCoordinate(Coordinate):
                 f"coordinate {coordinate_id!r}: shift normalization under "
                 "per-entity compaction needs intercept_index (the per-lane "
                 "intercept column absorbs the projected margin shift)")
-        if self._sparse:
-            # Row-sparse RE feature bag (the reference's per-entity sparse
-            # LocalDataset, data/LocalDataset.scala:35-247): each entity
-            # solves in the compact space of its observed columns, built
-            # DIRECTLY from the sparse rows — the full-vocabulary [E, S, d]
-            # bucket tensors never exist (bucket_by_entity_sparse).
-            # (projected_dim without RANDOM is rejected at CONFIG time —
-            # RandomEffectConfig.__post_init__ — so no guard here)
-            from photon_ml_tpu.parallel.bucketing import bucket_by_entity_sparse
-            from photon_ml_tpu.parallel.projection import ProjectedBuckets
+        # coord.bucket: host grouping + the Python packing loops;
+        # coord.upload: the design's way onto the device
+        with obs_span("coord.bucket", coordinate=coordinate_id):
+            if self._sparse:
+                # Row-sparse RE feature bag (the reference's per-entity sparse
+                # LocalDataset, data/LocalDataset.scala:35-247): each entity
+                # solves in the compact space of its observed columns, built
+                # DIRECTLY from the sparse rows — the full-vocabulary [E, S, d]
+                # bucket tensors never exist (bucket_by_entity_sparse).
+                # (projected_dim without RANDOM is rejected at CONFIG time —
+                # RandomEffectConfig.__post_init__ — so no guard here)
+                from photon_ml_tpu.parallel.bucketing import bucket_by_entity_sparse
+                from photon_ml_tpu.parallel.projection import ProjectedBuckets
 
-            ratio = (config.features_to_samples_ratio
-                     if config.projector == ProjectorType.INDEX_MAP else None)
-            self.buckets, projections = bucket_by_entity_sparse(
-                entity_ids, shard_data.indices, shard_data.values, self.dim,
-                np.asarray(data.y, dtype),
-                offset=np.asarray(data.offset, dtype),
-                weight=np.asarray(data.weight, dtype),
-                active_cap=config.active_cap,
-                min_active_samples=config.min_active_samples,
-                lane_multiple=lane_multiple, seed=seed, dtype=dtype,
-                features_to_samples_ratio=ratio,
-                intercept_index=config.intercept_index,
-                existing_model_keys=existing_model_keys,
-            )
-            self._proj = ProjectedBuckets(base=self.buckets,
-                                          buckets=self.buckets.buckets,
-                                          projections=projections)
-            if config.projector == ProjectorType.RANDOM:
-                # RANDOM over a sparse shard: the shared Gaussian matrix's
-                # rows GATHERED through each lane's observed-column map
-                # project the compact design into d_proj — exactly what the
-                # densified x @ A computes, because unobserved columns
-                # contribute zero either way (reference builds the same
-                # shared matrix per coordinate, ProjectionMatrixBroadcast
-                # .scala:150; the full-vocabulary [E, S, d] tensors still
-                # never exist).
-                import dataclasses as _dc
-
-                from photon_ml_tpu.parallel.projection import (
-                    build_random_projection)
-
-                if config.projected_dim is None:
-                    raise ValueError("RANDOM projection requires projected_dim")
-                shared = build_random_projection(
-                    self.dim, config.projected_dim, seed, dtype=dtype,
-                    intercept_index=config.intercept_index)
-                proj_buckets = []
-                for b, p in zip(self.buckets.buckets, projections):
-                    safe = np.where(p.indices < 0, 0, p.indices)
-                    a_sub = shared.matrix[safe]  # [lanes, d_compact, d_proj]
-                    a_sub = np.where((p.indices >= 0)[:, :, None], a_sub, 0.0)
-                    x_proj = np.einsum("lsd,ldp->lsp", b.x,
-                                       a_sub).astype(dtype)
-                    proj_buckets.append(_dc.replace(b, x=x_proj))
-                self._proj = ProjectedBuckets(
-                    base=self.buckets, buckets=proj_buckets,
-                    projections=[shared] * len(proj_buckets))
-        else:
-            # A streamed (device-assembled) dense shard stays on device: the
-            # bucketer gathers lanes on device, and the [n, d] array never
-            # materializes on host — the point of out-of-core ingest.
-            shard_is_device = isinstance(shard_data, jax.Array)
-            if shard_is_device and config.projector != ProjectorType.IDENTITY:
-                raise NotImplementedError(
-                    f"coordinate {coordinate_id!r}: projector "
-                    f"{config.projector.name} over a device-assembled "
-                    "(streamed) design shard would host-materialize it; "
-                    "IDENTITY only for now (ROADMAP item 5 follow-on)")
-            x = shard_data if shard_is_device else np.asarray(shard_data, dtype)
-            groups = None
-            if data.entity_stats is not None:
-                stats = data.entity_stats.get(config.random_effect_type)
-                if stats is not None:
-                    # per-entity grouping accumulated chunk-by-chunk during
-                    # streaming ingest; None on cap/seed mismatch -> the
-                    # bucketer rescans the host id column as usual
-                    groups = stats.groups(config.active_cap,
-                                          config.min_active_samples, seed,
-                                          existing_model_keys)
-            self.buckets = bucket_by_entity(
-                entity_ids, x, np.asarray(data.y, dtype),
-                offset=np.asarray(data.offset, dtype),
-                weight=np.asarray(data.weight, dtype),
-                active_cap=config.active_cap,
-                min_active_samples=config.min_active_samples,
-                lane_multiple=lane_multiple,
-                seed=seed, dtype=dtype,
-                existing_model_keys=existing_model_keys,
-                groups=groups,
-            )
-        # slot order for the stacked model = sorted entity id (stacked_coefficients)
-        self._sorted_ids = sorted(self.buckets.lane_of)
-        self._slot_of = {eid: i for i, eid in enumerate(self._sorted_ids)}
-        # per-bucket lane -> stacked-model row; invalid lanes get an
-        # out-of-range index so device scatters drop them (stack_bucket_lanes)
-        ne = len(self._sorted_ids)
-        self._slot_idx_dev = [
-            jnp.asarray(np.where(
-                (s := _slots_from(self._slot_of,
-                                  np.asarray(b.entity_lanes, np.int64))) < 0,
-                ne, s).astype(np.int32))
-            for b in self.buckets.buckets
-        ]
-        self._entity_ids = np.asarray(entity_ids, np.int64)
-        self._sample_slots = jnp.asarray(_slots_from(self._slot_of, self._entity_ids))
-        from photon_ml_tpu.utils.transfer import device_put_counted
-        self._x_full_is_t = False
-        if self._sparse:
-            # full-sample scoring stays sparse: [n, k] gather arrays, never
-            # an [n, d_full] densified design (score_samples_sparse)
-            self._x_idx_dev = device_put_counted(shard_data.indices, np.int32)
-            self._x_val_dev = device_put_counted(shard_data.values, dtype)
-        else:
-            # Narrow shards whose padded [n, d] footprint threatens HBM
-            # upload TRANSPOSED [d, n]: TPU tiling pads the minor axis to
-            # 128 lanes, so a [n, d<=32] array (and every scoring gather
-            # from it) occupies 128/d x its logical HBM bytes — 32x at
-            # glmix_chip's d=4, an OOM at 8.39M samples.  Small shards keep
-            # the row layout: the chip-measured crossover lives with
-            # score_samples_t in parallel/bucketing.py.
-            from photon_ml_tpu.parallel.bucketing import use_transposed_scoring
-            self._x_full_is_t = use_transposed_scoring(
-                x.shape[0], x.shape[1], np.dtype(dtype).itemsize)
-            self._x_full = device_put_counted(x.T if self._x_full_is_t else x)
-
-        # Optional per-entity feature projection (reference
-        # RandomEffectCoordinateInProjectedSpace.scala:149): solve each bucket
-        # in a compact feature space, back-project coefficients to full dim.
-        # (A sparse shard arrives here with self._proj already built — its
-        # buckets ARE the compact space.)
-        if not self._sparse:
-            self._proj = None
-            if config.projector != ProjectorType.IDENTITY:
-                from photon_ml_tpu.parallel.projection import project_buckets
-
-                self._proj = project_buckets(
-                    self.buckets, config.projector,
-                    projected_dim=config.projected_dim,
-                    features_to_samples_ratio=config.features_to_samples_ratio,
+                ratio = (config.features_to_samples_ratio
+                         if config.projector == ProjectorType.INDEX_MAP else None)
+                self.buckets, projections = bucket_by_entity_sparse(
+                    entity_ids, shard_data.indices, shard_data.values, self.dim,
+                    np.asarray(data.y, dtype),
+                    offset=np.asarray(data.offset, dtype),
+                    weight=np.asarray(data.weight, dtype),
+                    active_cap=config.active_cap,
+                    min_active_samples=config.min_active_samples,
+                    lane_multiple=lane_multiple, seed=seed, dtype=dtype,
+                    features_to_samples_ratio=ratio,
                     intercept_index=config.intercept_index,
-                    seed=seed,
+                    existing_model_keys=existing_model_keys,
                 )
-        solve_buckets = (self._proj.buckets if self._proj is not None
-                         else self.buckets.buckets)
-        if self._proj is not None:
-            # Device twins of each bucket's back-projection (gather indices /
-            # shared Gaussian matrix); they travel through sweep_data() into
-            # the fused program as arguments.  The Gaussian matrix is SHARED
-            # across buckets — upload it once, not once per bucket.
-            from photon_ml_tpu.parallel.projection import BucketProjection
+                self._proj = ProjectedBuckets(base=self.buckets,
+                                              buckets=self.buckets.buckets,
+                                              projections=projections)
+                if config.projector == ProjectorType.RANDOM:
+                    # RANDOM over a sparse shard: the shared Gaussian matrix's
+                    # rows GATHERED through each lane's observed-column map
+                    # project the compact design into d_proj — exactly what the
+                    # densified x @ A computes, because unobserved columns
+                    # contribute zero either way (reference builds the same
+                    # shared matrix per coordinate, ProjectionMatrixBroadcast
+                    # .scala:150; the full-vocabulary [E, S, d] tensors still
+                    # never exist).
+                    import dataclasses as _dc
 
-            # kinds are STATIC (python strings can't be jit-arg leaves);
-            # the arrays are the traced half
-            matrix_dev: Dict[int, Array] = {}
-            self._proj_kinds = []
-            self._proj_dev = []
-            for p in self._proj.projections:
-                if isinstance(p, BucketProjection):
-                    self._proj_kinds.append("index")
-                    self._proj_dev.append(jnp.asarray(p.indices))
-                else:
-                    self._proj_kinds.append("random")
-                    key = id(p.matrix)
-                    if key not in matrix_dev:  # one upload for the shared matrix
-                        matrix_dev[key] = jnp.asarray(p.matrix)
-                    self._proj_dev.append(matrix_dev[key])
-            self._proj_dev = tuple(self._proj_dev)
+                    from photon_ml_tpu.parallel.projection import (
+                        build_random_projection)
+
+                    if config.projected_dim is None:
+                        raise ValueError("RANDOM projection requires projected_dim")
+                    shared = build_random_projection(
+                        self.dim, config.projected_dim, seed, dtype=dtype,
+                        intercept_index=config.intercept_index)
+                    proj_buckets = []
+                    for b, p in zip(self.buckets.buckets, projections):
+                        safe = np.where(p.indices < 0, 0, p.indices)
+                        a_sub = shared.matrix[safe]  # [lanes, d_compact, d_proj]
+                        a_sub = np.where((p.indices >= 0)[:, :, None], a_sub, 0.0)
+                        x_proj = np.einsum("lsd,ldp->lsp", b.x,
+                                           a_sub).astype(dtype)
+                        proj_buckets.append(_dc.replace(b, x=x_proj))
+                    self._proj = ProjectedBuckets(
+                        base=self.buckets, buckets=proj_buckets,
+                        projections=[shared] * len(proj_buckets))
+            else:
+                # A streamed (device-assembled) dense shard stays on device: the
+                # bucketer gathers lanes on device, and the [n, d] array never
+                # materializes on host — the point of out-of-core ingest.
+                shard_is_device = isinstance(shard_data, jax.Array)
+                if shard_is_device and config.projector != ProjectorType.IDENTITY:
+                    raise NotImplementedError(
+                        f"coordinate {coordinate_id!r}: projector "
+                        f"{config.projector.name} over a device-assembled "
+                        "(streamed) design shard would host-materialize it; "
+                        "IDENTITY only for now (ROADMAP item 5 follow-on)")
+                x = shard_data if shard_is_device else np.asarray(shard_data, dtype)
+                groups = None
+                if data.entity_stats is not None:
+                    stats = data.entity_stats.get(config.random_effect_type)
+                    if stats is not None:
+                        # per-entity grouping accumulated chunk-by-chunk during
+                        # streaming ingest; None on cap/seed mismatch -> the
+                        # bucketer rescans the host id column as usual
+                        groups = stats.groups(config.active_cap,
+                                              config.min_active_samples, seed,
+                                              existing_model_keys)
+                self.buckets = bucket_by_entity(
+                    entity_ids, x, np.asarray(data.y, dtype),
+                    offset=np.asarray(data.offset, dtype),
+                    weight=np.asarray(data.weight, dtype),
+                    active_cap=config.active_cap,
+                    min_active_samples=config.min_active_samples,
+                    lane_multiple=lane_multiple,
+                    seed=seed, dtype=dtype,
+                    existing_model_keys=existing_model_keys,
+                    groups=groups,
+                )
+            # slot order for the stacked model = sorted entity id (stacked_coefficients)
+            self._sorted_ids = sorted(self.buckets.lane_of)
+            self._slot_of = {eid: i for i, eid in enumerate(self._sorted_ids)}
+            # per-bucket lane -> stacked-model row; invalid lanes get an
+            # out-of-range index so device scatters drop them (stack_bucket_lanes)
+            ne = len(self._sorted_ids)
+            self._slot_idx_dev = [
+                jnp.asarray(np.where(
+                    (s := _slots_from(self._slot_of,
+                                      np.asarray(b.entity_lanes, np.int64))) < 0,
+                    ne, s).astype(np.int32))
+                for b in self.buckets.buckets
+            ]
+            self._entity_ids = np.asarray(entity_ids, np.int64)
+            self._sample_slots = jnp.asarray(_slots_from(self._slot_of, self._entity_ids))
+        from photon_ml_tpu.utils.transfer import device_put_counted
+        with _upload_span(coordinate_id):
+            self._x_full_is_t = False
+            if self._sparse:
+                # full-sample scoring stays sparse: [n, k] gather arrays, never
+                # an [n, d_full] densified design (score_samples_sparse)
+                self._x_idx_dev = device_put_counted(shard_data.indices, np.int32)
+                self._x_val_dev = device_put_counted(shard_data.values, dtype)
+            else:
+                # Narrow shards whose padded [n, d] footprint threatens HBM
+                # upload TRANSPOSED [d, n]: TPU tiling pads the minor axis to
+                # 128 lanes, so a [n, d<=32] array (and every scoring gather
+                # from it) occupies 128/d x its logical HBM bytes — 32x at
+                # glmix_chip's d=4, an OOM at 8.39M samples.  Small shards keep
+                # the row layout: the chip-measured crossover lives with
+                # score_samples_t in parallel/bucketing.py.
+                from photon_ml_tpu.parallel.bucketing import use_transposed_scoring
+                self._x_full_is_t = use_transposed_scoring(
+                    x.shape[0], x.shape[1], np.dtype(dtype).itemsize)
+                self._x_full = device_put_counted(x.T if self._x_full_is_t else x)
+
+        with obs_span("coord.bucket", coordinate=coordinate_id):
+            # Optional per-entity feature projection (reference
+            # RandomEffectCoordinateInProjectedSpace.scala:149): solve each bucket
+            # in a compact feature space, back-project coefficients to full dim.
+            # (A sparse shard arrives here with self._proj already built — its
+            # buckets ARE the compact space.)
+            if not self._sparse:
+                self._proj = None
+                if config.projector != ProjectorType.IDENTITY:
+                    from photon_ml_tpu.parallel.projection import project_buckets
+
+                    self._proj = project_buckets(
+                        self.buckets, config.projector,
+                        projected_dim=config.projected_dim,
+                        features_to_samples_ratio=config.features_to_samples_ratio,
+                        intercept_index=config.intercept_index,
+                        seed=seed,
+                    )
+            solve_buckets = (self._proj.buckets if self._proj is not None
+                             else self.buckets.buckets)
+            if self._proj is not None:
+                # Device twins of each bucket's back-projection (gather indices /
+                # shared Gaussian matrix); they travel through sweep_data() into
+                # the fused program as arguments.  The Gaussian matrix is SHARED
+                # across buckets — upload it once, not once per bucket.
+                from photon_ml_tpu.parallel.projection import BucketProjection
+
+                # kinds are STATIC (python strings can't be jit-arg leaves);
+                # the arrays are the traced half
+                matrix_dev: Dict[int, Array] = {}
+                self._proj_kinds = []
+                self._proj_dev = []
+                for p in self._proj.projections:
+                    if isinstance(p, BucketProjection):
+                        self._proj_kinds.append("index")
+                        self._proj_dev.append(jnp.asarray(p.indices))
+                    else:
+                        self._proj_kinds.append("random")
+                        key = id(p.matrix)
+                        if key not in matrix_dev:  # one upload for the shared matrix
+                            matrix_dev[key] = jnp.asarray(p.matrix)
+                        self._proj_dev.append(matrix_dev[key])
+                self._proj_dev = tuple(self._proj_dev)
 
         self._bind_solver()
         self._refresh_lane_mult()
@@ -965,13 +990,14 @@ class RandomEffectCoordinate(Coordinate):
                 return bx.astype(sd)
             return np.asarray(bx).astype(sd)
 
-        self._dev = [
-            dict(x=put(_narrow(b.x)),
-                 y=put(b.y), w=put(b.weight),
-                 rows=put(np.where(b.rows < 0, 0, b.rows)),
-                 valid=put(b.rows >= 0))
-            for b in solve_buckets
-        ]
+        with _upload_span(coordinate_id):
+            self._dev = [
+                dict(x=put(_narrow(b.x)),
+                     y=put(b.y), w=put(b.weight),
+                     rows=put(np.where(b.rows < 0, 0, b.rows)),
+                     valid=put(b.rows >= 0))
+                for b in solve_buckets
+            ]
         # INDEX_MAP/sparse + normalization: project the coordinate context
         # into each entity's compact space (the reference's per-REId
         # contexts, NormalizationContextRDD through the per-entity
@@ -1494,16 +1520,12 @@ class RandomEffectCoordinate(Coordinate):
             # dispatch anyway; the fused sweep is where pipelining lives)
             with obs_span("solve.bucket", coordinate=self.coordinate_id,
                           bucket=bi, lanes=b.num_lanes,
-                          soa=self._use_soa) as sp:
+                          soa=self._use_soa):
                 t0 = _time.perf_counter()
-                # photonwatch attribution: host (vsolve dispatch) vs
-                # device (the block) split, stamped into the span's attrs
-                # and the xla_*_seconds{site=} families
-                with obs_attribute("solve.bucket", sp):
-                    res = self._vsolve(w0, dev["x"], dev["y"], off_b,
-                                       dev["w"], lane_regs[bi],
-                                       *self._solve_extras(bi))
-                    jax.block_until_ready(res.w)
+                res = self._vsolve(w0, dev["x"], dev["y"], off_b,
+                                   dev["w"], lane_regs[bi],
+                                   *self._solve_extras(bi))
+                jax.block_until_ready(res.w)
                 get_registry().observe(
                     "solve_bucket_seconds", _time.perf_counter() - t0,
                     coordinate=self.coordinate_id,
@@ -1678,20 +1700,29 @@ class RandomEffectCoordinate(Coordinate):
         offsets = offsets.astype(self._dtype)
         new_lanes = []
         for bi, (lanes, dev) in enumerate(zip(state, data["dev"])):
-            off_b = jnp.where(dev["valid"], offsets[dev["rows"]], 0.0)
-            res = self._vsolve(lanes, dev["x"], dev["y"], off_b, dev["w"],
-                               lane_regs[bi], *self._solve_extras(bi, data))
+            with device_scope("entity_gather"):
+                off_b = jnp.where(dev["valid"], offsets[dev["rows"]], 0.0)
+            with device_scope("entity_solve", f"b{bi}"):
+                res = self._vsolve(lanes, dev["x"], dev["y"], off_b,
+                                   dev["w"], lane_regs[bi],
+                                   *self._solve_extras(bi, data))
             new_lanes.append(res.w)
         w_stack = self.trace_publish(tuple(new_lanes), data=data)
-        if self._sparse:
-            score = score_samples_sparse(
-                w_stack, data["slots"], data["x_idx"], data["x_val"])[: self._n]
-        else:
-            score = self._score_dense_full(w_stack, data["slots"],
-                                           data["x_full"])[: self._n]
+        with device_scope("rescore"):
+            if self._sparse:
+                score = score_samples_sparse(
+                    w_stack, data["slots"], data["x_idx"],
+                    data["x_val"])[: self._n]
+            else:
+                score = self._score_dense_full(w_stack, data["slots"],
+                                               data["x_full"])[: self._n]
         return tuple(new_lanes), score
 
     def trace_publish(self, state: Tuple[Array, ...], data=None) -> Array:
+        with device_scope("publish"):
+            return self._trace_publish(state, data)
+
+    def _trace_publish(self, state: Tuple[Array, ...], data) -> Array:
         from photon_ml_tpu.parallel.bucketing import stack_bucket_lanes
 
         if self._norm is not None:

@@ -36,6 +36,9 @@ from jax import lax
 
 from photon_ml_tpu.game.coordinate import Coordinate
 from photon_ml_tpu.models.game import GameModel
+from photon_ml_tpu.obs.trace import (device_scope, get_tracer, hlo_op_table,
+                                     metadata_keyed_compile_cache)
+from photon_ml_tpu.obs.trace import enabled as obs_enabled
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.types import VarianceComputationType
 
@@ -79,6 +82,7 @@ class FusedSweep:
         self._grid_program = None  # built lazily by run_grid
         self._grid_snap_program = None  # built lazily by run_grid_snapshots
         self._val_program = None   # built lazily by run_validated
+        self._table_recorded = False  # the main program's op-to-layer table
 
         def program(states0, scores0, vars0, regs, base_key, base, datas):
             # regs: per-coordinate Regularization pytree, TRACED — a
@@ -105,12 +109,14 @@ class FusedSweep:
                         # published model (host-path semantics), so skip the
                         # curvature work on every earlier iteration — FULL
                         # variance is a d×d Hessian + Cholesky per lane.
-                        vars_[i] = lax.cond(
-                            it == self.num_iterations - 1,
-                            lambda s, o, r, k: coords[cid].trace_variances(
-                                s, o, reg=r, key=k, data=datas[i]),
-                            lambda s, o, r, k: vars_[i],
-                            states[i], base + partials[i], regs[i], keys[i])
+                        with device_scope("variances"):
+                            vars_[i] = lax.cond(
+                                it == self.num_iterations - 1,
+                                lambda s, o, r, k: coords[cid].trace_variances(
+                                    s, o, reg=r, key=k, data=datas[i]),
+                                lambda s, o, r, k: vars_[i],
+                                states[i], base + partials[i], regs[i],
+                                keys[i])
                 return (tuple(states), tuple(scores), tuple(vars_)), None
 
             carry, _ = lax.scan(body, (states0, scores0, vars0),
@@ -150,24 +156,32 @@ class FusedSweep:
         needs_rand = self._needs_rand
         states, scores = list(states), list(scores)
         partials, keys = [], []
-        total = scores[0]
-        # photonlint: disable=tracer-safety -- scores is a Python list with
-        # one entry per coordinate (static length at trace time); the loop
-        # unrolls over coordinates, not over a traced array's elements
-        for s in scores[1:]:
-            total = total + s
+        # device_scope: every op below carries its layer in the executable's
+        # metadata (obs/trace.py; the vocabulary is PERF.md section 3's)
+        with device_scope("residual"):
+            total = scores[0]
+            # photonlint: disable=tracer-safety -- scores is a Python list
+            # with one entry per coordinate (static length at trace time);
+            # the loop unrolls over coordinates, not over a traced array's
+            # elements
+            for s in scores[1:]:
+                total = total + s
         for i, cid in enumerate(order):
-            # residual trick (CoordinateDescent.scala:197-204)
-            partial = total - scores[i]
-            key = (jax.random.fold_in(it_key, i) if needs_rand[i] else None)
-            states[i], scores[i] = coords[cid].trace_update(
-                states[i], base + partial, reg=regs[i], key=key,
-                data=datas[i])
-            partials.append(partial)
-            keys.append(key)
-            total = partial + scores[i]
-            if on_update is not None:
-                on_update(i, cid, states[i])
+            with device_scope("update", cid):
+                with device_scope("residual"):
+                    # residual trick (CoordinateDescent.scala:197-204)
+                    partial = total - scores[i]
+                    offsets = base + partial
+                key = (jax.random.fold_in(it_key, i) if needs_rand[i]
+                       else None)
+                states[i], scores[i] = coords[cid].trace_update(
+                    states[i], offsets, reg=regs[i], key=key, data=datas[i])
+                partials.append(partial)
+                keys.append(key)
+                with device_scope("residual"):
+                    total = partial + scores[i]
+                if on_update is not None:
+                    on_update(i, cid, states[i])
         return states, scores, partials, keys
 
     def _init_carry(self, initial: Optional[GameModel]):
@@ -207,14 +221,42 @@ class FusedSweep:
         downloads — over slow transports those dominate) and for callers
         that pipeline further device work; ``run()`` wraps this with the
         host export."""
+        if obs_enabled() and not self._table_recorded:
+            self._record_device_table(initial, regs, seed, carry0)
+        # no fence: this is the ENQUEUE (argument preparation + dispatch),
+        # what the device waits for between back-to-back fits
+        with obs_span("descent.dispatch"):
+            args, carried = self._program_args(initial, regs, seed, carry0)
+            published, scores, vars_ = self._program(*args)
+        return published, scores, vars_, carried
+
+    def _program_args(self, initial, regs, seed, carry0):
+        """(the main program's positional arguments, carried scores)."""
         carry = carry0 if carry0 is not None else self.init_carry(initial)
         if regs is None:
             regs = tuple(self.coordinates[cid].config.reg for cid in self.order)
         base, carried = self._base_with_carry_through(initial)
-        published, scores, vars_ = self._program(
-            *carry, self._vars0, tuple(regs), jax.random.PRNGKey(seed),
-            base, self._datas)
-        return published, scores, vars_, carried
+        return (*carry, self._vars0, tuple(regs), jax.random.PRNGKey(seed),
+                base, self._datas), carried
+
+    def _record_device_table(self, initial, regs, seed, carry0) -> None:
+        """Once per sweep object, traced runs only: which layer each
+        instruction of the main program's executable belongs to, read off
+        that executable's own text and kept with the tracer
+        (``hlo_op_table``).  Lowers with the call's own arguments, so the
+        dispatch that follows finds this lowering and this executable in
+        jit's own caches: ONE executable serves the table, the run and an
+        operator's profile, and tracing adds no second compile or load.
+        Its persistent-cache key holds the metadata (the scopes are this
+        tree's, whoever filled the cache): the first traced run in a cache
+        compiles the program once more, later ones load it."""
+        self._table_recorded = True
+        with obs_span("descent.device_table"):
+            args, _ = self._program_args(initial, regs, seed, carry0)
+            with metadata_keyed_compile_cache():
+                text = self._program.lower(*args).compile().as_text()
+            get_tracer().record_device_table("jit_program",
+                                             hlo_op_table(text))
 
     def run(self, initial: Optional[GameModel] = None,
             regs: Optional[Sequence] = None, seed: int = 0,

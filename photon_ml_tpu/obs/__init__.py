@@ -7,8 +7,11 @@ shared by training AND serving:
 
   - ``trace``: nestable spans in a fixed-size ring buffer with a Chrome
     ``trace_event`` exporter (Perfetto-loadable), instant events bridged
-    from ``utils/events``, and opt-in per-span device fences
-    (``device_sync=True``) for device-accurate timings;
+    from ``utils/events``, opt-in per-span device fences
+    (``device_sync=True``) for device-accurate timings, and the device
+    half: ``trace.device_scope`` names the layers inside a compiled
+    program, ``trace.hlo_op_table`` reads instruction -> layer off its
+    executable;
   - ``registry``: one thread-safe ``MetricsRegistry`` — counters, gauges,
     fixed-bin latency histograms, label support — with Prometheus text
     exposition and JSON snapshots (``serving.ServingMetrics`` is a facade
@@ -16,8 +19,8 @@ shared by training AND serving:
   - ``probe``: ``JaxRuntimeProbe`` counting XLA compiles per call site and
     host<->device transfer bytes at the chunked-upload path;
   - ``watch``: the fleet-global plane (photonwatch) — metrics federation
-    (``DeltaExporter``/``FleetView``), multi-window SLO burn-rate alerting,
-    and span-aligned device-time attribution.
+    (``DeltaExporter``/``FleetView``) and multi-window SLO burn-rate
+    alerting.
 
 Tracing is disabled by default; the module-level ``span()``/``instant()``
 fast paths cost one boolean check when off (``bench.py --obs`` holds the

@@ -12,10 +12,10 @@ bridge) into a preallocated ring buffer and exports the Chrome
 serving requests on the same nested timeline.
 
 Concurrency model ("lock-free-ish"): every record claims a slot by bumping
-a cursor under a single lock — the lock protects ONLY the increment — and
-then fills the preallocated slot outside the lock.  Two writers can never
-share a slot; a reader (the exporter) skips slots whose sequence stamp says
-they are mid-write.  Slots are preallocated fixed-arity lists, so steady-
+a cursor under a single lock — on the record path the lock protects ONLY
+the increment — and then fills the preallocated slot outside the lock.  Two
+writers can never share a slot; a reader (the exporter) skips slots whose
+sequence stamp says they are mid-write.  Slots are preallocated fixed-arity lists, so steady-
 state tracing allocates nothing but the per-span attrs dict.
 
 Disabled cost: call sites go through the module-level ``span()`` /
@@ -31,13 +31,28 @@ and exit when tracing is enabled — enqueue a trivial op and block on it, so
 on an in-order accelerator stream the span brackets the actual device work.
 The fence costs a device round-trip, which is why it is per-span opt-in and
 completely absent when tracing is disabled.
+
+Device layers: inside ONE compiled program no host span can exist, so the
+program names its layers itself.  ``device_scope(layer, *ids)`` is a
+``jax.named_scope("photon.<layer>[.<id>...]")`` — trace-time metadata, free
+on the device, always on.  ``hlo_op_table(compiled.as_text())`` reads the
+other half of the join off the program's own executable: instruction name
+(what a ``jax.profiler`` trace prints on its ``XLA Ops`` line) -> ``op_name``
+path with the scopes in it.  The tracer keeps such tables by program name
+(``record_device_table``) and exports them under ``otherData`` next to the
+spans, so a reader of a device trace can say which layer ``fusion.71`` is.
+Enabled spans also open a ``jax.profiler.TraceAnnotation`` of the same name:
+they sit in the profiler's own host plane, on its clock.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
+import re
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -117,6 +132,139 @@ def _default_device_fence() -> None:
         pass
 
 
+def _profiler_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation(name)``, or None in a
+    process that never imported jax (nothing could be profiling it; the
+    tracer must not be what imports jax there).  Never raises."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
+        return ann
+    except Exception:
+        return None
+
+
+# -- device layers: scopes in the program, op-to-layer tables from it -------
+SCOPE_PREFIX = "photon."
+_SCOPE_ID = re.compile(r"[^A-Za-z0-9_]")
+
+
+def _scope_id(i) -> str:
+    return _SCOPE_ID.sub("_", str(i))
+
+
+def device_scope(layer: str, *ids):
+    """``with device_scope("update", cid):`` inside traced code: every op
+    traced within carries ``photon.update.<cid>`` in its HLO ``op_name``.
+    Metadata only — the device code is the same with or without it — so it
+    is always on.  The layer vocabulary is PERF.md section 3's; ids are
+    sanitised to ``[A-Za-z0-9_]`` ("/" and "." structure the path)."""
+    import jax
+
+    return jax.named_scope(".".join(
+        (SCOPE_PREFIX + layer, *map(_scope_id, ids))))
+
+
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = .*?\s([a-z][a-z0-9\-]*)\(")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+# computations that run as part of ONE instruction (a fusion, a reducer)
+_HLO_INSIDE = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+# computations whose instructions run as ops of their own, and what their
+# place in the caller adds to its path
+_HLO_CALLED = re.compile(
+    r"\b(body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}")
+_HLO_CALLED_AS = {"body": "/body", "condition": "/cond"}
+# never an event of their own on the device's op line
+_HLO_NO_EVENT = frozenset(("parameter", "constant", "get-tuple-element",
+                           "tuple", "bitcast"))
+
+
+def hlo_op_table(hlo_text: str) -> Dict[str, str]:
+    """Compiled HLO text (``jit(f).lower(...).compile().as_text()``) ->
+    ``{instruction name: op_name path}``, names as a device trace prints
+    them (no ``%``, no ``ROOT``).  Every instruction that can be an event
+    on the trace's op line is in it (fusions, ``while``, ``conditional``,
+    ``custom-call``, copies, ...); the insides of fusions and of reducers
+    are left out (a fusion runs as ONE op, charged to the scope of the
+    instruction that gave it its metadata).  An instruction the compiler
+    made itself carries no metadata: it takes the path of the ``while``,
+    ``conditional`` or ``call`` whose body it runs in (+ ``/body`` or
+    ``/cond``), and ``""`` where there is none (the entry computation)."""
+    ops: Dict[str, Dict[str, Optional[str]]] = {}  # computation -> its ops
+    inside = set()
+    callers: Dict[str, tuple] = {}  # computation -> (caller's, path, as)
+    computation = ""
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION.match(line)
+            if c is not None:
+                computation = c.group(1)
+                ops.setdefault(computation, {})
+            continue
+        name, opcode = m.groups()
+        found = _HLO_OP_NAME.search(line)
+        path = found.group(1) if found is not None else None
+        if opcode in ("while", "conditional", "call"):
+            for role, one, several in _HLO_CALLED.findall(line):
+                for called in ([one] if one else
+                               re.findall(r"[\w.\-]+", several)):
+                    callers[called] = (computation, path,
+                                       _HLO_CALLED_AS.get(role, ""))
+        else:
+            inside.update(_HLO_INSIDE.findall(line))
+        if opcode not in _HLO_NO_EVENT:
+            ops.setdefault(computation, {})[name] = path
+
+    def inherited(computation: str) -> str:
+        caller = callers.get(computation)
+        if caller is None:
+            return ""
+        callers_computation, path, called_as = caller
+        base = path or inherited(callers_computation)
+        return base + called_as if base else ""
+
+    table: Dict[str, str] = {}
+    for computation, its_ops in ops.items():
+        if computation in inside:
+            continue
+        scope = None
+        for name, path in its_ops.items():
+            if path is None:
+                if scope is None:
+                    scope = inherited(computation)
+                path = scope
+            table[name] = path
+    return table
+
+
+@contextlib.contextmanager
+def metadata_keyed_compile_cache():
+    """Compile inside with JAX's persistent cache keyed on HLO metadata.
+
+    By default the key leaves metadata out, so an executable loaded from a
+    cache another tree filled carries THAT tree's ``op_name``s: a table
+    read from it would name stale scopes, or none.  Keyed on metadata, the
+    executable is this tree's (compiled once more the first time, loaded
+    afterwards).  Scoped to the table's own compile: every other program
+    of the process keeps the default key and its cached executable."""
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, before)
+
+
 class _NoopSpan:
     """Shared do-nothing context manager for the disabled path."""
 
@@ -128,6 +276,9 @@ class _NoopSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -136,7 +287,7 @@ class _Span:
     """Active span handle; records the slot on exit."""
 
     __slots__ = ("_tracer", "_name", "_attrs", "_sync", "_t0", "_id",
-                 "_parent")
+                 "_parent", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Optional[Dict[str, Any]], sync: bool):
@@ -153,14 +304,24 @@ class _Span:
         stack.append(self._id)
         if self._sync:
             t.device_fence()
+        # the same span in the profiler's own host plane, on its clock
+        self._ann = _profiler_annotation(self._name)
         self._t0 = time.perf_counter_ns()
         return self
+
+    def set(self, **attrs) -> None:
+        """Attach attrs learned inside the span (bytes moved, rows)."""
+        if self._attrs is None:
+            self._attrs = {}
+        self._attrs.update(attrs)
 
     def __exit__(self, *exc) -> bool:
         t = self._tracer
         if self._sync:
             t.device_fence()
         dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = t._stack()
         if stack and stack[-1] == self._id:
             stack.pop()
@@ -194,6 +355,8 @@ class Tracer:
         # emits these as Chrome "thread_name" metadata so merged timelines
         # show "batcher-worker" instead of a bare ident
         self._thread_names: Dict[int, str] = {}
+        # program name ("jit_program") -> hlo_op_table of its executable
+        self._device_tables: Dict[str, Dict[str, str]] = {}
 
     # -- per-thread span stack ---------------------------------------------
     def _stack(self) -> List[int]:
@@ -263,6 +426,19 @@ class Tracer:
         slot[_ATTRS] = attrs
         slot[_SEQ] = seq + 1  # valid: seq stamps are 1-based, 0 = empty
 
+    def record_device_table(self, program: str,
+                            table: Dict[str, str]) -> None:
+        """Keep ``program``'s op-to-layer table (``hlo_op_table``) with the
+        spans: exported under ``otherData`` and read by the benchmark's
+        layer readers.  A re-recorded program replaces its table."""
+        if self.enabled:
+            with self._cursor_lock:
+                self._device_tables[program] = table
+
+    def device_tables(self) -> Dict[str, Dict[str, str]]:
+        with self._cursor_lock:
+            return dict(self._device_tables)
+
     def device_fence(self) -> None:
         self._fence()
 
@@ -283,6 +459,7 @@ class Tracer:
     def clear(self) -> None:
         with self._cursor_lock:
             self._cursor = 0
+            self._device_tables = {}
         for slot in self._slots:
             slot[_SEQ] = 0
 
@@ -340,6 +517,8 @@ class Tracer:
                 ev["s"] = "t"  # thread-scoped instant
             events.append(ev)
         other = {"process_label": label, "pid": pid}
+        if self._device_tables:
+            other["device_op_tables"] = self.device_tables()
         if _export_meta_provider is not None:
             try:
                 other.update(_export_meta_provider())

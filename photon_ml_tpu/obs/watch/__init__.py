@@ -11,9 +11,10 @@ exist across the constellation:
 * :mod:`slo` — declarative objectives evaluated as multi-window burn
   rates, publishing ``fleet_slo_burn_rate{slo=}`` gauges, latching alerts,
   and dumping the flight recorder on each burn edge.
-* :mod:`attribution` — span-aligned device-vs-host time split for the XLA
-  execute sites (``serve.execute``, ``solve.bucket``), exported as
-  ``xla_device_seconds{site=}`` and stamped into the Chrome trace.
+
+Device time per site is not measured here: the descent program names its
+layers on the device and says from its own executable which instruction
+belongs to which (``obs/trace.py``: ``device_scope``, ``hlo_op_table``).
 """
 
 from photon_ml_tpu.obs.watch.federation import (  # noqa: F401
@@ -26,11 +27,4 @@ from photon_ml_tpu.obs.watch.slo import (  # noqa: F401
     SLOEngine,
     SLOEvalThread,
     load_slos,
-)
-from photon_ml_tpu.obs.watch.attribution import (  # noqa: F401
-    attribute,
-    attribution_enabled,
-    disable_attribution,
-    enable_attribution,
-    set_device_timer,
 )

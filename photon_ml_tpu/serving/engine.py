@@ -38,7 +38,6 @@ from photon_ml_tpu.game.scoring import additive_total, output_scores
 from photon_ml_tpu.obs import get_probe
 from photon_ml_tpu.obs.trace import enabled as obs_enabled
 from photon_ml_tpu.obs.trace import span as obs_span
-from photon_ml_tpu.obs.watch.attribution import attribute as _attribute
 from photon_ml_tpu.parallel.bucketing import score_samples
 from photon_ml_tpu.serving.batcher import (AsyncBatcher, BucketedBatcher,
                                            Request, densify_features)
@@ -379,13 +378,9 @@ class ScoringEngine:
                 if tids:
                     attrs["traces"] = tids
             with obs_span("serve.execute", bucket=mb.bucket,
-                          rows=mb.real_rows, **attrs) as sp:
-                # photonwatch attribution: split this span into host
-                # (dispatch) vs device (drain) time — stamped into the
-                # span's attrs and the xla_*_seconds{site=} families
-                with _attribute("serve.execute", sp):
-                    scores = self._score_chunk(store, chunk, mb.bucket,
-                                               trace_attrs=attrs)
+                          rows=mb.real_rows, **attrs):
+                scores = self._score_chunk(store, chunk, mb.bucket,
+                                           trace_attrs=attrs)
             if out is None:
                 out = np.empty(n, scores.dtype)
             out[mb.start:mb.stop] = scores[: mb.real_rows]

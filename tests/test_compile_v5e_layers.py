@@ -1,0 +1,161 @@
+"""Compile rehearsal (on-chip-measurement guide, section 2): the two
+training cells' descent programs, at their dry-run sizes, compiled for a
+DESCRIBED v5e chip — no chip attached, nothing runs — and the op-to-layer
+table read off the executable's text.  What the CPU tests cannot show: the
+TPU compiler's own instruction names (the ones a device trace prints) land
+under the right ``photon.*`` scope, the Mosaic kernels and the gather
+fusions among them.
+
+The topology is described inside a module-scoped fixture, never at import,
+and every such compile of the repo's tests lives in this one file: only one
+process may hold the TPU's library, and a worker that imports this file must
+not load it.  ``has_tpu`` is patched here, in the test only, to steer the
+program onto its TPU branch (as benchmarks/tools/compile_for_v5e.py does).
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+sys.path[:0] = [BENCH, REPO]
+
+import run as harness  # noqa: E402
+
+from photon_ml_tpu.game.fused import FusedSweep  # noqa: E402
+from photon_ml_tpu.obs.trace import hlo_op_table  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled_text(one_chip):
+    """config -> the compiled HLO text of its descent program.  Around the
+    compiles the persistent cache is off (an executable compiled for a
+    described chip cannot be read back without one) and so is x64, which
+    tests/conftest.py turns on: the chip runs float32, and Mosaic cannot
+    lower the kernels' index arithmetic at 64 bits."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import photon_ml_tpu.ops.fused_glm as fused_glm
+    import photon_ml_tpu.ops.soa_newton as soa_newton
+
+    catalog = harness.Catalog()
+    train_fits = catalog.module("traffic", "train_fits")
+    patch = pytest.MonkeyPatch()
+    cache_was = jax.config.jax_enable_compilation_cache
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    compilation_cache.reset_cache()
+    texts = {}
+
+    def text_of(config):
+        if config not in texts:
+            cfg = harness.sized(catalog.json("configs", config), True)
+            recipe = catalog.module("recipes", cfg["recipe"])
+            coords = train_fits.build_coordinates(
+                cfg, recipe.make_training(cfg, 0), None)
+            sweep = FusedSweep(coords, num_iterations=int(cfg["sweeps"]))
+            args, _ = sweep._program_args(None, None, 0, None)
+            shapes = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip)
+                if hasattr(a, "shape") else a, args)
+            texts[config] = sweep._program.lower(*shapes).compile().as_text()
+        return texts[config]
+
+    patch.setattr(fused_glm, "has_tpu", lambda: True)
+    patch.setattr(soa_newton, "has_tpu", lambda: True)
+    try:
+        yield text_of
+    finally:
+        patch.undo()
+        jax.config.update("jax_enable_x64", x64_was)
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+def layers_of(table, prefix):
+    """{the photon.* scopes, joined} over the instructions named so."""
+    return {"/".join(p for p in path.split("/") if p.startswith("photon."))
+            for name, path in table.items() if name.startswith(prefix)}
+
+
+def gather_fusions(text, table):
+    """{scopes: result shape} of the gather fusions (``kind=kCustom``,
+    metadata ``.../gather``) the table holds."""
+    out = {}
+    for line in text.splitlines():
+        if "kind=kCustom" not in line or " fusion(" not in line:
+            continue
+        name = line.split(" = ", 1)[0].split("%")[-1]
+        if table.get(name, "").endswith("/gather"):
+            scopes = "/".join(p for p in table[name].split("/")
+                              if p.startswith("photon."))
+            out.setdefault(scopes, []).append(
+                line.split(" = ", 1)[1].split("{", 1)[0])
+    return out
+
+
+def test_glmix_chip_kernels_and_gathers_fall_under_their_layers(compiled_text):
+    text = compiled_text("glmix_chip")
+    table = hlo_op_table(text)
+    assert layers_of(table, "fused_glm_value_grad") == {
+        "photon.update.fixed/photon.fixed_solve"}
+    assert layers_of(table, "soa_newton_step") == {
+        "photon.update.per_user/photon.entity_solve.b0"}
+    # 128 users x 32 active rows gathered out of the 6,144 offsets; 6,144
+    # rows of w_stack gathered by slot (the row layout, at this size)
+    assert gather_fusions(text, table) == {
+        "photon.update.per_user/photon.entity_gather": ["f32[4096]"],
+        "photon.update.per_user/photon.rescore": ["f32[6144,4]"]}
+
+
+def test_glmix3_wide_solver_loops_fall_under_their_buckets(compiled_text):
+    text = compiled_text("glmix3_wide")
+    table = hlo_op_table(text)
+    assert "soa_newton_step" not in text
+    assert layers_of(table, "fused_glm_value_grad") == {
+        "photon.update.fixed/photon.fixed_solve"}
+    loops = {(scopes, path.count("while/body"))
+             for name, path in table.items() if name.startswith("while")
+             for scopes in ["/".join(p for p in path.split("/")
+                                     if p.startswith("photon."))]
+             if "entity_solve" in scopes}
+    for bucket in ("per_user/photon.entity_solve.b0",
+                   "per_item/photon.entity_solve.b0",
+                   "per_item/photon.entity_solve.b1"):
+        # the scan's body, then: the solver's loop, the line search in it
+        assert {("photon.update." + bucket, 1),
+                ("photon.update." + bucket, 2)} <= loops
+    gathers = gather_fusions(text, table)
+    assert {s.split("/")[-1] for s in gathers} >= {"photon.entity_gather",
+                                                   "photon.rescore"}
+
+
+@pytest.mark.parametrize("config", ["glmix_chip", "glmix3_wide"])
+def test_nearly_every_instruction_carries_a_scope(compiled_text, config):
+    table = hlo_op_table(compiled_text(config))
+    heavy = [n for n in table
+             if n.split(".")[0].endswith(("fusion", "while", "custom-call"))
+             or n.startswith(("fused_glm", "soa_newton"))]
+    bare = [n for n in heavy if "photon." not in table[n]]
+    assert len(heavy) > 50 and len(bare) <= 0.05 * len(heavy), bare

@@ -15,9 +15,6 @@ The contracts under test (ISSUE 20):
     cold-start burns are 0.0, alert latch edges (firing then resolved,
     exactly once each), ``fleet_slo_burn_rate`` gauges published, the
     firing edge dumps the flight recorder.
-  - attribution: device/host split accumulates ``xla_*_seconds{site=}``
-    and stamps ``device_us``/``host_us`` onto the enclosing span; the
-    disabled path hands back a shared no-op.
   - ``GET /watchz`` always-full pull and ``GET /fleetz`` on a
     FleetView-wired endpoint (404 without one).
   - AdmissionController ``fleet_burn_budget``: shed with reason
@@ -42,13 +39,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from photon_ml_tpu.obs import pulse
 from photon_ml_tpu.obs.registry import (MetricsRegistry, export_build_info,
                                         process_start_time)
-from photon_ml_tpu.obs.trace import Tracer, set_tracer, get_tracer
 from photon_ml_tpu.obs.watch import (SLO, DeltaExporter, FleetView,
-                                     SLOEngine, SLOEvalThread, attribute,
-                                     attribution_enabled,
-                                     disable_attribution,
-                                     enable_attribution, load_slos)
-from photon_ml_tpu.obs.watch.attribution import _NOOP
+                                     SLOEngine, SLOEvalThread, load_slos)
 from photon_ml_tpu.serving.frontend.admission import (SHED_FLEET,
                                                       AdmissionConfig,
                                                       AdmissionController)
@@ -349,49 +341,6 @@ class TestSLOEngine:
                 time.sleep(0.01)
         finally:
             thread.stop()
-
-
-# ---------------------------------------------------------------------------
-# attribution
-# ---------------------------------------------------------------------------
-class TestAttribution:
-    def teardown_method(self):
-        disable_attribution()
-
-    def test_disabled_returns_shared_noop(self):
-        disable_attribution()
-        assert not attribution_enabled()
-        assert attribute("serve.execute") is _NOOP
-        with attribute("serve.execute"):
-            pass  # no registry, no tracer touched
-
-    def test_split_accumulates_site_gauges(self):
-        reg = MetricsRegistry()
-        enable_attribution(reg)
-        with attribute("serve.execute"):
-            time.sleep(0.002)
-        with attribute("serve.execute"):
-            pass
-        dev = reg.gauge_series("xla_device_seconds")
-        host = reg.gauge_series("xla_host_seconds")
-        assert {dict(lk)["site"] for lk in dev} == {"serve.execute"}
-        assert {dict(lk)["site"] for lk in host} == {"serve.execute"}
-        assert list(host.values())[0] >= 0.002
-
-    def test_stamps_split_onto_enclosing_span(self):
-        reg = MetricsRegistry()
-        enable_attribution(reg)
-        prev = set_tracer(Tracer(capacity=64, enabled=True))
-        try:
-            tracer = get_tracer()
-            with tracer.span("serve.execute", bucket=8) as sp:
-                with attribute("serve.execute", sp):
-                    pass
-            events = tracer.chrome_trace()["traceEvents"]
-            ev = [e for e in events if e["name"] == "serve.execute"][-1]
-            assert "device_us" in ev["args"] and "host_us" in ev["args"]
-        finally:
-            set_tracer(prev)
 
 
 # ---------------------------------------------------------------------------
