@@ -789,8 +789,10 @@ class RandomEffectCoordinate(Coordinate):
                 f"coordinate {coordinate_id!r}: shift normalization under "
                 "per-entity compaction needs intercept_index (the per-lane "
                 "intercept column absorbs the projected margin shift)")
-        # coord.bucket: host grouping + the Python packing loops;
+        # coord.bucket: host grouping + the Python packing loops, the
+        # full-sample layout (coord.rescore_layout) inside it;
         # coord.upload: the design's way onto the device
+        narrow, runs = False, None  # a dense shard over the footprint line
         with obs_span("coord.bucket", coordinate=coordinate_id):
             if self._sparse:
                 # Row-sparse RE feature bag (the reference's per-entity sparse
@@ -862,6 +864,19 @@ class RandomEffectCoordinate(Coordinate):
                         "(streamed) design shard would host-materialize it; "
                         "IDENTITY only for now (ROADMAP item 5 follow-on)")
                 x = shard_data if shard_is_device else np.asarray(shard_data, dtype)
+                # Narrow shards whose padded [n, d] footprint threatens HBM
+                # keep the samples on the lanes: TPU tiling pads the minor
+                # axis to 128, so a [n, d<=32] array (and every scoring
+                # gather from it) occupies 128/d x its logical HBM bytes —
+                # 32x at glmix_chip's d=4, an OOM at 8.39M samples.  Which
+                # narrow layout, and the chip-measured crossover, live with
+                # score_samples_em in parallel/bucketing.py; the grouping
+                # it is laid out by is the bucketer's own.
+                from photon_ml_tpu.parallel.bucketing import (
+                    entity_runs, use_transposed_scoring)
+                narrow = use_transposed_scoring(
+                    x.shape[0], x.shape[1], np.dtype(dtype).itemsize)
+                runs = entity_runs(entity_ids) if narrow else None
                 groups = None
                 if data.entity_stats is not None:
                     stats = data.entity_stats.get(config.random_effect_type)
@@ -881,7 +896,7 @@ class RandomEffectCoordinate(Coordinate):
                     lane_multiple=lane_multiple,
                     seed=seed, dtype=dtype,
                     existing_model_keys=existing_model_keys,
-                    groups=groups,
+                    groups=groups, runs=runs,
                 )
             # slot order for the stacked model = sorted entity id (stacked_coefficients)
             self._sorted_ids = sorted(self.buckets.lane_of)
@@ -896,28 +911,53 @@ class RandomEffectCoordinate(Coordinate):
                     ne, s).astype(np.int32))
                 for b in self.buckets.buckets
             ]
-            self._entity_ids = np.asarray(entity_ids, np.int64)
-            self._sample_slots = jnp.asarray(_slots_from(self._slot_of, self._entity_ids))
+            # The full-sample design's layout (bucketing.py's note): sparse, or
+            # row-major [n, d], or over the padded-footprint line entity-major
+            # where the rows of an entity fill chunks, else transposed [d, n].
+            # Sample-order layouts score by per-SAMPLE slots (``_slot_ids``:
+            # the entity of each sample); the entity-major one by per-CHUNK
+            # slots (the entity of each chunk), its design and ``pos`` in the
+            # place of ``x_full`` and ``slots``, not beside them.
+            from photon_ml_tpu.parallel.bucketing import entity_major_layout
+            self._em = None
+            self._x_full_is_t = False
+            with obs_span("coord.rescore_layout",
+                          coordinate=coordinate_id) as layout_span:
+                if narrow:
+                    self._em = entity_major_layout(runs)
+                if self._em is not None:
+                    self._slot_ids = self._em.entities
+                    layout_span.set(layout="entity_major", chunk=self._em.chunk,
+                                    lanes=self._em.lanes, fill=self._em.fill,
+                                    identity=self._em.pos is None)
+                else:
+                    self._x_full_is_t = narrow
+                    self._slot_ids = np.asarray(entity_ids, np.int64)
+                    layout_span.set(layout="sparse" if self._sparse
+                                    else "transposed" if narrow
+                                    else "row_major")
+                slots = jnp.asarray(self._slots_under(self._slot_of))
+        from photon_ml_tpu.parallel.bucketing import entity_major_design
         from photon_ml_tpu.utils.transfer import device_put_counted
         with _upload_span(coordinate_id):
-            self._x_full_is_t = False
+            # what sweep_data() hands the rescore of _score_samples_full
             if self._sparse:
                 # full-sample scoring stays sparse: [n, k] gather arrays, never
                 # an [n, d_full] densified design (score_samples_sparse)
-                self._x_idx_dev = device_put_counted(shard_data.indices, np.int32)
-                self._x_val_dev = device_put_counted(shard_data.values, dtype)
+                self._full = dict(
+                    slots=slots,
+                    x_idx=device_put_counted(shard_data.indices, np.int32),
+                    x_val=device_put_counted(shard_data.values, dtype))
+            elif self._em is not None:
+                self._full = dict(
+                    lane_slot=slots,
+                    x_em=entity_major_design(self._em,
+                                             device_put_counted(x.T)),
+                    pos=None if self._em.pos is None
+                    else jnp.asarray(self._em.pos))
             else:
-                # Narrow shards whose padded [n, d] footprint threatens HBM
-                # upload TRANSPOSED [d, n]: TPU tiling pads the minor axis to
-                # 128 lanes, so a [n, d<=32] array (and every scoring gather
-                # from it) occupies 128/d x its logical HBM bytes — 32x at
-                # glmix_chip's d=4, an OOM at 8.39M samples.  Small shards keep
-                # the row layout: the chip-measured crossover lives with
-                # score_samples_t in parallel/bucketing.py.
-                from photon_ml_tpu.parallel.bucketing import use_transposed_scoring
-                self._x_full_is_t = use_transposed_scoring(
-                    x.shape[0], x.shape[1], np.dtype(dtype).itemsize)
-                self._x_full = device_put_counted(x.T if self._x_full_is_t else x)
+                self._full = dict(slots=slots, x_full=device_put_counted(
+                    x.T if self._x_full_is_t else x))
 
         with obs_span("coord.bucket", coordinate=coordinate_id):
             # Optional per-entity feature projection (reference
@@ -1602,10 +1642,34 @@ class RandomEffectCoordinate(Coordinate):
         return dataclasses.replace(model, w_stack=w_stack, slot_of=slot_of,
                                    variances=var_stack)
 
+    def _slots_under(self, slot_of: Dict[int, int],
+                     only: Optional[np.ndarray] = None) -> np.ndarray:
+        """The full-sample layout's slot vector under ANY slot map (``only``:
+        restricted to these entity ids, the rest -1): per sample, or per
+        CHUNK of the entity-major layout, where a chunk is one entity's and
+        a foreign model costs a lookup per entity, not per sample."""
+        slots = _slots_from(slot_of, self._slot_ids)
+        if only is not None:
+            slots = np.where(np.isin(self._slot_ids, only), slots,
+                             -1).astype(np.int32)
+        return slots if self._em is None else self._em.lane_slots(slots)
+
+    def _score_full(self, w_stack: np.ndarray, slot_of: Dict[int, int],
+                    only: Optional[np.ndarray] = None) -> np.ndarray:
+        """Host: every training sample's score under a model's ``w_stack``
+        and ``slot_of``: this coordinate's own map, or a foreign one (a
+        model trained elsewhere: an entity may be absent from our training
+        buckets yet present in the model)."""
+        data = self._full
+        if only is not None or slot_of != self._slot_of:
+            key = "slots" if self._em is None else "lane_slot"
+            data = dict(data, **{key: jnp.asarray(
+                self._slots_under(slot_of, only))})
+        w = jnp.asarray(np.asarray(w_stack, self._dtype))
+        return np.asarray(self._score_samples_full(w, data))[: self._n]
+
     def carry_through_scores(self, init: Optional[RandomEffectModel]
                              ) -> Optional[np.ndarray]:
-        from photon_ml_tpu.parallel.bucketing import score_samples_sparse
-
         if init is None:
             return None
         init = self._dense_init(init)
@@ -1614,44 +1678,29 @@ class RandomEffectCoordinate(Coordinate):
             np.int64)
         if carried.size == 0:
             return None
-        slots = _slots_from(init.slot_of, self._entity_ids)
-        slots = np.where(np.isin(self._entity_ids, carried),
-                         slots, -1).astype(np.int32)
-        w = jnp.asarray(np.asarray(init.w_stack, self._dtype))
-        if self._sparse:
-            s = score_samples_sparse(w, jnp.asarray(slots),
-                                     self._x_idx_dev, self._x_val_dev)
-        else:
-            s = self._score_dense_full(w, jnp.asarray(slots))
-        return np.asarray(s)[: self._n]
+        return self._score_full(init.w_stack, init.slot_of, only=carried)
 
     def score(self, model: RandomEffectModel) -> np.ndarray:
-        from photon_ml_tpu.parallel.bucketing import score_samples_sparse
+        return self._score_full(model.w_stack, model.slot_of)
 
-        w = jnp.asarray(np.asarray(model.w_stack, self._dtype))
-        if model.slot_of == self._slot_of:
-            slots = self._sample_slots
-        else:
-            # model trained elsewhere: remap the RAW entity ids through its
-            # slot map (an entity may be absent from our training buckets yet
-            # present in the model)
-            slots = jnp.asarray(_slots_from(model.slot_of, self._entity_ids))
-        if self._sparse:
-            return np.asarray(score_samples_sparse(
-                w, slots, self._x_idx_dev, self._x_val_dev))[: self._n]
-        return np.asarray(self._score_dense_full(w, slots))[: self._n]
-
-    def _score_dense_full(self, w_stack: Array, slots: Array,
-                          x_full: Optional[Array] = None) -> Array:
-        """Full-sample dense scoring in whichever layout ``_x_full`` uses:
-        [n, d], or [d, n] for narrow shards (bucketing.score_samples_t)."""
+    def _score_samples_full(self, w_stack: Array, data) -> Array:
+        """Every sample's score in whichever layout the full-sample design
+        has (``data``: what ``sweep_data`` passes of it): sparse,
+        entity-major, [d, n] or [n, d] (parallel/bucketing.py)."""
         from photon_ml_tpu.parallel.bucketing import (score_samples,
+                                                      score_samples_em,
+                                                      score_samples_sparse,
                                                       score_samples_t)
 
-        x = self._x_full if x_full is None else x_full
+        if self._sparse:
+            return score_samples_sparse(w_stack, data["slots"],
+                                        data["x_idx"], data["x_val"])
+        if self._em is not None:
+            return score_samples_em(w_stack, data["lane_slot"], data["x_em"],
+                                    data["pos"])
         if self._x_full_is_t:
-            return score_samples_t(w_stack, slots, x)
-        return score_samples(w_stack, slots, x)
+            return score_samples_t(w_stack, data["slots"], data["x_full"])
+        return score_samples(w_stack, data["slots"], data["x_full"])
 
     # --- traceable-step interface (game/fused.py) ---
     # State = tuple of per-bucket lane coefficient arrays [(lanes, d), ...].
@@ -1673,17 +1722,14 @@ class RandomEffectCoordinate(Coordinate):
         """Bucket design matrices, full-sample scoring arrays and (when
         projecting) back-projection arrays, passed into the fused program as
         arguments (see Coordinate.sweep_data)."""
-        d = dict(dev=self._dev, slots=self._sample_slots,
+        d = dict(dev=self._dev,
                  proj=self._proj_dev if self._proj is not None else None,
                  norm_fac=self._norm_fac_dev,
                  norm_shift=self._norm_shift_dev, norm_ii=self._norm_ii_dev,
                  box=self._box_lanes,
                  box_fill=None if self._box_fill is None
                  else jnp.asarray(self._box_fill))
-        if self._sparse:
-            d.update(x_idx=self._x_idx_dev, x_val=self._x_val_dev)
-        else:
-            d["x_full"] = self._x_full
+        d.update(self._full)
         return d
 
     def trace_update(self, state: Tuple[Array, ...], offsets: Array,
@@ -1691,8 +1737,6 @@ class RandomEffectCoordinate(Coordinate):
                      key=None, data=None) -> Tuple[Tuple[Array, ...], Array]:
         # ``key`` unused: random effects have no per-update stochastic work
         # (down-sampling is a fixed-effect-only config, as in the reference).
-        from photon_ml_tpu.parallel.bucketing import score_samples_sparse
-
         if data is None:
             data = self.sweep_data()
         reg = self.config.reg if reg is None else reg
@@ -1709,13 +1753,7 @@ class RandomEffectCoordinate(Coordinate):
             new_lanes.append(res.w)
         w_stack = self.trace_publish(tuple(new_lanes), data=data)
         with device_scope("rescore"):
-            if self._sparse:
-                score = score_samples_sparse(
-                    w_stack, data["slots"], data["x_idx"],
-                    data["x_val"])[: self._n]
-            else:
-                score = self._score_dense_full(w_stack, data["slots"],
-                                               data["x_full"])[: self._n]
+            score = self._score_samples_full(w_stack, data)[: self._n]
         return tuple(new_lanes), score
 
     def trace_publish(self, state: Tuple[Array, ...], data=None) -> Array:

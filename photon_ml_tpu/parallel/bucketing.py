@@ -104,6 +104,27 @@ class EntityBuckets:
         return np.asarray(sorted(self.lane_of), np.int64)
 
 
+EntityRuns = Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]
+
+
+def entity_runs(entity_ids: np.ndarray) -> EntityRuns:
+    """``(entities [U], counts [U], order [n])``: the sorted distinct
+    entity ids, the rows of each, and ALL rows grouped by entity in that
+    order, each entity's in sample order (a stable sort).  What the
+    bucketers cut their lanes from and what ``entity_major_layout`` lays the
+    full-sample design out by: computed once per coordinate.  Rows that
+    arrive grouped already (ids ascending) are recognised in one pass and
+    skip the sort: ``order`` is then None, for ``arange(n)``."""
+    ids = np.asarray(entity_ids, np.int64)
+    n = len(ids)
+    if n and np.all(ids[1:] >= ids[:-1]):
+        starts = np.flatnonzero(np.r_[True, ids[1:] != ids[:-1]])
+        return ids[starts], np.diff(np.r_[starts, n]), None
+    uniq, inverse, counts = np.unique(ids, return_inverse=True,
+                                      return_counts=True)
+    return uniq, counts, np.argsort(inverse, kind="stable")
+
+
 def _group_rows(
     entity_ids: np.ndarray,
     active_cap: Optional[int],
@@ -111,6 +132,7 @@ def _group_rows(
     seed: int,
     existing_model_keys: Optional[frozenset] = None,
     row_ids: Optional[np.ndarray] = None,
+    runs: Optional[EntityRuns] = None,
 ) -> Tuple[List[np.ndarray], List[int], List[float]]:
     """Group sample rows by entity with the deterministic reservoir cap +
     weight rescale count/cap (reference RandomEffectDataset.scala:358-420)
@@ -128,10 +150,12 @@ def _group_rows(
     reads, parallel/multihost.py).  Reservoir keys mix the global id, so an
     entity keeps the SAME samples no matter how many hosts the data is split
     over — the recompute-stable property the reference gets from hashing
-    uniqueId (RandomEffectDataset.scala:394-401), extended across topology."""
-    uniq, inverse, counts = np.unique(entity_ids, return_inverse=True,
-                                      return_counts=True)
-    order = np.argsort(inverse, kind="stable")  # rows grouped by entity
+    uniqueId (RandomEffectDataset.scala:394-401), extended across topology.
+
+    ``runs``: ``entity_runs(entity_ids)`` where the caller has it already."""
+    uniq, counts, order = entity_runs(entity_ids) if runs is None else runs
+    if order is None:
+        order = np.arange(len(entity_ids))
     starts = np.concatenate([[0], np.cumsum(counts)])
 
     kept_rows: List[np.ndarray] = []
@@ -205,6 +229,7 @@ def bucket_by_entity(
     row_ids: Optional[np.ndarray] = None,
     num_samples: Optional[int] = None,
     groups: Optional[Tuple[List[np.ndarray], List[int], List[float]]] = None,
+    runs: Optional[EntityRuns] = None,
 ) -> EntityBuckets:
     """Group samples by entity into power-of-two-capacity buckets.
 
@@ -225,6 +250,8 @@ def bucket_by_entity(
       ``_group_rows`` scan; it must have been built with the SAME cap /
       min-active / seed / warm-start arguments (EntityStats.groups enforces
       the cap+seed half and returns None on mismatch).
+    - ``runs``: ``entity_runs(entity_ids)`` where the caller has it already
+      (the coordinate lays its full-sample design out by the same grouping).
 
     ``x`` may be a device-resident ``jax.Array`` (streaming ingest
     assembles design shards on device): the per-lane design blocks are then
@@ -256,7 +283,8 @@ def bucket_by_entity(
     else:
         kept_rows, kept_entities, rescale = _group_rows(
             entity_ids, active_cap, min_active_samples, seed,
-            existing_model_keys=existing_model_keys, row_ids=row_ids)
+            existing_model_keys=existing_model_keys, row_ids=row_ids,
+            runs=runs)
 
     # Capacity classes: next power of two of the active count.
     caps = _capacity_classes(kept_rows)
@@ -520,30 +548,40 @@ def score_samples(w_stack: Array, slots: Array, x: Array) -> Array:
     return jnp.where(slots >= 0, margins, 0.0)
 
 
-NARROW_SCORE_DIM_MAX = 32  # [d, n] layout only ever helps below this width
-# Measured crossover for the transposed layout (v5e, round-5 shipped-code
-# checklist vs the run-1 pre-swap numbers, TPU_CHECKLIST.json):
-#   - glmix2  [524288, 16] f32  -> padded [n, d] is 268 MB; the einsum row
-#     layout is 1.56x FASTER (0.47s vs 0.73s per sweep) — the pad fits HBM
-#     and XLA fuses the single gather+einsum better than d serial passes.
-#   - glmix_chip [8.39M, 4] bf16 -> padded [n, d] is 2.1 GB and the scoring
-#     HLO materializes two of them: OOM on a 16 GB chip. Transposed layout
-#     is the only way this config EXISTS on the v5e.
-# So the gate is the padded-HBM footprint (n x 128 lanes x itemsize), not
-# the width alone: transpose only when the pad is an actual memory threat.
+NARROW_SCORE_DIM_MAX = 32  # the narrow layouts only ever help below this width
+# Three layouts of a coordinate's full-sample design, and ONE rule over what
+# the coordinate can observe of its data (RandomEffectCoordinate.__init__):
+#   - row-major [n, d] (``score_samples``) under the padded-footprint line
+#     below.  v5e, round-5 checklist (TPU_CHECKLIST.json): glmix2
+#     [524288, 16] f32 pads to 268 MB and the one gather + einsum was 1.56x
+#     FASTER than d serial passes (0.47 s vs 0.73 s a sweep).  No benchmark
+#     cell sits under the line yet (PERF.md section 7, ROADMAP D3).
+#   - over the line, where TPU tiling would pad the narrow minor axis to 128
+#     lanes (glmix_chip [8.39M, 4]: 2.1 GB a copy and two copies in the
+#     scoring HLO, an OOM on a 16 GB chip), the design is stored with the
+#     samples on the lanes.  ENTITY-MAJOR (``entity_major_layout`` +
+#     ``score_samples_em``) where the rows of an entity fill chunks of C rows
+#     within 1.3x padding: a coefficient is gathered once per chunk.
+#   - TRANSPOSED [d, n] in sample order (``score_samples_t``) where no C
+#     exists (fewer than ~30 rows an entity): a coefficient is gathered once
+#     per row and column.  The chip showed what that costs (PERF.md section 6,
+#     PR 23): the time is INDICES, 7 to 29 ns each, not bytes: d x 8.39M of
+#     them were 86.6% of glmix_chip's busy time and 49.7% of glmix3_wide's.
 NARROW_SCORE_PAD_BYTES_MIN = 1 << 30
 
 
 def use_transposed_scoring(n: int, d: int, itemsize: int) -> bool:
-    """True when full-sample dense scoring should use the [d, n]
-    samples-on-lanes layout (``score_samples_t``) instead of row-major
-    [n, d] (``score_samples``).  See the crossover note above."""
+    """True when full-sample dense scoring should keep the samples on the
+    lanes (``score_samples_em`` or ``score_samples_t``) instead of row-major
+    [n, d] (``score_samples``).  See the note above."""
     return (d <= NARROW_SCORE_DIM_MAX
             and n * 128 * itemsize >= NARROW_SCORE_PAD_BYTES_MIN)
 
 
 def score_samples_t(w_stack: Array, slots: Array, x_t: Array) -> Array:
-    """``score_samples`` for a TRANSPOSED [d, n] full-sample array.
+    """``score_samples`` for a TRANSPOSED [d, n] full-sample array in sample
+    order: the narrow layout for data that finds no entity-major chunk
+    (``entity_major_chunk`` is None).
 
     TPU tiling pads an array's minor axis to 128 lanes, so a narrow [n, d]
     design (random-effect shards are typically d<=16 wide) occupies 128/d x
@@ -551,7 +589,10 @@ def score_samples_t(w_stack: Array, slots: Array, x_t: Array) -> Array:
     at d=4, which turned glmix_chip's 8.39M-sample scoring into 2 x 4GB of
     HLO temp and OOMed a 16GB v5e (bench round 5).  Samples-on-lanes layout
     keeps every large intermediate 1-D over n: d static gathers of [E]
-    coefficient columns, and no padded [n, d] array ever exists.
+    coefficient columns, and no padded [n, d] array ever exists.  Each of
+    the d gathers has n indices, and on the v5e the indices are the cost
+    (7.4 ns each where PR 23 measured them, up to 28.9): ``score_samples_em``
+    issues n / C of them a column.
     """
     safe = jnp.where(slots >= 0, slots, 0)
     w_t = w_stack.T  # [d, E]: entities on lanes, tiny either way
@@ -560,6 +601,166 @@ def score_samples_t(w_stack: Array, slots: Array, x_t: Array) -> Array:
     for j in range(x_t.shape[0]):  # d is static and small by contract
         acc = acc + x_t[j] * w_t[j][safe]
     return jnp.where(slots >= 0, acc, 0.0)
+
+
+EM_ROW = 128  # lanes of a stored row: a chunk is EM_ROW / k of them
+EM_CHUNK_MIN = 8
+EM_PAD_MAX = 1.3  # padded rows over rows; per-item at C = 128 pads to 1.25
+
+
+def entity_major_chunk(counts: np.ndarray) -> Optional[int]:
+    """The chunk length C of the entity-major layout, read off the rows of
+    each entity: the largest power of two in [EM_CHUNK_MIN, EM_ROW] at which
+    cutting every entity's rows into chunks of C, the last one padded, keeps
+    the padded row count within EM_PAD_MAX of the real one.  A longer chunk
+    is fewer gathered indices; a long entity takes several chunks, so a
+    heavy tail costs nothing.  None where there is none (under ~30 rows an
+    entity on average)."""
+    counts = np.asarray(counts, np.int64)
+    c = EM_ROW
+    while c >= EM_CHUNK_MIN:
+        if int((-(-counts // c)).sum()) * c <= EM_PAD_MAX * int(counts.sum()):
+            return c
+        c //= 2
+    return None
+
+
+@dataclasses.dataclass
+class EntityMajorLayout:
+    """Where each sample sits when every entity's rows are contiguous.
+
+    The design is stored ``[d, R, EM_ROW]``: R rows of 128 samples, each
+    row ``k = EM_ROW / chunk`` chunks of ``chunk`` samples, a chunk all one
+    entity's (the last chunk of an entity zero-padded; chunks that only
+    complete the last row belong to nobody).  Chunk ``(i, r)`` covers flat
+    positions ``r * EM_ROW + i * chunk + [0, chunk)``.
+
+    ``entities``: the sorted distinct entity ids of ALL rows [U].
+    ``chunk_entity`` [k, R] int32: index into ``entities`` of each chunk's
+    entity, -1 for nobody's.  ``pos`` [n] int32: flat position of sample i,
+    or None when the layout IS the sample order (``pos == arange(n)``: no
+    padding but behind the last sample)."""
+
+    chunk: int
+    entities: np.ndarray
+    chunk_entity: np.ndarray
+    pos: Optional[np.ndarray]
+    num_samples: int
+
+    @property
+    def lanes(self) -> int:
+        return self.chunk_entity.size
+
+    @property
+    def fill(self) -> float:
+        return self.num_samples / (self.lanes * self.chunk)
+
+    def lane_slots(self, slot_of_entity: np.ndarray) -> np.ndarray:
+        """[k, R] int32 stacked-model row of each chunk from a per-ENTITY
+        slot vector aligned with ``entities`` (-1: no model, scores 0)."""
+        ce = self.chunk_entity
+        return np.where(ce >= 0, np.asarray(slot_of_entity, np.int32)[ce],
+                        -1).astype(np.int32)
+
+    def source_rows(self) -> np.ndarray:
+        """[R * EM_ROW] int32: the sample stored at each flat position,
+        ``num_samples`` (one past the last row) at padding."""
+        n = self.num_samples
+        src = np.full(self.lanes * self.chunk, n, np.int32)
+        src[slice(n) if self.pos is None else self.pos] = np.arange(
+            n, dtype=np.int32)
+        return src
+
+
+def entity_major_layout(runs: EntityRuns) -> Optional[EntityMajorLayout]:
+    """The entity-major layout of ``entity_runs``' grouping, or None where
+    ``entity_major_chunk`` finds no chunk length.  Counts and row order
+    only: the design is not touched."""
+    entities, counts, order = runs
+    c = entity_major_chunk(counts)
+    if c is None:
+        return None
+    n, k = int(counts.sum()), EM_ROW // c
+    chunks = -(-counts // c)                      # chunks of each entity
+    first = np.cumsum(chunks) - chunks            # its first chunk
+    starts = np.cumsum(counts) - counts           # its first grouped row
+    rows = -(-int(chunks.sum()) // k)             # R
+    ce = np.full(rows * k, -1, np.int32)
+    ce[:int(chunks.sum())] = np.repeat(
+        np.arange(len(counts), dtype=np.int32), chunks)
+    pos = None
+    if order is not None or np.any(counts[:-1] % c):
+        # grouped row g of entity e sits at first[e] * C + (g - starts[e])
+        pos = (np.arange(n) + np.repeat(first * c - starts, counts)
+               ).astype(np.int32)
+        if order is not None:
+            grouped, pos = pos, np.empty(n, np.int32)
+            pos[order] = grouped
+    return EntityMajorLayout(chunk=c, entities=entities,
+                             chunk_entity=np.ascontiguousarray(
+                                 ce.reshape(rows, k).T),
+                             pos=pos, num_samples=n)
+
+
+# photonlint: disable=sharding-annotation -- set-up, on one device: the
+# full-sample design is an unsharded device_put under a mesh too (as the
+# [d, n] design was), and the output is its only consumer's argument
+@jax.jit
+def _columns_at(x_t: Array, src: Array) -> Array:
+    return jnp.take(x_t, src, axis=1, mode="fill",
+                    fill_value=0).reshape(x_t.shape[0], -1, EM_ROW)
+
+
+def entity_major_design(layout: EntityMajorLayout, x_t: Array) -> Array:
+    """The transposed design ``x_t`` [d, n], on the device in sample order,
+    stored by ``layout``: [d, R, EM_ROW], padding zero.  A reshape where the
+    layout is the sample order; else ONE gather of whole columns on the
+    device, at set-up (0.19 to 0.26 s for 8.39M x 16 f32 on a v5e, where
+    numpy takes 2.0 to 3.4 s on the chip's host: my chip run, PR 24).
+    Returns when ``x_t`` is no longer needed: a coordinate that went on
+    uploading its buckets while both designs were alive raised the
+    process's peak device memory by 0.9 GB."""
+    d, n = x_t.shape
+    if layout.pos is None and layout.lanes * layout.chunk == n:
+        out = x_t.reshape(d, -1, EM_ROW)
+    else:
+        out = _columns_at(x_t, jnp.asarray(layout.source_rows()))
+    return jax.block_until_ready(out)
+
+
+def score_samples_em(w_stack: Array, lane_slot: Array, x_em: Array,
+                     pos: Optional[Array] = None) -> Array:
+    """``score_samples`` for an ENTITY-MAJOR design (``EntityMajorLayout``):
+    ``x_em`` [d, R, EM_ROW], ``lane_slot`` [k, R] the stacked-model row of
+    each chunk (-1: no model, its samples score exactly 0), ``pos`` the flat
+    position of each sample or None where the layout is the sample order
+    (the result is then [R * EM_ROW]: the samples, then the tail's zeros).
+
+    The same d products a sample as ``score_samples_t``, summed in the same
+    order in the same promoted dtype; but a coefficient is gathered once per
+    CHUNK (k x R = n / C indices a column, not n) and spread along the chunk
+    in registers, and where the samples do not arrive entity-major ONE
+    n-sized gather puts the finished scores back into sample order.
+    """
+    k, _ = lane_slot.shape
+    has = lane_slot >= 0
+    safe = jnp.where(has, lane_slot, 0)
+    w_t = w_stack.T  # [d, E]
+    chunk_of_lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1, EM_ROW), 1) // (EM_ROW // k)
+
+    def along_chunks(v):  # [k, R] a chunk -> [R, EM_ROW] (or [R, 1]) a sample
+        out = v[0][:, None]
+        for i in range(1, k):
+            out = jnp.where(chunk_of_lane == i, v[i][:, None], out)
+        return out
+
+    acc = jnp.zeros(x_em.shape[1:],
+                    jnp.promote_types(x_em.dtype, w_stack.dtype))
+    for j in range(x_em.shape[0]):  # d is static and small by contract
+        acc = acc + x_em[j] * along_chunks(w_t[j][safe])
+    acc = jnp.where(along_chunks(has), acc, 0.0).reshape(-1)
+    return acc if pos is None else acc[pos]
 
 
 def score_samples_sparse(w_stack: Array, slots: Array, indices: Array,

@@ -131,3 +131,151 @@ def test_score_samples_t_property(n, ne, d, seed, bf16):
         jnp.asarray(w), jnp.asarray(slots), xa.T), np.float64)
     np.testing.assert_allclose(a, b, **tol)
     assert (b[slots < 0] == 0).all()
+
+
+# -- the entity-major full-sample layout (ISSUE 24) ---------------------------
+
+def _row_counts(pattern, ne, rng):
+    """Rows of each of ``ne`` entities under a named pattern."""
+    if pattern == "equal":
+        return np.full(ne, int(rng.choice([8, 16, 48, 64, 128])))
+    if pattern == "one_heavy":  # one entity holds most rows
+        return np.r_[rng.integers(30, 90, size=ne - 1), 3000]
+    if pattern == "single_rows":  # 1-row entities beside a few long ones
+        return np.r_[np.ones(ne, np.int64), rng.integers(400, 900, size=3)]
+    return rng.integers(40, 300, size=ne)  # "ragged"
+
+
+@settings(max_examples=30, deadline=None)
+@given(pattern=st.sampled_from(["equal", "one_heavy", "single_rows",
+                                "ragged"]),
+       ne=st.integers(2, 12), d=st.integers(1, 24),
+       order=st.sampled_from(["sorted", "shuffled", "descending"]),
+       absent=st.floats(0.0, 0.6), seed=st.integers(0, 2**31 - 1),
+       bf16=st.booleans())
+def test_score_samples_em_property(pattern, ne, d, order, absent, seed, bf16):
+    """Entity-major layout + scorer == row-major gather scoring on the same
+    rows for ANY row-count pattern, sample order, width and storage dtype,
+    bitwise equal to the transposed scorer (the same products in the same
+    order), and exactly 0 where the entity has no model."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    counts = _row_counts(pattern, ne, rng)
+    ids = np.repeat(rng.choice(10_000, size=len(counts), replace=False) - 7,
+                    counts).astype(np.int64)
+    ids = {"sorted": np.sort, "shuffled": rng.permutation,
+           "descending": lambda v: np.sort(v)[::-1].copy()}[order](ids)
+    n = len(ids)
+    runs = bucketing.entity_runs(ids)
+    layout = bucketing.entity_major_layout(runs)
+    assert layout is not None, counts
+    assert layout.chunk == bucketing.entity_major_chunk(runs[1])
+    # every sample has a place of its own, inside a chunk of its entity
+    pos = np.arange(n) if layout.pos is None else layout.pos
+    assert len(np.unique(pos)) == n
+    k = bucketing.EM_ROW // layout.chunk
+    chunk_of = layout.chunk_entity.T.reshape(-1)[pos // layout.chunk]
+    np.testing.assert_array_equal(layout.entities[chunk_of], ids)
+    assert layout.chunk_entity.shape[0] == k
+    assert (layout.pos is None) == np.array_equal(pos, np.arange(n))
+
+    ne_all = len(layout.entities)
+    slot_of_entity = rng.permutation(ne_all).astype(np.int32)
+    slot_of_entity[rng.random(ne_all) < absent] = -1
+    slots = slot_of_entity[np.searchsorted(layout.entities, ids)]
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    w = jnp.asarray(rng.normal(size=(ne_all, d)).astype(np.float32))
+    xa = jnp.asarray(x)
+    tol = dict(rtol=1e-5, atol=1e-6)
+    if bf16:
+        xa = xa.astype(jnp.bfloat16)
+        tol = dict(rtol=2e-2, atol=2e-2)
+    x_em = bucketing.entity_major_design(layout, xa.T)
+    assert x_em.dtype == xa.dtype
+    assert x_em.shape == (d, layout.lanes // k, bucketing.EM_ROW)
+    got = np.asarray(bucketing.score_samples_em(
+        w, jnp.asarray(layout.lane_slots(slot_of_entity)), x_em,
+        None if layout.pos is None else jnp.asarray(layout.pos)))[:n]
+    want = np.asarray(bucketing.score_samples(w, jnp.asarray(slots), xa),
+                      np.float64)
+    np.testing.assert_allclose(got.astype(np.float64), want, **tol)
+    np.testing.assert_array_equal(got, np.asarray(bucketing.score_samples_t(
+        w, jnp.asarray(slots), xa.T)))
+    assert (got[slots < 0] == 0).all()
+
+
+def _cell_entity_ids(config, coordinate, full):
+    """The entity column of one of the benchmark's three random-effect
+    coordinates as its recipe makes it (benchmarks/recipes), at the full or
+    the dry-run size: ids only, no design."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run as harness
+
+    catalog = harness.Catalog()
+    cfg = harness.sized(catalog.json("configs", config), not full)
+    recipe = catalog.module("recipes", cfg["recipe"])
+    if config == "glmix_chip":  # chip_signal.make_training's own line
+        s = recipe.sizes(cfg)
+        return np.repeat(np.arange(s["users"], dtype=np.int64), s["per_user"])
+    uids, iids = recipe.entity_columns(cfg, 3)
+    return uids if coordinate == "per-user" else iids
+
+
+@pytest.mark.parametrize("config, coordinate, full, chunk, fill, identity", [
+    ("glmix_chip", "per-user", True, 64, 1.0, True),
+    ("glmix3_wide", "per-user", True, 128, 1.0, False),  # rows shuffled
+    ("glmix3_wide", "per-item", True, 128, 0.8, False),  # 240 and 272 rows
+    ("glmix_chip", "per-user", False, 16, 1.0, True),    # 48 rows each
+    ("glmix3_wide", "per-user", False, 32, 1.0, False),
+    ("glmix3_wide", "per-item", False, 64, 0.8, False),  # 120 and 136
+])
+def test_entity_major_rule_on_the_cells(config, coordinate, full, chunk, fill,
+                                        identity):
+    """The chunk length is read off the row counts: the benchmark's three
+    random-effect coordinates, from their entity columns alone."""
+    ids = _cell_entity_ids(config, coordinate, full)
+    runs = bucketing.entity_runs(ids)
+    assert bucketing.entity_major_chunk(runs[1]) == chunk
+    layout = bucketing.entity_major_layout(runs)
+    assert layout.chunk == chunk and layout.fill == pytest.approx(fill)
+    assert layout.lanes * chunk * fill == pytest.approx(len(ids))
+    assert (layout.pos is None) == identity
+
+
+@pytest.mark.parametrize("counts, chunk", [
+    (np.full(1000, 10), None),   # 16 of 10 at C = 8: [d, n] as before
+    (np.full(1000, 24), 8),
+    (np.full(1000, 31), 32),
+    (np.full(10, 129), 32),      # 160 of 129; 256 and 192 are too many
+    (np.r_[np.ones(1000, np.int64), 100_000], 16),  # a long entity takes
+    # many chunks: only the 1-row entities pad
+])
+def test_entity_major_chunk_rule(counts, chunk):
+    """The largest power of two in [8, 128] within 1.3x padding, or none."""
+    assert bucketing.entity_major_chunk(counts) == chunk
+    ids = np.repeat(np.arange(len(counts), dtype=np.int64), counts)
+    layout = bucketing.entity_major_layout(bucketing.entity_runs(ids))
+    assert (layout is None) == (chunk is None)
+
+
+@settings(max_examples=40, deadline=None)
+@given(ids=_ids, cap=st.integers(1, 8), seed=st.integers(0, 2**31 - 1),
+       sort=st.booleans())
+def test_group_rows_from_shared_runs(ids, cap, seed, sort):
+    """``entity_runs`` handed to the bucketer groups as its own scan does,
+    for rows that arrive grouped (no sort, ``order`` None) and anywhere."""
+    if sort:
+        ids = np.sort(ids)
+    runs = bucketing.entity_runs(ids)
+    assert (runs[2] is None) == bool(np.all(ids[1:] >= ids[:-1]))
+    uniq, counts = np.unique(ids, return_counts=True)
+    np.testing.assert_array_equal(runs[0], uniq)
+    np.testing.assert_array_equal(runs[1], counts)
+    a = bucketing._group_rows(ids, cap, 1, seed)
+    b = bucketing._group_rows(ids, cap, 1, seed, runs=runs)
+    assert all(np.array_equal(x, y) for x, y in zip(a[0], b[0]))
+    assert a[1] == b[1] and a[2] == b[2]

@@ -184,6 +184,53 @@ def test_traced_sweep_records_its_table(cells, config):
     assert names.count("descent.dispatch") == 2
 
 
+# -- (ii-b) the entity-major rescore: one gather a chunk, one a sample ----------
+
+@pytest.mark.parametrize("config, want", [
+    # (n-sized gathers, chunk gathers a column) per random-effect scope
+    ("glmix_chip", {"per_user": (0, 4 * [384])}),        # rows entity-major
+    ("glmix3_wide", {"per_user": (1, 16 * [96]),         # rows anywhere
+                     "per_item": (1, 16 * [60])}),
+])
+def test_entity_major_rescore_gathers(monkeypatch, config, want):
+    """The dry-run sizes sit under the padded-footprint line, so lower it
+    here (the program has no knob for it): each random-effect coordinate
+    then rescores from the entity-major layout, and its own executable
+    holds, under ``photon.update.<cid>/photon.rescore``, d gathers of one
+    entry a CHUNK and at most ONE gather of n entries, none where the rows
+    arrive entity-major."""
+    import re
+
+    from photon_ml_tpu.parallel import bucketing
+
+    monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 1)
+    catalog = harness.Catalog()
+    cfg = harness.sized(catalog.json("configs", config), True)
+    data = catalog.module("recipes", cfg["recipe"]).make_training(cfg, 5)
+    coords = catalog.module("traffic", "train_fits").build_coordinates(
+        cfg, data, None)
+    n = len(data["y"])
+    sweep = FusedSweep(coords, num_iterations=int(cfg["sweeps"]))
+    args, _ = sweep._program_args(None, None, 0, None)
+    text = sweep._program.lower(*args).compile().as_text()
+    found = {}
+    for line in text.splitlines():
+        path = re.search(r'op_name="([^"]*)"', line)
+        if " gather(" not in line or path is None:
+            continue
+        path = path.group(1)
+        if layer_join.layer_of(path) != "rescore":
+            continue
+        shape = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+        found.setdefault(layer_join.coordinate_of(path), []).append(
+            math.prod(int(v) for v in shape.split(",")))
+    assert set(found) == set(want)
+    for cid, (big, chunks) in want.items():
+        assert sorted(found[cid]) == sorted(chunks + big * [n]), (cid, n)
+    published, scores, _, _ = sweep.run_device()
+    assert all(np.isfinite(np.asarray(s)).all() for s in scores)
+
+
 # -- (iii) tracer off: nothing lowered ----------------------------------------
 
 class CountingProgram:

@@ -314,6 +314,195 @@ def test_transposed_scoring_gate_is_padded_bytes():
     assert not use_transposed_scoring(n_edge - 1, 4, 4)
 
 
+def _capped_glmix(rng, shuffled):
+    """A small two-coordinate GLMix whose per-user effect has an active cap
+    (half of each user's rows are passive: scored, not trained on)."""
+    from photon_ml_tpu.core.regularization import Regularization
+    from photon_ml_tpu.game import (FixedEffectConfig, GameData,
+                                    RandomEffectConfig)
+    from photon_ml_tpu.opt.types import SolverConfig
+
+    n_users, per_user, dg, du = 12, 32, 6, 3
+    n = n_users * per_user
+    uids = np.repeat(np.arange(n_users) * 3 + 1, per_user)
+    if shuffled:
+        uids = rng.permutation(uids)
+    data = GameData(
+        y=(rng.random(n) < 0.5).astype(float),
+        features={"g": rng.normal(size=(n, dg)), "u": rng.normal(size=(n, du))},
+        id_tags={"userId": uids})
+    solver = SolverConfig(max_iters=40, tolerance=1e-9)
+    cfgs = {
+        "fixed": FixedEffectConfig(feature_shard="g", solver=solver,
+                                   reg=Regularization(l2=1.0)),
+        "user": RandomEffectConfig(random_effect_type="userId",
+                                   feature_shard="u", solver=solver,
+                                   reg=Regularization(l2=1.0), active_cap=16),
+    }
+    return data, cfgs
+
+
+@pytest.mark.parametrize("shuffled", [False, True],
+                         ids=["entity_major_rows", "rows_anywhere"])
+def test_entity_major_rescore_matches_host_descent(rng, monkeypatch,
+                                                   shuffled):
+    """The entity-major full-sample layout (ISSUE 24) is a pure layout
+    change: with the padded-footprint line lowered so that a small shard
+    takes it, the fused sweep equals the host loop (which scores through
+    ``score(model)``), equals the row-major build of the same data, scores
+    the capped-out rows too, and serves a FOREIGN slot map and the
+    carry-through from per-chunk slots."""
+    import dataclasses
+
+    from photon_ml_tpu.game.coordinate import build_coordinate
+    from photon_ml_tpu.game.descent import CoordinateDescent
+    from photon_ml_tpu.game.fused import FusedSweep
+    from photon_ml_tpu.parallel import bucketing
+    from photon_ml_tpu.types import TaskType
+
+    data, cfgs = _capped_glmix(rng, shuffled)
+    task = TaskType.LOGISTIC_REGRESSION
+
+    def build():
+        return {cid: build_coordinate(cid, data, c, task)
+                for cid, c in cfgs.items()}
+
+    row_major = build()
+    assert row_major["user"]._em is None
+    monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 1)
+    coords = build()
+    user = coords["user"]
+    assert user._em is not None and user._em.chunk == 32
+    assert (user._em.pos is None) == (not shuffled)
+    # in the place of the sample-order design and slots, not beside them
+    assert sorted(user._full) == ["lane_slot", "pos", "x_em"]
+    assert sorted(row_major["user"]._full) == ["slots", "x_full"]
+
+    fused, fused_scores = FusedSweep(coords, num_iterations=2).run()
+    host, _, _ = CoordinateDescent(coords, num_iterations=2).run()
+    plain, plain_scores = FusedSweep(row_major, num_iterations=2).run()
+    for other in (host, plain):
+        assert other["user"].slot_of == fused["user"].slot_of
+        np.testing.assert_allclose(fused["user"].w_stack,
+                                   other["user"].w_stack,
+                                   rtol=2e-3, atol=2e-3)
+        np.testing.assert_allclose(fused["fixed"].coefficients.means,
+                                   other["fixed"].coefficients.means,
+                                   rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(fused_scores["user"], plain_scores["user"],
+                               rtol=2e-3, atol=2e-3)
+
+    # every row is scored, the passive half of each user's included
+    model = fused["user"]
+    x, uids = data.features["u"], data.id_tags["userId"]
+    want = np.einsum("nd,nd->n", x, model.w_stack[
+        [model.slot_of[int(u)] for u in uids]])
+    np.testing.assert_allclose(fused_scores["user"], want, rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(user.score(model), want, rtol=1e-5, atol=1e-5)
+
+    # a model trained elsewhere: other slot order, one user of ours missing
+    # (scores exactly 0), one we never saw
+    ids = sorted(model.slot_of)
+    gone = ids[2]
+    foreign_of = {e: i for i, e in enumerate(reversed(ids + [10_000]))
+                  if e != gone}
+    foreign_w = rng.normal(size=(len(ids) + 1, x.shape[1]))
+    foreign = dataclasses.replace(model, w_stack=foreign_w,
+                                  slot_of=foreign_of)
+    want = np.where(uids == gone, 0.0, np.einsum(
+        "nd,nd->n", x, foreign_w[[foreign_of.get(int(u), 0) for u in uids]]))
+    got = user.score(foreign)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert (got[uids == gone] == 0).all()
+    np.testing.assert_allclose(got, row_major["user"].score(foreign),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_entity_major_carry_through_scores(rng, monkeypatch):
+    """A prior model's entity that this run does not retrain (under the
+    lower bound, covered by the prior model) keeps scoring its rows: the
+    entity-major layout serves the carry-through from per-chunk slots, and
+    only the carried entity's rows score."""
+    import dataclasses
+
+    from photon_ml_tpu.game.coordinate import build_coordinate
+    from photon_ml_tpu.models.game import RandomEffectModel
+    from photon_ml_tpu.parallel import bucketing
+    from photon_ml_tpu.types import TaskType
+
+    data, cfgs = _capped_glmix(rng, shuffled=True)
+    uids = data.id_tags["userId"].copy()
+    rare = int(uids[0])
+    keep = np.flatnonzero(uids == rare)[:4]  # 4 rows stay the rare user's
+    uids[np.setdiff1d(np.flatnonzero(uids == rare), keep)] = 4  # another's
+    data = dataclasses.replace(data, id_tags={"userId": uids})
+    cfg = dataclasses.replace(cfgs["user"], min_active_samples=8)
+    x = data.features["u"]
+    prior = RandomEffectModel(
+        w_stack=rng.normal(size=(2, x.shape[1])), slot_of={rare: 1, 777: 0},
+        random_effect_type="userId", feature_shard="u",
+        task=TaskType.LOGISTIC_REGRESSION)
+    want = np.where(uids == rare, x @ prior.w_stack[1], 0.0)
+
+    def carried():
+        coord = build_coordinate("user", data, cfg,
+                                 TaskType.LOGISTIC_REGRESSION,
+                                 existing_model_keys=frozenset(prior.slot_of))
+        assert rare not in coord._slot_of
+        return coord, coord.carry_through_scores(prior)
+
+    coord, plain = carried()
+    assert coord._em is None
+    monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 1)
+    coord, got = carried()
+    assert coord._em is not None
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got, plain, rtol=1e-5, atol=1e-6)
+    assert (got[uids != rare] == 0).all()
+
+
+@pytest.mark.parametrize("line, per_user, layout", [
+    (1, 32, dict(layout="entity_major", chunk=32, lanes=12, fill=1.0,
+                 identity=True)),
+    (1, 10, dict(layout="transposed")),  # 10 rows a user: no chunk
+    (None, 32, dict(layout="row_major")),
+])
+def test_rescore_layout_span_says_what_engaged(rng, monkeypatch, line,
+                                               per_user, layout):
+    """``coord.rescore_layout`` (inside ``coord.bucket``): which of the
+    three dense layouts the coordinate took, and the entity-major one's
+    chunk, lanes, fill and whether it is the sample order."""
+    from photon_ml_tpu import obs
+    from photon_ml_tpu.game import GameData, RandomEffectConfig
+    from photon_ml_tpu.game.coordinate import build_coordinate
+    from photon_ml_tpu.obs.trace import Tracer, set_tracer
+    from photon_ml_tpu.parallel import bucketing
+    from photon_ml_tpu.types import TaskType
+
+    if line is not None:
+        monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", line)
+    n = 12 * per_user
+    data = GameData(y=(rng.random(n) < 0.5).astype(float),
+                    features={"u": rng.normal(size=(n, 3))},
+                    id_tags={"userId": np.repeat(np.arange(12), per_user)})
+    prev = set_tracer(Tracer(capacity=256, enabled=True))
+    try:
+        coord = build_coordinate(
+            "user", data, RandomEffectConfig(random_effect_type="userId",
+                                             feature_shard="u"),
+            TaskType.LOGISTIC_REGRESSION)
+        records = obs.get_tracer().records()
+    finally:
+        set_tracer(prev)
+    spans = [r for r in records if r["name"] == "coord.rescore_layout"]
+    assert [r["attrs"] for r in spans] == [dict(coordinate="user", **layout)]
+    assert spans[0]["parent"] in {r["id"] for r in records
+                                  if r["name"] == "coord.bucket"}
+    assert (coord._em is not None) == (layout["layout"] == "entity_major")
+    assert coord._x_full_is_t == (layout["layout"] == "transposed")
+
+
 def test_scoring_unknown_entity_is_zero(rng):
     eids, x, y = _entity_data(rng, n_entities=3)
     obj = GLMObjective(loss=losses.logistic_loss)
