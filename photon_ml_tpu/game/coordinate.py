@@ -107,6 +107,13 @@ class Coordinate:
     def dtype(self):
         return self._dtype
 
+    @property
+    def num_solves(self) -> int:
+        """Solves an update makes (rows of ``trace_update``'s
+        ``iterations_out`` entry): one, or a random effect's capacity
+        classes."""
+        return 1
+
     def init_sweep_state(self, init: Optional[DatumScoringModel] = None):
         """Host: initial device state (cold or warm-started from a model)."""
         raise NotImplementedError
@@ -121,7 +128,9 @@ class Coordinate:
 
     def trace_update(self, state, offsets: Array,
                      reg: "Optional[Regularization]" = None,
-                     key=None, data=None) -> Tuple[object, Array]:
+                     key=None, data=None,
+                     iterations_out: Optional[list] = None
+                     ) -> Tuple[object, Array]:
         """Traceable: one update against residual-folded ``offsets[n]``;
         returns (state', this coordinate's new score[n]).  ``reg`` (possibly
         traced) overrides the config's regularization weights so one compiled
@@ -130,7 +139,11 @@ class Coordinate:
         (down-sampling); coordinates without such work ignore it.  ``data``:
         this coordinate's ``sweep_data()`` passed back as traced arguments
         (None = read the coordinate's own device arrays, the host-paced
-        path)."""
+        path).  ``iterations_out``: a list the update appends ONE int32
+        array [solves, 2] to: for each of its solves (a fixed effect has
+        one, a random effect one per capacity class) the sum and the
+        maximum of ``SolverResult.iterations`` over the solve's problems;
+        a vmapped solve runs as many trips as its slowest problem."""
         raise NotImplementedError
 
     def trace_publish(self, state, data=None) -> Array:
@@ -271,6 +284,7 @@ class FixedEffectCoordinate(Coordinate):
         # would transfer f32 and transiently hold both copies in HBM).
         x_dtype = _storage_np_dtype(config.storage_dtype) or dtype
         from photon_ml_tpu.ops.fused_glm import (_pick_block_rows, eligible,
+                                                 runs_in_place,
                                                  storage_narrowing_ok)
         from photon_ml_tpu.parallel.mesh import (DATA_AXIS, FEATURE_AXIS,
                                                  padded_dim)
@@ -323,11 +337,17 @@ class FixedEffectCoordinate(Coordinate):
             n_dev = 1 if mesh is None else mesh.shape[DATA_AXIS]
             pad_to = None
             if fused_ok:
-                # pad so each device's LOCAL shard is a block multiple
+                # pad so each device's LOCAL shard is a block multiple.  On
+                # one device a design of MANY blocks stays as it is: the
+                # kernels take its whole blocks in place and its last rows
+                # as a batch of their own (fused_glm.runs_in_place), where
+                # padding would copy all of it beside the original that
+                # the caller's data still holds
                 local = -(-batch.num_examples // n_dev)
                 bn = _pick_block_rows(local, batch.dim,
                                       np.dtype(batch.x.dtype).itemsize)
-                pad_to = (-(-local // bn) * bn) * n_dev
+                if mesh is not None or not runs_in_place(local, bn):
+                    pad_to = (-(-local // bn) * bn) * n_dev
             if mesh is not None:
                 batch = shard_batch(
                     batch, mesh, pad_to=pad_to,
@@ -571,13 +591,18 @@ class FixedEffectCoordinate(Coordinate):
 
     def trace_update(self, state: Array, offsets: Array,
                      reg: Optional[Regularization] = None,
-                     key=None, data=None) -> Tuple[Array, Array]:
+                     key=None, data=None,
+                     iterations_out: Optional[list] = None
+                     ) -> Tuple[Array, Array]:
         batch = self._batch if data is None else data
         with device_scope("fixed_solve"):
             offs, weights = self._sweep_batch_inputs(offsets, key, batch)
             res = self._solve(state,
                               batch.replace(offset=offs, weight=weights),
                               self.config.reg if reg is None else reg)
+            if iterations_out is not None:
+                iterations_out.append(jnp.broadcast_to(
+                    res.iterations.astype(jnp.int32), (1, 2)))
         w_pub = self.trace_publish(res.w)
         with device_scope("rescore"):
             if self._fs and isinstance(batch, SparseBatch):
@@ -793,7 +818,7 @@ class RandomEffectCoordinate(Coordinate):
         # full-sample layout (coord.rescore_layout) inside it;
         # coord.upload: the design's way onto the device
         narrow, runs = False, None  # a dense shard over the footprint line
-        with obs_span("coord.bucket", coordinate=coordinate_id):
+        with obs_span("coord.bucket", coordinate=coordinate_id) as bucket_span:
             if self._sparse:
                 # Row-sparse RE feature bag (the reference's per-entity sparse
                 # LocalDataset, data/LocalDataset.scala:35-247): each entity
@@ -898,6 +923,18 @@ class RandomEffectCoordinate(Coordinate):
                     existing_model_keys=existing_model_keys,
                     groups=groups, runs=runs,
                 )
+            # what the bucketer made of the rows per entity: one vmapped
+            # solve per class, lanes x capacity slots of which active_rows
+            # hold a row; passive rows are scored and never trained on
+            classes = self.buckets.buckets
+            bucket_span.set(
+                classes=len(classes),
+                capacities=[b.capacity for b in classes],
+                lanes=[b.num_lanes for b in classes],
+                slots=sum(b.num_lanes * b.capacity for b in classes),
+                active_rows=sum(int(b.counts.sum()) for b in classes),
+                capped_entities=self.buckets.capped_entities,
+                passive_rows=self.buckets.passive_rows)
             # slot order for the stacked model = sorted entity id (stacked_coefficients)
             self._sorted_ids = sorted(self.buckets.lane_of)
             self._slot_of = {eid: i for i, eid in enumerate(self._sorted_ids)}
@@ -1705,6 +1742,10 @@ class RandomEffectCoordinate(Coordinate):
     # --- traceable-step interface (game/fused.py) ---
     # State = tuple of per-bucket lane coefficient arrays [(lanes, d), ...].
 
+    @property
+    def num_solves(self) -> int:
+        return len(self.buckets.buckets)
+
     def init_sweep_state(self, init: Optional[RandomEffectModel] = None) -> Tuple[Array, ...]:
         init = self._dense_init(init)
         lanes = []
@@ -1734,7 +1775,9 @@ class RandomEffectCoordinate(Coordinate):
 
     def trace_update(self, state: Tuple[Array, ...], offsets: Array,
                      reg: Optional[Regularization] = None,
-                     key=None, data=None) -> Tuple[Tuple[Array, ...], Array]:
+                     key=None, data=None,
+                     iterations_out: Optional[list] = None
+                     ) -> Tuple[Tuple[Array, ...], Array]:
         # ``key`` unused: random effects have no per-update stochastic work
         # (down-sampling is a fixed-effect-only config, as in the reference).
         if data is None:
@@ -1742,7 +1785,7 @@ class RandomEffectCoordinate(Coordinate):
         reg = self.config.reg if reg is None else reg
         lane_regs = self._lane_regs(reg)
         offsets = offsets.astype(self._dtype)
-        new_lanes = []
+        new_lanes, iterations = [], []
         for bi, (lanes, dev) in enumerate(zip(state, data["dev"])):
             with device_scope("entity_gather"):
                 off_b = jnp.where(dev["valid"], offsets[dev["rows"]], 0.0)
@@ -1750,7 +1793,14 @@ class RandomEffectCoordinate(Coordinate):
                 res = self._vsolve(lanes, dev["x"], dev["y"], off_b,
                                    dev["w"], lane_regs[bi],
                                    *self._solve_extras(bi, data))
+                if iterations_out is not None:
+                    # an entity's lane holds a row in its first slot; a
+                    # padding lane (a mesh's lane multiple) counts for none
+                    its = jnp.where(dev["valid"][:, 0], res.iterations, 0)
+                    iterations.append(jnp.stack([its.sum(), its.max()]))
             new_lanes.append(res.w)
+        if iterations_out is not None:
+            iterations_out.append(jnp.stack(iterations).astype(jnp.int32))
         w_stack = self.trace_publish(tuple(new_lanes), data=data)
         with device_scope("rescore"):
             score = self._score_samples_full(w_stack, data)[: self._n]

@@ -83,6 +83,7 @@ class FusedSweep:
         self._grid_snap_program = None  # built lazily by run_grid_snapshots
         self._val_program = None   # built lazily by run_validated
         self._table_recorded = False  # the main program's op-to-layer table
+        self.solve_iterations = None  # the last run_device's (see there)
 
         def program(states0, scores0, vars0, regs, base_key, base, datas):
             # regs: per-coordinate Regularization pytree, TRACED — a
@@ -97,12 +98,14 @@ class FusedSweep:
             # as ARGUMENTS — closed-over device arrays would lower to baked
             # XLA constants, with compile time linear in constant bytes.
             def body(carry, it):
-                states, scores, vars_ = carry
+                states, scores, vars_, solved = carry
                 vars_ = list(vars_)
                 it_key = (jax.random.fold_in(base_key, it)
                           if any(needs_rand) else None)
+                iterations = []
                 states, scores, partials, keys = self._sweep_iteration(
-                    states, scores, regs, it_key, base, datas)
+                    states, scores, regs, it_key, base, datas,
+                    iterations_out=iterations)
                 for i, cid in enumerate(order):
                     if needs_var[i]:
                         # Only the LAST update's variances survive into the
@@ -117,15 +120,25 @@ class FusedSweep:
                                 lambda s, o, r, k: vars_[i],
                                 states[i], base + partials[i], regs[i],
                                 keys[i])
-                return (tuple(states), tuple(scores), tuple(vars_)), None
+                with device_scope("solve_iterations"):
+                    solved = tuple(a.at[it].set(new)
+                                   for a, new in zip(solved, iterations))
+                return (tuple(states), tuple(scores), tuple(vars_),
+                        solved), None
 
-            carry, _ = lax.scan(body, (states0, scores0, vars0),
+            # solved[i]: int32 [num_iterations, solves, 2], the sum and the
+            # maximum of solver iterations over each solve's problems
+            # (Coordinate.trace_update's ``iterations_out``)
+            solved0 = tuple(
+                jnp.zeros((self.num_iterations, coords[cid].num_solves, 2),
+                          jnp.int32) for cid in order)
+            carry, _ = lax.scan(body, (states0, scores0, vars0, solved0),
                                 jnp.arange(self.num_iterations))
-            states, scores, vars_ = carry
+            states, scores, vars_, solved = carry
             published = tuple(coords[cid].trace_publish(states[i],
                                                         data=datas[i])
                               for i, cid in enumerate(order))
-            return published, scores, vars_
+            return published, scores, vars_, solved
 
         self._program_fn = program  # unjitted: the grid path vmaps it
         self._program = jax.jit(program)
@@ -140,7 +153,7 @@ class FusedSweep:
                             for cid in self.order)
 
     def _sweep_iteration(self, states, scores, regs, it_key, base, datas,
-                         on_update=None):
+                         on_update=None, iterations_out=None):
         """Traceable: ONE outer iteration's coordinate loop — the single
         source of the descent math (residual fold + per-coordinate update,
         CoordinateDescent.scala:197-204) shared by the main program, the
@@ -151,7 +164,9 @@ class FusedSweep:
         down-sampling mask as the published coefficients, so it re-uses both
         rather than re-deriving them.  ``on_update(i, cid, state_i)``:
         traced hook after each coordinate's update (the validated program's
-        per-update held-out bookkeeping)."""
+        per-update held-out bookkeeping).  ``iterations_out``: a list that
+        gets one array of solver-iteration counts per coordinate
+        (``Coordinate.trace_update``)."""
         order, coords = self.order, self.coordinates
         needs_rand = self._needs_rand
         states, scores = list(states), list(scores)
@@ -175,7 +190,8 @@ class FusedSweep:
                 key = (jax.random.fold_in(it_key, i) if needs_rand[i]
                        else None)
                 states[i], scores[i] = coords[cid].trace_update(
-                    states[i], offsets, reg=regs[i], key=key, data=datas[i])
+                    states[i], offsets, reg=regs[i], key=key, data=datas[i],
+                    iterations_out=iterations_out)
                 partials.append(partial)
                 keys.append(key)
                 with device_scope("residual"):
@@ -220,14 +236,26 @@ class FusedSweep:
         to host.  For benchmarking (time the sweep, not the [n]-vector
         downloads — over slow transports those dominate) and for callers
         that pipeline further device work; ``run()`` wraps this with the
-        host export."""
+        host export.  The program's fourth output, the solver iterations
+        of every solve of every update, stays on the device as
+        ``self.solve_iterations`` (one int32 [num_iterations, solves, 2] a
+        coordinate, sum and maximum over the solve's problems); a traced
+        run fetches it into the span ``descent.solve_iterations``, which
+        waits for the program."""
         if obs_enabled() and not self._table_recorded:
             self._record_device_table(initial, regs, seed, carry0)
         # no fence: this is the ENQUEUE (argument preparation + dispatch),
         # what the device waits for between back-to-back fits
         with obs_span("descent.dispatch"):
             args, carried = self._program_args(initial, regs, seed, carry0)
-            published, scores, vars_ = self._program(*args)
+            published, scores, vars_, self.solve_iterations = self._program(
+                *args)
+        if obs_enabled():
+            with obs_span("descent.solve_iterations") as sp:
+                fetched = jax.device_get(self.solve_iterations)
+                sp.set(coordinates=list(self.order),
+                       lane_iterations=[a[..., 0].tolist() for a in fetched],
+                       trips=[a[..., 1].tolist() for a in fetched])
         return published, scores, vars_, carried
 
     def _program_args(self, initial, regs, seed, carry0):
@@ -545,7 +573,7 @@ class FusedSweep:
                 in_axes=(None, None, None, 0, None, None, None)))
         carry = carry0 if carry0 is not None else self.init_carry(initial)
         base, carried = self._base_with_carry_through(initial)
-        published, scores, vars_ = self._grid_program(
+        published, scores, vars_, _iterations = self._grid_program(
             *carry, self._vars0, self._stack_regs(regs_grid),
             jax.random.PRNGKey(seed), base, self._datas)
         # one bulk device->host transfer per output array, host-indexed per

@@ -89,6 +89,37 @@ def _pad_rows(batch: DenseBatch, block_rows: int) -> DenseBatch:
     )
 
 
+# From this many kernel blocks on (a block holds about 1 MiB of X: 64 MiB),
+# a design whose rows do not divide is run where it lies rather than padded
+_IN_PLACE_BLOCKS = 64
+
+
+def runs_in_place(n: int, block_rows: int) -> bool:
+    """Whether a batch of ``n`` rows meets the kernels' grid unpadded: its
+    rows do not divide into blocks, and it has MANY blocks, so that padding
+    it, which copies all of it, is what costs (6.7 GB for 13.0M rows x 128
+    float32, beside the original its owner still holds).  A smaller batch
+    is padded, once by its owner (``FixedEffectCoordinate``) or here."""
+    return n % block_rows != 0 and n >= _IN_PLACE_BLOCKS * block_rows
+
+
+def _blocks_in_place(batch: DenseBatch, block_rows: int):
+    """How a batch meets the kernels' grid: ``(batch to run, grid length,
+    remainder)``.  Where ``runs_in_place`` says so, the grid covers the
+    whole blocks where they lie, and the last ``n % block_rows`` rows come
+    back as a batch of their own, one small slice, for a second call (the
+    ``fused_glm_tail_*`` kernels: a pass over the design is one call of the
+    main kernel).  Every other batch is padded and has no remainder."""
+    n = batch.num_examples
+    if not runs_in_place(n, block_rows):
+        batch = _pad_rows(batch, block_rows)
+        return batch, batch.num_examples // block_rows, None
+    whole = n // block_rows * block_rows
+    return batch, n // block_rows, DenseBatch(
+        x=batch.x[whole:], y=batch.y[whole:], offset=batch.offset[whole:],
+        weight=batch.weight[whole:])
+
+
 def _acc_dtype(dtype) -> jnp.dtype:
     """Accumulate in >= f32 (f64 stays f64 for interpret-mode parity tests)."""
     return jnp.promote_types(dtype, jnp.float32)
@@ -245,6 +276,7 @@ def fused_value_and_grad(
     margin_shift: Array | float = 0.0,
     block_rows: Optional[int] = None,
     interpret: bool = False,
+    name: str = "fused_glm_value_grad",
 ) -> Tuple[Array, Array, Array]:
     """(Σ wt·l, X^T r, Σ r) in one pass over X.
 
@@ -263,12 +295,11 @@ def fused_value_and_grad(
     n, d = batch.x.shape
     bn = block_rows or _pick_block_rows(
         n, d, np.dtype(batch.x.dtype).itemsize)
-    batch = _pad_rows(batch, bn)
-    n_pad = batch.num_examples
+    batch, blocks, rest = _blocks_in_place(batch, bn)
     acc = _acc_dtype(batch.x.dtype)
     shift = jnp.asarray(margin_shift, acc).reshape(1, 1)
 
-    grid = (n_pad // bn,)
+    grid = (blocks,)
     yow = jnp.stack([batch.y, batch.offset, batch.weight])  # (3, n): rows on lanes
     kernel = functools.partial(_value_grad_kernel, loss)
     val, rsum, grad = pl.pallas_call(
@@ -291,9 +322,14 @@ def fused_value_and_grad(
             jax.ShapeDtypeStruct((_NACC, d), acc),
         ],
         interpret=interpret,
-        name="fused_glm_value_grad",
+        name=name,
     )(shift, w_eff.reshape(-1, 1), batch.x, yow)
-    return jnp.sum(val), jnp.sum(grad, axis=0), jnp.sum(rsum)
+    out = jnp.sum(val), jnp.sum(grad, axis=0), jnp.sum(rsum)
+    if rest is not None:
+        out = tuple(a + b for a, b in zip(out, fused_value_and_grad(
+            loss, w_eff, rest, margin_shift, interpret=interpret,
+            name="fused_glm_tail_value_grad")))
+    return out
 
 
 def fused_hvp(
@@ -305,6 +341,7 @@ def fused_hvp(
     v_shift: Array | float = 0.0,
     block_rows: Optional[int] = None,
     interpret: bool = False,
+    name: str = "fused_glm_hvp",
 ) -> Tuple[Array, Array]:
     """(X^T q, Σ q) with q = wt·l''(z)·(X@v_eff + v_shift), one pass over X.
 
@@ -321,8 +358,7 @@ def fused_hvp(
     n, d = batch.x.shape
     bn = block_rows or _pick_block_rows(
         n, d, np.dtype(batch.x.dtype).itemsize)
-    batch = _pad_rows(batch, bn)
-    n_pad = batch.num_examples
+    batch, blocks, rest = _blocks_in_place(batch, bn)
     acc = _acc_dtype(batch.x.dtype)
     shift = jnp.asarray(margin_shift, acc).reshape(1, 1)
     vshift = jnp.asarray(v_shift, acc).reshape(1, 1)
@@ -332,7 +368,7 @@ def fused_hvp(
     kernel = functools.partial(_hvp_kernel, loss)
     hv, qsum = pl.pallas_call(
         kernel,
-        grid=(n_pad // bn,),
+        grid=(blocks,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
@@ -349,6 +385,11 @@ def fused_hvp(
             jax.ShapeDtypeStruct((_NACC, 1), acc),
         ],
         interpret=interpret,
-        name="fused_glm_hvp",
+        name=name,
     )(shift, vshift, wv, batch.x, yow)
-    return jnp.sum(hv, axis=0), jnp.sum(qsum)
+    out = jnp.sum(hv, axis=0), jnp.sum(qsum)
+    if rest is not None:
+        out = tuple(a + b for a, b in zip(out, fused_hvp(
+            loss, w_eff, v_eff, rest, margin_shift, v_shift,
+            interpret=interpret, name="fused_glm_tail_hvp")))
+    return out

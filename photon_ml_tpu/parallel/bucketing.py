@@ -99,6 +99,8 @@ class EntityBuckets:
     num_entities: int
     num_samples: int  # original sample-row count (scores vector length)
     compact: bool = False
+    capped_entities: int = 0  # entities over the active cap
+    passive_rows: int = 0     # their rows outside the reservoir: scored only
 
     def entity_ids(self) -> np.ndarray:
         return np.asarray(sorted(self.lane_of), np.int64)
@@ -184,6 +186,18 @@ def _capacity_classes(kept_rows: List[np.ndarray]) -> np.ndarray:
     ONE rounding rule for the dense and sparse bucketers."""
     return np.asarray([max(1, 1 << (len(r) - 1).bit_length())
                        for r in kept_rows])
+
+
+def _passive(kept_rows: List[np.ndarray], rescale: List[float]) -> dict:
+    """``capped_entities`` and ``passive_rows`` of ``_group_rows``' result:
+    a capped entity's weight is count / kept, so it leaves kept x (weight
+    - 1) of its rows out of training."""
+    scale = np.asarray(rescale, np.float64)
+    kept = np.fromiter(map(len, kept_rows), np.int64, len(kept_rows))
+    capped = scale > 1.0
+    return dict(capped_entities=int(capped.sum()),
+                passive_rows=int(np.rint(kept[capped]
+                                         * (scale[capped] - 1.0)).sum()))
 
 
 def _pack_lane_meta(n_lanes, cap, idxs, kept_rows, kept_entities, rescale,
@@ -313,7 +327,8 @@ def bucket_by_entity(
 
     return EntityBuckets(buckets=buckets, lane_of=lane_of, dim=d,
                          num_entities=len(kept_entities),
-                         num_samples=n if num_samples is None else num_samples)
+                         num_samples=n if num_samples is None else num_samples,
+                         **_passive(kept_rows, rescale))
 
 
 def bucket_by_entity_sparse(
@@ -422,7 +437,7 @@ def bucket_by_entity_sparse(
     ents = EntityBuckets(buckets=buckets, lane_of=lane_of, dim=dim,
                          num_entities=len(kept_entities),
                          num_samples=n if num_samples is None else num_samples,
-                         compact=True)
+                         compact=True, **_passive(kept_rows, rescale))
     return ents, projections
 
 

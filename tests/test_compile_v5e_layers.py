@@ -159,3 +159,58 @@ def test_nearly_every_instruction_carries_a_scope(compiled_text, config):
              or n.startswith(("fused_glm", "soa_newton"))]
     bare = [n for n in heavy if "photon." not in table[n]]
     assert len(heavy) > 50 and len(bare) <= 0.05 * len(heavy), bare
+
+
+def test_fixed_effect_kernels_take_an_undivisible_design_in_place(one_chip):
+    """``glmix_ml20m``'s fixed design, 13,017,636 rows x 128 float32 (6.7
+    GB), is 6,356 blocks of 2,048 rows and 548 rows more.  Compiled for the
+    chip, one evaluation runs the kernel twice (the whole blocks where they
+    lie, the last rows as one small batch) and needs no temporary the size
+    of the design: padding it would."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import jax.numpy as jnp
+
+    import photon_ml_tpu.ops.fused_glm as fused_glm
+    from photon_ml_tpu.core.batch import DenseBatch
+    from photon_ml_tpu.core.losses import loss_for_task
+    from photon_ml_tpu.types import TaskType
+
+    n, d = 13017636, 128
+    loss = loss_for_task(TaskType.LOGISTIC_REGRESSION)
+
+    def value_grad(w, x, y, o, wt):
+        return fused_glm.fused_value_and_grad(
+            loss, w, DenseBatch(x=x, y=y, offset=o, weight=wt))
+
+    def hvp(w, x, y, o, wt):
+        return fused_glm.fused_hvp(
+            loss, w, w, DenseBatch(x=x, y=y, offset=o, weight=wt))
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(fused_glm, "has_tpu", lambda: True)
+    cache_was = jax.config.jax_enable_compilation_cache
+    x64_was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    compilation_cache.reset_cache()
+    try:
+        for f in (value_grad, hvp):
+            compiled = jax.jit(f).lower(shape(d), shape(n, d), shape(n),
+                                        shape(n), shape(n)).compile()
+            text = compiled.as_text()
+            assert text.count("tpu_custom_call") == 2
+            # a pass over the design stays ONE call of the main kernel: the
+            # last rows run under another name (fixed_passes_per_fit and
+            # fused_glm_hbm_share count calls by name)
+            assert text.count("fused_glm_tail_") >= 1
+            design = n * d * 4
+            assert compiled.memory_analysis().temp_size_in_bytes < design // 8
+    finally:
+        patch.undo()
+        jax.config.update("jax_enable_x64", x64_was)
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()

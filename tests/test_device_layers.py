@@ -489,6 +489,48 @@ def test_traced_dry_run_reports_the_span_metrics(cell, tmp_path):
                for m in line["metrics"].values())
 
 
+def test_traced_dry_run_of_the_heavy_tailed_cell(tmp_path):
+    """``glmix_ml20m.train`` (ISSUE 25) lists its layer metrics itself: a
+    traced CPU dry run reports the three the program's spans and iteration
+    outputs feed, leaves out what needs a device trace, holds the passive
+    rows of the last fit to the plain reference, and compiles nothing in
+    the window although a traced fit fetches its iteration counts."""
+    cell = "glmix_ml20m.train"
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
+         "--seed", "3000000019", "--seconds", "1", "--trace", "1",
+         "--dry-run"],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        timeout=600,
+        env={**os.environ, "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla")})
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"], line["checks"]
+    assert line["checks"]["no_compile_in_window"]
+    catalog = harness.Catalog()
+    listed = catalog.json("workloads", cell)["per_layer"]
+    want = {n for n in listed
+            if catalog.json("layer_metrics", n)["source"] != "device_trace"}
+    assert set(line["metrics"]) == want
+    assert {"solve_classes", "solve_slot_fill",
+            "solve_lane_waste_share"} <= want
+    assert {"tail_solve_busy_share", "device_idle_share"} <= set(listed) - want
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    assert m["solve_classes"] == 14  # 1 to 64 rows: seven powers of two each
+    assert 50 < m["solve_slot_fill"] < 100
+    assert 0 < m["solve_lane_waste_share"] < 100
+    d = line["detail"]
+    assert d["passive_entities"] == 8 and d["passive_rows_checked"] > 0
+    assert d["passive_rows_err"] < 1e-2 and d["newton_parity_err"] < 1e-2
+    assert d["passive_score_err"] < 1e-5  # the scoring alone: float32 rounding
+    # both random effects' solves against the reference, the one updated
+    # first through its own update on the last fit's scores
+    assert set(d["solve_precision"]) == {"per-user", "per-item"}
+    assert all(q["entities"] == 64 and q["p10"] < 3e-4 and q["p10"] <= q["max"]
+               for q in d["solve_precision"].values())
+    assert d["reference_dtype"] == "float32"
+
+
 # -- (viii) a cache another tree filled ---------------------------------------
 
 @pytest.fixture
