@@ -1288,6 +1288,12 @@ class RandomEffectCoordinate(Coordinate):
         # cap 256, 2k lanes) 1.5x SLOWER.  cap*d^2/2 <= 1280 keeps the
         # winning regime: per-iteration Hessian traffic at or below the
         # vmapped path's padded-state traffic (128 lanes x m=10 history).
+        # That traffic has since shrunk: with the history newest-first
+        # (opt/lbfgs.py) a solver trip of the vmapped path over 65,536
+        # lanes x 128 rows x d=16 takes 35 ms on a v5e where it took 116,
+        # 31 ms of it the line search's ~17 objective evaluations; over
+        # 24,656 lanes x 32 rows 0.95 ms where it took 28.8 (PERF.md
+        # section 6, PR 26).  The line itself was not measured again.
         # The SOLVE-space shapes decide: compact sparse buckets and
         # projected (INDEX_MAP / RANDOM) buckets solve at their compact /
         # projected width, which is exactly where narrow dims live — the

@@ -115,6 +115,16 @@ def gather_fusions(text, table):
     return out
 
 
+def assert_solves_gather_nothing(gathers):
+    """The L-BFGS history is newest-first (opt/lbfgs.py): every lane of a
+    vmapped solve reads the slot of the recursion's own counter, so no
+    gather fusion is left under a solve (a circular history cost nine a
+    bucket), while the offsets gathers and the rescore keep theirs."""
+    assert not [s for s in gathers if "photon.entity_solve" in s], gathers
+    assert {s.split("/")[-1] for s in gathers} >= {"photon.entity_gather",
+                                                   "photon.rescore"}
+
+
 def test_glmix_chip_kernels_and_gathers_fall_under_their_layers(compiled_text):
     text = compiled_text("glmix_chip")
     table = hlo_op_table(text)
@@ -146,9 +156,17 @@ def test_glmix3_wide_solver_loops_fall_under_their_buckets(compiled_text):
         # the scan's body, then: the solver's loop, the line search in it
         assert {("photon.update." + bucket, 1),
                 ("photon.update." + bucket, 2)} <= loops
-    gathers = gather_fusions(text, table)
-    assert {s.split("/")[-1] for s in gathers} >= {"photon.entity_gather",
-                                                   "photon.rescore"}
+    assert_solves_gather_nothing(gather_fusions(text, table))
+
+
+def test_glmix_ml20m_ragged_solves_gather_nothing(compiled_text):
+    text = compiled_text("glmix_ml20m")
+    table = hlo_op_table(text)
+    solves = {scopes for scopes in layers_of(table, "while")
+              if "photon.entity_solve" in scopes}
+    # the dry-run recipe's counts fall into seven capacity classes a side
+    assert len(solves) >= 10, solves
+    assert_solves_gather_nothing(gather_fusions(text, table))
 
 
 @pytest.mark.parametrize("config", ["glmix_chip", "glmix3_wide"])

@@ -21,6 +21,7 @@ from photon_ml_tpu.opt import (
     minimize_owlqn,
     minimize_tron,
 )
+from photon_ml_tpu.opt.lbfgs import push_pair, two_loop_direction
 from photon_ml_tpu.opt.solve import compute_variances
 from photon_ml_tpu.types import ConvergenceReason, OptimizerType, VarianceComputationType
 
@@ -500,3 +501,172 @@ class TestNewtonSoa:
         assert int(res.reason[1]) != int(
             ConvergenceReason.OBJECTIVE_NOT_IMPROVING)
         assert np.abs(w[:, 1]).max() > 0               # healthy lane solved
+
+
+# ---------------------------------------------------------------------------
+# The L-BFGS history, newest-first (PR 26).  The plain references live here:
+# the circular-buffer recursion the solver had before (every read through
+# ``pos``, a per-lane gather under vmap), to be matched EXACTLY, and a
+# textbook recursion over a Python list of pairs, to rounding.
+# ---------------------------------------------------------------------------
+
+
+def _circular_two_loop(g, s_hist, y_hist, rho, count, pos):
+    m = rho.shape[0]
+
+    def newest_first(j):
+        return (pos - 1 - j) % m
+
+    def loop1(j, carry):
+        q, alphas = carry
+        i = newest_first(j)
+        a = rho[i] * jnp.vdot(s_hist[i], q)
+        a = jnp.where(j < count, a, 0.0)
+        q = q - a * y_hist[i]
+        return q, alphas.at[i].set(a)
+
+    q, alphas = jax.lax.fori_loop(0, m, loop1, (g, jnp.zeros_like(rho)))
+    newest = newest_first(0)
+    sy = jnp.vdot(s_hist[newest], y_hist[newest])
+    yy = jnp.vdot(y_hist[newest], y_hist[newest])
+    gamma = jnp.where((count > 0) & (yy > 0), sy / jnp.where(yy == 0, 1.0, yy), 1.0)
+
+    def loop2(j, r):
+        jj = m - 1 - j
+        i = newest_first(jj)
+        b = rho[i] * jnp.vdot(y_hist[i], r)
+        return r + jnp.where(jj < count, 1.0, 0.0) * ((alphas[i] - b) * s_hist[i])
+
+    return -jax.lax.fori_loop(0, m, loop2, gamma * q)
+
+
+def _circular_push(hist, s, y, ok):
+    s_hist, y_hist, rho, count, pos = hist
+    m = rho.shape[0]
+    sy = jnp.vdot(s, y)
+    admit = ok & (sy > 1e-12 * jnp.maximum(jnp.vdot(y, y), 1e-30))
+    return (jnp.where(admit, s_hist.at[pos].set(s), s_hist),
+            jnp.where(admit, y_hist.at[pos].set(y), y_hist),
+            jnp.where(admit, rho.at[pos].set(1.0 / jnp.where(sy == 0, 1.0, sy)), rho),
+            jnp.where(admit, jnp.minimum(count + 1, m), count),
+            jnp.where(admit, (pos + 1) % m, pos))
+
+
+def _textbook_direction(g, pairs, m):
+    """Nocedal & Wright, algorithm 7.4, in float64 over the last ``m`` pairs
+    (oldest first)."""
+    pairs = [(np.asarray(s, np.float64), np.asarray(y, np.float64))
+             for s, y in pairs[-m:]]
+    q, alphas = np.asarray(g, np.float64), []
+    for s, y in reversed(pairs):
+        alphas.append((s @ q) / (s @ y))
+        q = q - alphas[-1] * y
+    r = q * ((pairs[-1][0] @ pairs[-1][1]) / (pairs[-1][1] @ pairs[-1][1])
+             if pairs else 1.0)
+    for (s, y), a in zip(pairs, reversed(alphas)):
+        r = r + (a - (y @ r) / (s @ y)) * s
+    return -r
+
+
+# steps offered to the history: "a" a pair with s.y > 0 from an accepted
+# step, "n" one with s.y < 0 (refused by the cautious rule), "f" a failed
+# line search (ok = False)
+_HISTORY_PATTERNS = {
+    "empty": "",
+    "partly_filled": "aaaa",
+    "full": "aaaaaa",
+    "wrapped": "aaaaaaaaa",
+    "wrapped_twice": "aaaaaaaaaaaaaaa",
+    "refused_in_between": "anaafaanaanfaa",
+    "refused_first_and_last": "nfaaaaaaanf",
+}
+_STEPS = max(len(p) for p in _HISTORY_PATTERNS.values())
+
+
+def _history_steps(pattern, d, dtype, seed):
+    """[T, d] s, [T, d] y, [T] ok, padded to _STEPS with failed steps."""
+    rng = np.random.default_rng(seed)
+    a = rng.normal(size=(d, d)) * 0.2
+    hess = a @ a.T + np.eye(d)
+    s = rng.normal(size=(_STEPS, d))
+    y = s @ hess
+    ok = np.zeros(_STEPS, bool)
+    for t, kind in enumerate(pattern):
+        ok[t] = kind != "f"
+        if kind == "n":
+            y[t] = -y[t]
+    return s.astype(dtype), y.astype(dtype), ok
+
+
+def _run_histories(s, y, ok, g, m):
+    """Offer the steps one by one; the direction from the newest-first
+    history, the direction from the circular one, and both final states."""
+    d = s.shape[-1]
+    zero = (jnp.zeros((m, d), s.dtype), jnp.zeros((m, d), s.dtype),
+            jnp.zeros((m,), s.dtype), jnp.int32(0))
+
+    def step(carry, step_t):
+        new, circ = carry
+        return (push_pair(*new, *step_t), _circular_push(circ, *step_t)), None
+
+    (new, circ), _ = jax.lax.scan(step, (zero, zero + (jnp.int32(0),)),
+                                  (s, y, ok))
+    return two_loop_direction(g, *new), _circular_two_loop(g, *circ), new, circ
+
+
+_run_histories_jit = jax.jit(_run_histories, static_argnums=4)
+
+
+def _admitted(pattern, s, y):
+    """The pairs the history must hold, oldest first."""
+    return [(s[t], y[t]) for t, kind in enumerate(pattern) if kind == "a"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("pattern", sorted(_HISTORY_PATTERNS))
+def test_newest_first_history_matches_circular_and_textbook(pattern, dtype):
+    m, d = 6, 5
+    s, y, ok = _history_steps(_HISTORY_PATTERNS[pattern], d, dtype, seed=len(pattern))
+    g = np.random.default_rng(7).normal(size=d).astype(dtype)
+    got, circ_dir, new, circ = _run_histories_jit(s, y, ok, g, m)
+    # same operations in the same order on the same values: bit for bit
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(circ_dir))
+    admitted = _admitted(_HISTORY_PATTERNS[pattern], s, y)
+    count, pos = int(circ[3]), int(circ[4])
+    assert int(new[3]) == count == min(len(admitted), m)
+    # slot j is the j-th newest admitted pair, where the circular buffer
+    # holds it at (pos - 1 - j) % m
+    for j in range(count):
+        np.testing.assert_array_equal(new[0][j], admitted[-1 - j][0])
+        np.testing.assert_array_equal(new[1][j], admitted[-1 - j][1])
+        np.testing.assert_array_equal(new[0][j], circ[0][(pos - 1 - j) % m])
+        assert new[2][j] == circ[2][(pos - 1 - j) % m]
+    np.testing.assert_allclose(
+        got, _textbook_direction(g, admitted, m),
+        rtol=2e-4 if dtype == "float32" else 1e-11,
+        atol=2e-5 if dtype == "float32" else 1e-12)
+
+
+@pytest.mark.parametrize("m", [3, 6, 10])
+def test_newest_first_history_under_vmap_lanes_at_unlike_fill(m):
+    """One vmapped program, every pattern a lane: lanes hold 0 to m pairs,
+    wrap or not, and refuse pairs at different steps, so a circular buffer's
+    ``pos`` differs from lane to lane while the newest-first slot does not."""
+    d, dtype = 5, "float32"
+    names = sorted(_HISTORY_PATTERNS)
+    steps = [_history_steps(_HISTORY_PATTERNS[p], d, dtype, seed=10 + k)
+             for k, p in enumerate(names)]
+    s, y, ok = (np.stack(a) for a in zip(*steps))
+    g = np.random.default_rng(8).normal(size=(len(names), d)).astype(dtype)
+    batched = jax.jit(jax.vmap(lambda *a: _run_histories(*a, m)))
+    got, circ_dir, new, circ = batched(s, y, ok, g)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(circ_dir))
+    assert len(set(np.asarray(circ[4]).tolist())) > 1  # pos differs by lane
+    for k, name in enumerate(names):
+        plain = _run_histories_jit(s[k], y[k], ok[k], g[k], m)[0]
+        # a batched dot may sum in another order than a plain one
+        np.testing.assert_allclose(got[k], plain, rtol=1e-5, atol=1e-6)
+        admitted = _admitted(_HISTORY_PATTERNS[name], s[k], y[k])
+        assert int(new[3][k]) == min(len(admitted), m)
+        np.testing.assert_allclose(got[k], _textbook_direction(g[k], admitted, m),
+                                   rtol=2e-4, atol=2e-5)
