@@ -32,8 +32,8 @@ Everything here is best-effort and conservative in the same direction as
 the rest of the analysis stack: unresolvable facts contribute nothing, so
 dataflow can only ADD precision, never invent phantom findings.  The time
 spent in this module is accounted separately (``reset_cost``/
-``cost_seconds``) so ``bench.py --lint`` can report the dataflow pass cost
-next to the ProgramIndex build.
+``cost_seconds``) so ``tools/photonlint.py`` can report the dataflow pass
+cost next to the ProgramIndex build.
 """
 
 from __future__ import annotations

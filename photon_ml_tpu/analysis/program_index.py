@@ -72,8 +72,8 @@ def _source_digest(source: str) -> str:
 # (``id(fn)``), so reusing a cached ModuleSummaries REQUIRES the index to
 # adopt the very tree object those summaries were built over — this cache
 # is what makes the two identities coincide across ProgramIndex builds in
-# one process (e.g. photonlint --diff linting several changed files, or
-# the lint bench's repeat loop).  Unbounded but tiny: one tree per module
+# one process (e.g. photonlint --diff linting several changed files).
+# Unbounded but tiny: one tree per module
 # file actually linted.
 _PARSE_CACHE: Dict[str, Tuple[str, ast.Module]] = {}
 

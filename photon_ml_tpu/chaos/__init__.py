@@ -1,5 +1,5 @@
-"""photonchaos: deterministic fault injection, health/readiness, and the
-seeded chaos schedule behind ``bench.py --chaos``.
+"""photonchaos: deterministic fault injection, health/readiness, and
+seeded chaos schedules.
 
 Seam-side usage (one boolean check when disabled)::
 
@@ -9,7 +9,7 @@ Seam-side usage (one boolean check when disabled)::
     if act is not None:
         raise act.to_error()
 
-Test/bench-side usage::
+Test-side usage::
 
     from photon_ml_tpu.chaos import get_injector
 

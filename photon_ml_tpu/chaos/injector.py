@@ -6,7 +6,7 @@ frontend as cooperating processes and has to prove the topology heals on
 its own.  The delta log already learned that lesson at byte granularity
 (the every-offset truncation property test) — this module generalizes it
 to the process level: every failure seam carries a NAMED fault point, and
-a test or ``bench.py --chaos`` arms a deterministic schedule against it.
+a test arms a deterministic schedule against it.
 
 Discipline (photonscope's ``obs.span`` rule applies unchanged):
 
@@ -17,7 +17,7 @@ Discipline (photonscope's ``obs.span`` rule applies unchanged):
     configuration: fire-on-Nth-hit counts calls, seeded probability draws
     from a per-point ``random.Random(seed)``, timed windows measure from
     the moment the point was armed.  Same arms + same call sequence →
-    same fires.  ``bench.py --chaos`` builds its whole run from one seed.
+    same fires.
   - **Sites interpret, the injector schedules.**  ``check`` returns a
     ``FaultAction`` (kind + data) or None; the seam decides what "drop"
     or "torn" means locally (raise, sleep, write garbage, close).  Sites
@@ -89,7 +89,7 @@ class FaultAction:
 
 # stall_dist defaults: median 30ms holds, heavy-tailed (sigma 0.6 puts the
 # p99 near 4x the median), capped so a pathological draw cannot wedge a
-# bench; all three overridable via the rule's data
+# run; all three overridable via the rule's data
 _STALL_DIST_MU = math.log(0.03)
 _STALL_DIST_SIGMA = 0.6
 _STALL_DIST_CAP_S = 0.25

@@ -1,8 +1,8 @@
-"""Seeded chaos schedules for ``bench.py --chaos``.
+"""Seeded chaos schedules.
 
 ``build_schedule(seed, rounds)`` is a pure function: the same seed and
 round count produce the identical event list on every machine and every
-run — the bench's whole fault sequence (which seam, which kind, which
+run — a run's whole fault sequence (which seam, which kind, which
 stall length, in which order) derives from one integer.  The first
 ``len(FAULT_CLASSES)`` rounds are a deterministic shuffle covering every
 fault class once (so per-class time-to-ready is always measurable);

@@ -1027,7 +1027,7 @@ def run(argv: List[str]) -> int:
 
     # readiness surface (/readyz on --metrics-port): engine warmed AND the
     # delta feed writable/fresh AND no registered worker stalled.  Built
-    # unconditionally — cheap, and the bench/tests read it in-process.
+    # unconditionally — cheap, and tests read it in-process.
     from photon_ml_tpu.chaos.health import (HealthState, Watchdog,
                                             delta_log_check,
                                             follower_staleness_check)

@@ -1283,7 +1283,7 @@ class RandomEffectCoordinate(Coordinate):
         # The swap wins where the vmapped path's 128-lane padding waste
         # dominates (tiny d, modest caps, many lanes); at larger d/cap the
         # Hessian assembly (d^2/2 weighted column products over the cap)
-        # outweighs it.  Measured on a real v5e (BENCH artifacts, round 5):
+        # outweighs it.  Measured on a real v5e in 2026-08, before the ledger:
         # glmix_chip (d=4, cap 32, 131k lanes) 2.7x FASTER; glmix2 (d=16,
         # cap 256, 2k lanes) 1.5x SLOWER.  cap*d^2/2 <= 1280 keeps the
         # winning regime: per-iteration Hessian traffic at or below the

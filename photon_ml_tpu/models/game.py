@@ -206,8 +206,7 @@ def score_compact_sparse(w_idx: Array, w_val: Array, slots: Array,
                          f_idx: Array, f_val: Array) -> Array:
     """``score_compact_sparse_xla``'s margins; on TPU, shapes inside the
     kernel's gate take the pallas match-dot instead (ops/compact_score.py —
-    same math, one VMEM pass; PHOTON_COMPACT_DISABLE_PALLAS=1 escape
-    hatch)."""
+    same math, one VMEM pass)."""
     from photon_ml_tpu.ops import compact_score
 
     if compact_score.eligible(w_idx.shape[1], f_idx.shape[1],

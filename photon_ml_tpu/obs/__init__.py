@@ -23,8 +23,7 @@ shared by training AND serving:
     alerting.
 
 Tracing is disabled by default; the module-level ``span()``/``instant()``
-fast paths cost one boolean check when off (``bench.py --obs`` holds the
-guard under 1µs/call).  Enable with ``photon_ml_tpu.obs.enable_tracing()``,
+fast paths cost one boolean check when off.  Enable with ``photon_ml_tpu.obs.enable_tracing()``,
 ``cli/serve.py --trace``, or ``cli/train.py --trace-out``.
 """
 

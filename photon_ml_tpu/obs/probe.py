@@ -17,7 +17,7 @@ Instrumented sites:
     first-compiles; that should be visible, not inferred);
   - ``utils/transfer.device_put_counted`` — design-array transfer bytes;
   - ``utils/transfer.stream_device_put`` — streaming-ingest batch uploads
-    (``site="stream_feed"``), the bench's ingest-bytes axis.
+    (``site="stream_feed"``): ingest bytes.
 
 Per-span device fences (``span(..., device_sync=True)``) live on the
 tracer; this module only provides the default fence wiring.

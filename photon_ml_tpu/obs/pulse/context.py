@@ -26,7 +26,7 @@ Binding uses the thread-local cell in ``obs.trace``: while bound, every
 ``origin=``) attrs automatically, so existing call sites join the trace
 without signature changes.  All entry points are gated by the caller on
 ``obs.enabled()`` — when tracing is off nothing mints, binds, or looks up,
-preserving the one-boolean disabled cost ``bench.py --obs`` asserts.
+preserving the one-boolean disabled cost.
 
 The module also keeps a small bounded map from delta-log identity
 ``(generation, delta_version)`` to the context that published it: the owner

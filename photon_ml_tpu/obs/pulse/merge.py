@@ -18,8 +18,7 @@ this process estimated against its named peers (``pulse.clock``), and
      ``otherData``.
 
 Pure host-side JSON transforms — no jax, no sockets — so the same code
-backs ``tools/tracemerge.py``, the e2e tests, and the merge-throughput
-leg of ``bench.py --obs``.
+backs ``tools/tracemerge.py`` and the e2e tests.
 """
 
 from __future__ import annotations

@@ -20,8 +20,7 @@ state tracing allocates nothing but the per-span attrs dict.
 
 Disabled cost: call sites go through the module-level ``span()`` /
 ``instant()`` helpers, which check one boolean and return a shared no-op
-context manager — a few ns guard (``bench.py --obs`` holds this under
-1µs/call).  Tracing is OFF by default; ``enable()`` / ``cli`` flags turn it
+context manager.  Tracing is OFF by default; ``enable()`` / ``cli`` flags turn it
 on.
 
 Device-accurate timings: wall-clocking a host block around async device

@@ -13,7 +13,7 @@ threshold — the long window proves the problem is sustained, the short
 window makes the alert RESOLVE quickly once the bleeding stops.  Two pairs
 run in parallel: a *fast* pair (page-grade, high threshold) and a *slow*
 pair (ticket-grade, low threshold).  Production windows are 5m/1h and
-30m/6h; the dataclass takes them as plain seconds so tests and the bench
+30m/6h; the dataclass takes them as plain seconds so tests
 scale the same logic down to sub-second episodes.
 
 Event sources are cumulative registry series, read from whatever registry

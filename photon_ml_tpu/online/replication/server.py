@@ -1,7 +1,7 @@
 """photonrepl log server: the delta-log owner's replication endpoint.
 
 One asyncio TCP server runs next to the log owner (``cli/learn.py
---repl-listen``, or in-process in tests/bench).  Each subscriber gets:
+--repl-listen``, or in-process in tests).  Each subscriber gets:
 
   - **Identity-based resume.**  The subscribe hello carries the client's
     last applied ``(generation, delta_version)`` and the base-generation
@@ -666,7 +666,7 @@ class ThreadedReplicationServer:
     """Run a ReplicationServer on a dedicated event-loop thread (the
     ``ThreadedFrontend`` pattern): ``start()`` blocks until the socket is
     bound, ``stop()`` closes and joins.  This is what blocking callers —
-    ``cli/learn.py``, the bench, tests — use."""
+    ``cli/learn.py``, tests — use."""
 
     def __init__(self, log: DeltaLog,
                  config: Optional[ReplicationConfig] = None,
@@ -732,7 +732,7 @@ def attach_replication(swapper, config: Optional[ReplicationConfig] = None,
     (``serving_base()`` — the atomic ``(model_dir, floor)`` pair), and a
     successful hot swap raises the server's base floor in-stream via the
     swapper's ``on_swap`` hook (chained, not replaced).  This is the one
-    call sites use — ``cli/learn.py --repl-listen``, the bench, tests."""
+    call sites use — ``cli/learn.py --repl-listen``, tests."""
     if swapper.delta_log is None or not swapper.log_owner:
         raise ValueError("replication needs a swapper that OWNS a delta "
                          "log (delta_log=..., log_owner=True)")

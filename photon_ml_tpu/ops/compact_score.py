@@ -19,8 +19,7 @@ takes [k, n] transposed operands so every compare/multiply uses all 128
 lanes and the k_model reduction is a sublane sum.
 
 Gating follows ops/fused_glm.py: TPU-only (``eligible``), interpret=True
-for CPU correctness tests, PHOTON_COMPACT_DISABLE_PALLAS=1 escape hatch
-(also the bench's pallas on/off A/B knob).  The O(k_model * k_feat)
+for CPU correctness tests.  The O(k_model * k_feat)
 compare-accumulate only beats the O(k_feat log k_model) search while the
 product is small — ``_MAX_MATCH_WORK`` bounds it.
 """
@@ -28,7 +27,6 @@ product is small — ``_MAX_MATCH_WORK`` bounds it.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -64,12 +62,7 @@ def eligible(k_model: int, k_feat: int, itemsize: int = 4,
 
     Shape rules: k_model * k_feat <= 4096 (match work), k_feat <= 512
     (static unroll), and a 128-lane block of both operand pairs within the
-    VMEM block budget (k_model + k_feat <= ~4096 rows in f32).
-
-    PHOTON_COMPACT_DISABLE_PALLAS=1 forces the XLA path everywhere — the
-    bench's pallas-vs-XLA A/B knob (and an escape hatch)."""
-    if os.environ.get("PHOTON_COMPACT_DISABLE_PALLAS") == "1":
-        return False
+    VMEM block budget (k_model + k_feat <= ~4096 rows in f32)."""
     if k_model < 1 or k_feat < 1 or k_model * k_feat > _MAX_MATCH_WORK:
         return False
     if k_feat > _MAX_FEAT_UNROLL:

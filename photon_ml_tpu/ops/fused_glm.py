@@ -137,7 +137,7 @@ def _mxu_precision(dtype):
     decomposition).  Sub-f32 storage (bf16) is already the MXU's native input
     width — a single DEFAULT pass is exact for those operands, and Mosaic
     rejects an fp32-precision contract on bf16 vregs outright ("Bad lhs
-    type", seen on a real v5e, TPU_CHECKLIST round 5)."""
+    type", seen on a real v5e)."""
     return _HIGHEST if jnp.dtype(dtype).itemsize >= 4 else jax.lax.Precision.DEFAULT
 
 
@@ -252,14 +252,7 @@ def eligible(batch, interpret: bool = False) -> bool:
     dim, and a design row within ``_MAX_ROW_BYTES`` so the X tile,
     coefficient block and accumulators fit VMEM.  Callers (GLMObjective)
     use their plain-XLA path otherwise — the kernels raise rather than
-    silently duplicating that math here.
-
-    PHOTON_GLM_DISABLE_PALLAS=1 forces the plain-XLA path everywhere —
-    the bench's pallas-vs-XLA A/B knob (and an escape hatch)."""
-    import os
-
-    if os.environ.get("PHOTON_GLM_DISABLE_PALLAS") == "1":
-        return False
+    silently duplicating that math here."""
     if not isinstance(batch, DenseBatch):
         return False
     if interpret:

@@ -18,14 +18,12 @@ below the MXU's useful width; the VPU column products ARE the fast path).
 
 Gating follows ops/fused_glm.py: TPU-only (``eligible``), CPU correctness
 via the ``interpret=True`` arguments (tests only — no environment variable
-reaches interpret mode), and a PHOTON_SOA_DISABLE_PALLAS=1 escape hatch —
-also the bench's A/B knob.
+reaches interpret mode).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -71,12 +69,7 @@ def eligible(d: int, num_lanes: int, cap: int, itemsize: int = 4,
 
     Shape rules: lanes a multiple of 128, 1 <= d <= 16 (static unroll), and
     the smallest (128-lane) block of a (cap, d) design at ``itemsize`` must
-    fit the VMEM block budget — cap <= 427 at d=16 in f32, 741 at d=4.
-
-    PHOTON_SOA_DISABLE_PALLAS=1 forces the XLA path everywhere — the bench's
-    pallas-vs-XLA A/B knob (and an escape hatch)."""
-    if os.environ.get("PHOTON_SOA_DISABLE_PALLAS") == "1":
-        return False
+    fit the VMEM block budget — cap <= 427 at d=16 in f32, 741 at d=4."""
     if not 1 <= d <= _MAX_DIM or cap < 1:
         return False
     if num_lanes < 1 or num_lanes % _LANE != 0:

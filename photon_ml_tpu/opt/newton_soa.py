@@ -36,12 +36,10 @@ projected buckets (their compact/projected width is exactly where narrow
 dims live) all qualify when there are no per-lane normalization/box
 extras, l1 == 0, solve dim <= _MAX_SOA_DIM, cap*d^2/2 is small enough,
 and the loss is smooth.  Everything else keeps the general vmapped path.
-Escape hatch: PHOTON_DISABLE_SOA_NEWTON=1.
 """
 
 from __future__ import annotations
 
-import os
 from typing import List
 
 import jax
@@ -55,7 +53,7 @@ from photon_ml_tpu.types import ConvergenceReason
 Array = jax.Array
 
 _MAX_SOA_DIM = 16   # Cholesky unroll is O(d^3) fused ops; 16 covers every
-# GLMix random-effect shard in the bench suite (d_user=16, d_item=16, d=4).
+# GLMix random-effect shard of the benchmark's cells (d = 16 and d = 4).
 # d=32 was tried and reverted: the unroll compiles ~35s (measured, XLA
 # CPU) and under the cap*d^2/2 traffic guard only cap<=2 buckets would
 # ever qualify at that width — compile cost without a measurable win
@@ -65,8 +63,6 @@ _MAX_SOA_DIM = 16   # Cholesky unroll is O(d^3) fused ops; 16 covers every
 
 def soa_eligible(dim: int, loss_name: str) -> bool:
     """Static part of the gate (the caller adds its own layout conditions)."""
-    if os.environ.get("PHOTON_DISABLE_SOA_NEWTON") == "1":
-        return False
     return dim <= _MAX_SOA_DIM and loss_name != "smoothed_hinge"
 
 
@@ -167,7 +163,7 @@ def solve_newton_soa(loss: PointwiseLoss, w0_t: Array, x_t: Array,
     # curvature -> Hessian triangle -> Cholesky solve in ONE kernel, the
     # design streamed through VMEM once per iteration and the [cap, d, L]
     # xq intermediate never materialized (ops/soa_newton.py; same algorithm,
-    # parity-tested in interpret mode; PHOTON_SOA_DISABLE_PALLAS=1 escape).
+    # parity-tested in interpret mode).
     from photon_ml_tpu.ops import soa_newton
 
     use_pallas = soa_newton.eligible(d, num_l, x_t.shape[0],

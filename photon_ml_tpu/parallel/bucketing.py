@@ -567,7 +567,7 @@ NARROW_SCORE_DIM_MAX = 32  # the narrow layouts only ever help below this width
 # Three layouts of a coordinate's full-sample design, and ONE rule over what
 # the coordinate can observe of its data (RandomEffectCoordinate.__init__):
 #   - row-major [n, d] (``score_samples``) under the padded-footprint line
-#     below.  v5e, round-5 checklist (TPU_CHECKLIST.json): glmix2
+#     below.  On a v5e in 2026-08, before the ledger: glmix2
 #     [524288, 16] f32 pads to 268 MB and the one gather + einsum was 1.56x
 #     FASTER than d serial passes (0.47 s vs 0.73 s a sweep).  No benchmark
 #     cell sits under the line yet (PERF.md section 7, ROADMAP D3).
@@ -602,7 +602,7 @@ def score_samples_t(w_stack: Array, slots: Array, x_t: Array) -> Array:
     design (random-effect shards are typically d<=16 wide) occupies 128/d x
     its logical bytes in HBM and so does every [n, d] gather from it — 32x
     at d=4, which turned glmix_chip's 8.39M-sample scoring into 2 x 4GB of
-    HLO temp and OOMed a 16GB v5e (bench round 5).  Samples-on-lanes layout
+    HLO temp and OOMed a 16GB v5e.  Samples-on-lanes layout
     keeps every large intermediate 1-D over n: d static gathers of [E]
     coefficient columns, and no padded [n, d] array ever exists.  Each of
     the d gathers has n indices, and on the v5e the indices are the cost

@@ -303,9 +303,10 @@ def global_entity_buckets(local, mesh: Mesh, projections=None):
             "bucket_by_entity_sparse's BucketProjection list so the "
             "agreement pass can align per-host compact widths and export "
             "can back-project to the full vocabulary")
-    # process_allgather returns the input shape unchanged when n_proc == 1
-    # (no leading process axis is prepended) — normalize both gathers to
-    # [n_proc, ...] so the per-host indexing below holds either way
+    # with n_proc == 1 process_allgather returns the input shape unchanged
+    # in some jax versions and stacks a leading axis of one in others (0.9)
+    # — normalize both gathers to [n_proc, ...] so the per-host indexing
+    # below holds either way
     all_vec = np.asarray(multihost_utils.process_allgather(vec)
                          ).reshape((n_proc,) + vec.shape)
     ent_counts = np.asarray(multihost_utils.process_allgather(

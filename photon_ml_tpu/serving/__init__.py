@@ -26,8 +26,7 @@ package is that serving layer, TPU-native:
     many clients into the AsyncBatcher with deadline-budget admission
     control (load shedding + hysteresis), per-client round-robin fairness,
     graceful drain on swap/SIGTERM, a ``/metrics`` scrape endpoint, and
-    the open-loop Poisson load generator behind
-    ``bench.py --serving --open-loop``;
+    an open-loop Poisson load generator;
   - ``fleet``: multi-model serving — a keyed family of model handles
     sharing one AOT kernel cache and one device hot-row budget with
     per-tenant quotas, plus canary rollout (deterministic traffic split,

@@ -202,9 +202,8 @@ class AsyncBatcher:
 
     The synchronous ``BucketedBatcher`` API makes the CALLER responsible for
     batch formation — at low QPS every caller hands over a near-singleton
-    list and pays the pow2 ladder's padding tax (the 19.5% padding-waste
-    ratio in BENCH_SERVING_cpu.json).  This accumulator inverts that:
-    callers ``submit`` ONE request at a time and get a
+    list and pays the pow2 ladder's padding tax.  This accumulator inverts
+    that: callers ``submit`` ONE request at a time and get a
     ``concurrent.futures.Future`` back; a worker thread flushes the pending
     set whenever it reaches ``flush_threshold`` (the engine's top bucket —
     a zero-padding launch) OR the OLDEST pending request has waited

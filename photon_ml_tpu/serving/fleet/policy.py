@@ -170,7 +170,7 @@ class CanaryController:
 
     @property
     def settle_s(self) -> Optional[float]:
-        """Episode wall time, start -> promote/rollback (bench metric)."""
+        """Episode wall time, start -> promote/rollback."""
         if self.started_at is None or self.settled_at is None:
             return None
         return self.settled_at - self.started_at

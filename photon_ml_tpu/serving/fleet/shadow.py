@@ -23,8 +23,7 @@ primary and shadow executions of one request joined under one trace id.
 
 Executables come from the shared ``KernelCache``: a same-shape shadow
 store warms for free, and the whole shadow episode performs zero
-compiles — the overhead is exactly one extra execution per batch, which
-``bench.py --fleet`` reports as the shadow overhead ratio.
+compiles — the overhead is exactly one extra execution per batch.
 """
 
 from __future__ import annotations

@@ -12,8 +12,7 @@ delimited JSON wire protocol as the stdio ``cli/serve.py`` loop:
   - ``server``: the :class:`FrontendServer` tying those together with
     graceful drain on swap/delta/shutdown/SIGTERM;
   - ``metrics_http``: the ``GET /metrics`` Prometheus scrape endpoint;
-  - ``loadgen``: the open-loop Poisson generator behind
-    ``bench.py --serving --open-loop``.
+  - ``loadgen``: the open-loop Poisson generator.
 
 ``cli/serve.py --listen host:port`` runs it; stdio stays the default.
 """

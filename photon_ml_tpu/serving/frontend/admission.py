@@ -14,7 +14,7 @@ here is the classic one for a batching accelerator backend:
   refusing work while the queue is still ~one deadline deep — bounding
   p99 at roughly the budget instead of letting the queue (and every
   client's latency) grow without bound, which is exactly the cliff an
-  open-loop arrival process exposes (``bench.py --serving --open-loop``).
+  open-loop arrival process exposes.
 
   **Hysteresis makes shedding stable.**  A single threshold oscillates: one
   shed reply drains the queue below the limit, the next request is

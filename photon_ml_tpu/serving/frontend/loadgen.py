@@ -8,8 +8,8 @@ flat right through saturation.  An OPEN-loop generator fixes the arrival
 process instead: requests fire at exponentially-spaced (Poisson) instants
 drawn up front from a seeded RNG, whether or not earlier replies have come
 back.  Past saturation the backlog grows at (arrival - service) rate and
-latency diverges — unless the server sheds, which is exactly the behavior
-``bench.py --serving --open-loop`` tracks: below saturation shed≈0, past
+latency diverges — unless the server sheds, which is the behavior an
+open-loop run shows: below saturation shed≈0, past
 it p99 stays bounded near the admission budget while the shed rate (not
 the latency) absorbs the excess.
 

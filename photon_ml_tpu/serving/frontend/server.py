@@ -1157,7 +1157,7 @@ class FrontendServer:
 class ThreadedFrontend:
     """Run a FrontendServer on a dedicated event-loop thread.
 
-    The harness tests and the open-loop bench use: ``start()`` blocks until
+    The harness tests use: ``start()`` blocks until
     the socket is bound (``.port`` is then live), ``stop()`` runs the
     graceful drain and joins.  The CLI's asyncio main does NOT use this —
     it owns its loop; this exists for callers living in blocking code.

@@ -43,8 +43,8 @@ class ChunkPipeline:
 
     Iterating yields ``(chunk, records, error)``: exactly one of
     ``records`` / ``error`` is None.  ``stall_seconds`` accumulates time
-    the consumer spent blocked on not-yet-decoded chunks — the pipeline-
-    stall axis the stream bench reports (0 means decode fully hidden).
+    the consumer spent blocked on not-yet-decoded chunks — the pipeline
+    stall (0 means decode fully hidden).
     """
 
     def __init__(self, source, workers: int = 2, depth: int = 2,
@@ -118,6 +118,6 @@ class ChunkPipeline:
         finally:
             registry.set_gauge("stream_buffer_depth", 0)
             # cumulative consumer-blocked time, visible to metrics exports
-            # and the stream bench even when the pipeline object is internal
+            # even when the pipeline object is internal
             registry.add_gauge("stream_stall_seconds", self.stall_seconds)
             pool.shutdown(wait=False, cancel_futures=True)

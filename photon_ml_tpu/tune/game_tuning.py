@@ -54,7 +54,7 @@ class GameEstimatorEvaluationFunction:
             raise ValueError("all coordinates are locked; nothing to tune")
         self.results: List[GameFitResult] = []
         self._sweep = None  # None = not built; False = un-fusable
-        # phase accounting (bench reports the breakdown; reset_phases())
+        # phase accounting (reset_phases())
         self.fit_seconds = 0.0
         self.eval_seconds = 0.0
 

@@ -1,12 +1,12 @@
-"""Process start-up shared by the command-line drivers, bench.py and
+"""Process start-up shared by the command-line drivers, the benchmark and
 chip_smoke.py: place the compile cache, then say once which device the
 process actually runs on.
 
 JAX falls back to the CPU when no accelerator answers (it logs a libtpu
 error and carries on), so a run that does not name its device can pass for
 a chip run.  Every entry point therefore logs platform, device kind and
-device count at start, and the measuring ones (bench.py, chip_smoke.py)
-refuse a platform they were not asked for.
+device count at start, and the measuring ones (benchmarks/run.py,
+chip_smoke.py) refuse a platform they were not asked for.
 """
 
 from __future__ import annotations

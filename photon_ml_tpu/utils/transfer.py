@@ -44,8 +44,8 @@ def stream_device_put(arr: np.ndarray, dtype=None) -> jax.Array:
 
     The streaming data plane's upload primitive: it never blocks
     (double-buffering wants the transfer in flight while the decode pool
-    fills the next batch).  Every upload is probe-accounted under ``site="stream_feed"`` so the bench's
-    ingest-bytes axis and the photonscope byte counters agree.
+    fills the next batch).  Every upload is probe-accounted under
+    ``site="stream_feed"``.
     """
     arr = np.asarray(arr, dtype)
     get_probe().record_transfer(arr.nbytes, "h2d", site="stream_feed")

@@ -40,15 +40,6 @@ def test_without_the_switch_a_cpu_is_a_failure_and_prints_no_result():
     assert '"ok"' not in out.stdout and "== " not in out.stdout
 
 
-def test_refuses_to_run_with_a_kernel_hatch_set():
-    for knob in ("PHOTON_GLM_DISABLE_PALLAS", "PHOTON_SOA_DISABLE_PALLAS",
-                 "PHOTON_COMPACT_DISABLE_PALLAS",
-                 "PHOTON_SOA_PALLAS_INTERPRET", "PHOTON_DISABLE_SOA_NEWTON"):
-        out = _run(["--dry-run"], **{knob: "1"})
-        assert out.returncode == 2 and knob in out.stderr
-        assert out.stdout == ""
-
-
 def test_alone_in_a_directory_it_fails(tmp_path):
     """A directory that holds chip_smoke.py and nothing else of the repo:
     there is no program to smoke, so the script must not report one."""

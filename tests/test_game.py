@@ -334,34 +334,6 @@ def test_down_sampling_weights_semantics(rng):
     assert cos > 0.95
 
 
-def test_fused_sweep_matches_host_descent(rng):
-    """FusedSweep (one jitted scan program) must reproduce the host-paced
-    CoordinateDescent trajectory: same residual semantics, same warm starts
-    across outer iterations, same final model."""
-    from photon_ml_tpu.game.fused import FusedSweep
-
-    data, _, _, _ = _glmix_data(rng, n_users=12, per_user=50)
-    cfg = _configs(num_iters=3)
-    coords = {cid: build_coordinate(cid, data, c, cfg.task)
-              for cid, c in cfg.coordinates.items()}
-
-    host_model, _, _ = CoordinateDescent(coords, num_iterations=3).run()
-    fused_model, fused_scores = FusedSweep(coords, num_iterations=3).run()
-
-    wf_h = host_model["fixed"].coefficients.means
-    wf_f = fused_model["fixed"].coefficients.means
-    np.testing.assert_allclose(wf_f, wf_h, rtol=2e-3, atol=2e-3)
-
-    re_h, re_f = host_model["per-user"], fused_model["per-user"]
-    assert re_h.slot_of == re_f.slot_of
-    np.testing.assert_allclose(re_f.w_stack, re_h.w_stack, rtol=2e-3, atol=2e-3)
-
-    # fused final scores equal the model's own re-scoring
-    np.testing.assert_allclose(
-        fused_scores["fixed"], np.asarray(coords["fixed"].score(fused_model["fixed"])),
-        rtol=1e-5, atol=1e-5)
-
-
 def test_fused_sweep_warm_start(rng):
     """initial= warm start feeds both coordinate types."""
     from photon_ml_tpu.game.fused import FusedSweep
@@ -594,6 +566,13 @@ def test_fused_grid_l1_regime_switch(rng):
                                    rtol=2e-3, atol=2e-3)
 
 
+def _golden_fit():
+    rng = np.random.default_rng(20260729)
+    data, *_ = _glmix_data(rng, n_users=5, per_user=40)
+    return data, GameEstimator(fused=False).fit(
+        data, [_configs(num_iters=2)])[0].model
+
+
 def test_golden_coefficients_regression():
     """Pinned-value regression in the reference's style
     (GameEstimatorIntegTest.scala:105-107 asserts exact coefficient values
@@ -601,37 +580,73 @@ def test_golden_coefficients_regression():
     layout, solvers, residual descent — against silent numeric drift.
     Captured 2026-07-29 on the CPU x64 test surface, seed 20260729;
     re-captured 2026-07-30 after the batch-as-argument jit refactor (XLA
-    fusion order shifted f32 rounding by ~8e-5; the f64 reference goldens
-    in test_reference_golden_* pin cross-implementation correctness);
-    re-captured 2026-07-31 after the approximate-Wolfe line-search slack
-    (opt/linesearch.py: f32 solves now stop deterministically at the
-    working-precision plateau, shifting iterates by ~2e-5 within the
-    plateau-flat region);
-    re-captured 2026-08-05 on the current CPU test image — the drift
-    (~5e-4 relative on the per-user rows, ~4e-5 on the fixed effect) is an
-    XLA-version f32 fusion-order shift, present identically at every
-    repo commit back through PR 4, i.e. environmental rather than caused
-    by any code change here.  The f64 reference goldens
-    (test_reference_golden_*) pin cross-implementation correctness and
-    were unaffected.  To regenerate after a LEGITIMATE numeric change:
-    run the fit below and paste ``repr(float(x))`` of each coefficient,
-    then record the cause in this docstring."""
-    rng = np.random.default_rng(20260729)
-    data, *_ = _glmix_data(rng, n_users=5, per_user=40)
-    res = GameEstimator(fused=False).fit(data, [_configs(num_iters=2)])[0]
+    fusion order shifted f32 rounding by ~8e-5) and 2026-07-31 after the
+    approximate-Wolfe line-search slack (opt/linesearch.py: f32 solves stop
+    at the working-precision plateau, shifting iterates by ~2e-5).
+    The 2026-08-05 capture (PR 13) took the values of another image: on
+    this one (jax 0.9.0) every commit from PR 6 to PR 26 gives the values
+    below, which pass the 2026-07-31 golden and failed the 2026-08-05 one
+    by 6e-4 on the per-user row at every PR since.  Re-captured 2026-10-01
+    (PR 27) from the unchanged solver, after checking it against the plain
+    float64 Newton descent of ``test_golden_fit_matches_plain_newton``: this
+    fit is 2.1e-5 from it on that row, the 2026-08-05 values 1.7e-4.
+    To regenerate after a LEGITIMATE numeric change: run ``_golden_fit``,
+    see that the test below still passes, paste ``repr(float(x))`` of each
+    coefficient, and record the cause here."""
+    _, model = _golden_fit()
 
     golden_fixed = np.asarray([
-        -0.34681177139282227, -1.5030040740966797, -0.16299287974834442,
-        1.1834511756896973, 0.5667862892150879, -0.41815751791000366])
-    np.testing.assert_allclose(res.model["fixed"].coefficients.means,
+        -0.3468096852302551, -1.50300931930542, -0.1629900336265564,
+        1.1834657192230225, 0.5667847394943237, -0.41816797852516174])
+    np.testing.assert_allclose(model["fixed"].coefficients.means,
                                golden_fixed, rtol=1e-4, atol=1e-5)
 
-    re_model = res.model["per-user"]
+    re_model = model["per-user"]
     assert sorted(re_model.slot_of) == [11, 14, 17, 20, 23]
     golden_user0 = np.asarray([
-        0.7986433506011963, 0.1569463014602661, -0.6273418068885803])
+        0.7988278269767761, 0.15703976154327393, -0.6275042295455933])
     np.testing.assert_allclose(re_model.w_stack[re_model.slot_of[11]],
                                golden_user0, rtol=1e-4, atol=1e-5)
+
+
+def test_golden_fit_matches_plain_newton():
+    """What makes the golden values right and not only pinned: the same
+    two sweeps (fixed, then one GLM a user on the other's scores as
+    offsets) by an undamped float64 Newton in numpy, run to a step under
+    1e-14.  The float32 fit stops at its working-precision plateau, 2e-5
+    to 4e-5 from it; 1e-4 is the golden test's own tolerance."""
+    data, model = _golden_fit()
+
+    def newton(x, y, offset, l2=1.0):
+        w = np.zeros(x.shape[1])
+        for _ in range(100):
+            p = 1.0 / (1.0 + np.exp(-(x @ w + offset)))
+            grad = x.T @ (p - y) + l2 * w
+            hess = (x * (p * (1 - p))[:, None]).T @ x + l2 * np.eye(len(w))
+            step = np.linalg.solve(hess, grad)
+            w -= step
+            if np.abs(step).max() < 1e-14:
+                return w
+        raise AssertionError("plain Newton did not converge")
+
+    xg = np.asarray(data.features["global"], np.float64)
+    xu = np.asarray(data.features["per_user"], np.float64)
+    y, uid = np.asarray(data.y, np.float64), data.id_tags["userId"]
+    user_scores = np.zeros(len(y))
+    for _ in range(2):
+        w_fixed = newton(xg, y, user_scores)
+        w_user = {}
+        for u in np.unique(uid):
+            rows = uid == u
+            w_user[u] = newton(xu[rows], y[rows], xg[rows] @ w_fixed)
+            user_scores[rows] = xu[rows] @ w_user[u]
+
+    np.testing.assert_allclose(model["fixed"].coefficients.means, w_fixed,
+                               rtol=1e-4, atol=1e-4)
+    re_model = model["per-user"]
+    for u, w in w_user.items():
+        np.testing.assert_allclose(re_model.w_stack[re_model.slot_of[u]], w,
+                                   rtol=1e-4, atol=1e-4)
 
 
 def test_per_entity_l2_multipliers(rng):

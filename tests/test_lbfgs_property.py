@@ -23,7 +23,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from photon_ml_tpu.opt.lbfgs import minimize_lbfgs  # noqa: E402
-from photon_ml_tpu.opt.types import SolverConfig  # noqa: E402
+from photon_ml_tpu.opt.types import PLATEAU_ULPS, SolverConfig  # noqa: E402
 
 _D = 5
 
@@ -63,10 +63,28 @@ _vec = st.lists(st.floats(-3, 3, allow_nan=False),
 @settings(max_examples=50, deadline=None)
 @given(m=_mat, b=_vec, w0=_vec, jitter=st.floats(0.1, 5.0))
 def test_lbfgs_reaches_analytic_optimum(m, b, w0, jitter):
+    """The solver stops on FUNCTION VALUES (opt/types.convergence_check:
+    |f_k - f_{k-1}| <= tolerance x |f_0|, floored at PLATEAU_ULPS ulps of f),
+    so that is the scale it is held to: the objective gap it leaves, and
+    the distance from the optimum that gap allows on a strongly convex
+    quadratic (gap = e'Ae / 2 >= lambda_min |e|^2 / 2).  A fixed 1e-6 on w
+    was never promised: at |f_0| = 16 and lambda_min = 1 the criterion
+    stops 1.8e-6 away, as asked.  The gap left after a step that gained
+    f_tol is that gain x r / (1 - r) for a step contracting by r: 10 x
+    allows r = 0.91 (6,000 random draws of these problems peak at 2.1 x)."""
     A = _spd(m, jitter)
     res = _solve_quad(jnp.asarray(A), jnp.asarray(b), jnp.asarray(w0))
     want = np.linalg.solve(A, b)
-    np.testing.assert_allclose(np.asarray(res.w), want, rtol=1e-5, atol=1e-6)
+
+    def f(w):
+        return 0.5 * w @ A @ w - b @ w
+
+    f_tol = max(1e-12 * abs(f(w0)),
+                PLATEAU_ULPS * np.finfo(np.float64).eps * abs(f(want)))
+    e = np.asarray(res.w) - want
+    assert 0.5 * e @ A @ e <= 10 * f_tol
+    assert np.linalg.norm(e) <= np.sqrt(
+        2 * 10 * f_tol / np.linalg.eigvalsh(A)[0])
 
 
 @settings(max_examples=40, deadline=None)

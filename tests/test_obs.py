@@ -556,21 +556,35 @@ class TestServeCliTrace:
 
 
 # ---------------------------------------------------------------------------
-# bench.py --obs plumbing (budget relaxed: CI boxes are noisy; the real
-# 1µs assertion runs in the bench itself)
+# the disabled span guard: every hot-path call site pays it on every request
 # ---------------------------------------------------------------------------
-class TestObsBench:
-    def test_obs_bench_shape(self, tmp_path, monkeypatch):
-        import bench
+def test_disabled_span_is_cheaper_than_a_recording_one():
+    """Best of five windows of 2,000 calls each way, also under a bound
+    trace context (propagation wired in must not make the guard record)."""
+    import time
 
-        monkeypatch.setenv("PHOTON_BENCH_OBS_BUDGET_NS", "1e9")
-        out_path = str(tmp_path / "BENCH_OBS.json")
-        out = bench.run_obs_bench(n_calls=2000, out_path=out_path)
-        on_disk = json.load(open(out_path))
-        assert on_disk == out
-        assert set(out) >= {"disabled_span_ns", "enabled_span_ns",
-                            "instant_ns", "registry_inc_labeled_ns",
-                            "budget_ns", "within_budget"}
-        assert out["disabled_span_ns"] > 0
-        # the guard must be cheaper than actually recording
-        assert out["disabled_span_ns"] < out["enabled_span_ns"]
+    from photon_ml_tpu.obs.pulse import context as pulse_ctx
+
+    def per_call_ns(n=2000):
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter_ns()
+            for _ in range(n):
+                with obs.span("guard.op", bucket=64):
+                    pass
+            best = min(best, (time.perf_counter_ns() - t0) / n)
+        return best
+
+    tracer = Tracer(capacity=4096, enabled=False)
+    prev = obs.set_tracer(tracer)
+    try:
+        disabled_ns = per_call_ns()
+        with pulse_ctx.bind(pulse_ctx.mint()):
+            disabled_bound_ns = per_call_ns()
+        assert not tracer.records()  # the guard recorded nothing
+        tracer.enable()
+        enabled_ns = per_call_ns()
+    finally:
+        obs.set_tracer(prev)
+    assert 0 < disabled_ns < enabled_ns
+    assert 0 < disabled_bound_ns < enabled_ns

@@ -164,8 +164,6 @@ def test_gate_is_a_vmem_shape_rule(rng, monkeypatch):
     assert fused_glm.eligible(b(8192, jnp.bfloat16))
     assert not fused_glm.eligible(b(8192 + 128, jnp.bfloat16))
     assert not fused_glm.eligible(b(500, jnp.float32))  # not lane-aligned
-    monkeypatch.setenv("PHOTON_GLM_DISABLE_PALLAS", "1")
-    assert not fused_glm.eligible(b(512, jnp.float32))
 
 
 @pytest.mark.parametrize("loss", [logistic_loss, poisson_loss], ids=lambda l: l.name)

@@ -302,7 +302,7 @@ def test_transposed_scoring_gate_is_padded_bytes():
     (n x 128 lanes x itemsize), not width alone: glmix2-shaped shards
     (524k x 16 f32, 268 MB padded) measured 1.56x FASTER row-major on the
     v5e while glmix_chip's (8.39M x 4 bf16, 2.1 GB padded) OOMs without
-    the transpose (TPU_CHECKLIST.json r5b vs run 1)."""
+    the transpose (builders' v5e runs of 2026-08, before the ledger)."""
     from photon_ml_tpu.parallel.bucketing import (
         NARROW_SCORE_PAD_BYTES_MIN, use_transposed_scoring)
 
@@ -1389,22 +1389,25 @@ class TestMultihostGuards:
 
     def test_single_process_allgather_has_no_process_axis(self, devices,
                                                           rng):
-        """Regression: with one process, ``process_allgather`` returns the
-        INPUT shape unchanged — no leading process axis is prepended.  The
+        """Regression: with one process, ``process_allgather`` has returned
+        the INPUT shape unchanged (no leading process axis) in the jax this
+        was written against, and stacks an axis of one in jax 0.9.  The
         agreement pass in ``global_entity_buckets`` used to index
         ``all_vec[:, log, 0]`` as if the axis were always there and died
-        with ``IndexError: too many indices for array``; it must reshape
-        to ``[n_proc, ...]`` first."""
+        with ``IndexError: too many indices for array``; it reshapes to
+        ``[n_proc, ...]`` first, which holds either way."""
         from jax.experimental import multihost_utils
 
         from photon_ml_tpu.parallel.bucketing import bucket_by_entity
         from photon_ml_tpu.parallel.multihost import global_entity_buckets
 
-        # pin the offending shape: the (MAXLOG, 2) metadata vector comes
-        # back (33, 2), NOT (1, 33, 2)
-        vec = np.zeros((33, 2), np.int64)
+        # the (MAXLOG, 2) metadata vector comes back (33, 2) or (1, 33, 2)
+        # by jax version: the same 66 numbers, which is what the guard's
+        # reshape needs
+        vec = np.arange(66, dtype=np.int64).reshape(33, 2)
         out = np.asarray(multihost_utils.process_allgather(vec))
-        assert out.shape == vec.shape
+        assert out.shape in (vec.shape, (1,) + vec.shape)
+        np.testing.assert_array_equal(out.reshape((1,) + vec.shape)[0], vec)
 
         # and the end-to-end single-process assembly works on top of it
         mesh = self._mesh(devices)

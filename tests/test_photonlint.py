@@ -1534,18 +1534,26 @@ class TestPackageGate:
         assert summary["whole_program"] is True
         assert isinstance(summary["index_build_s"], float)
 
-    def test_bench_lint_mode(self, tmp_path):
-        out = tmp_path / "BENCH_LINT.json"
+    def test_whole_package_gate_stays_inside_its_budget(self):
+        """The gate as CI runs it (script, default scope, the committed
+        baseline): clean, and the whole-program pass inside the 10 s it was
+        given.  The budget is held against the child's CPU seconds: the
+        suite's workers share the cores, and the pass is one thread."""
+        import resource
+
+        before = resource.getrusage(resource.RUSAGE_CHILDREN)
         proc = subprocess.run(
-            [sys.executable, os.path.join(REPO_ROOT, "bench.py"), "--lint",
-             "--lint-repeats", "1", "--out", str(out)],
+            [sys.executable, os.path.join(REPO_ROOT, "tools", "photonlint.py"),
+             "--format", "json"],
             cwd=REPO_ROOT, capture_output=True, text=True, timeout=300)
+        after = resource.getrusage(resource.RUSAGE_CHILDREN)
         assert proc.returncode == 0, proc.stdout + proc.stderr
-        payload = json.loads(out.read_text())
-        assert payload["metric"] == "photonlint_full_package_wall_s"
-        assert payload["files_scanned"] >= 100
-        assert 0 < payload["value"] < 10  # the acceptance budget, on CPU
-        assert payload["index_build_s"] < payload["value"]
-        # v4: the summary-layer share is accounted beside the dataflow one
-        assert 0 <= payload["summaries_s"] < payload["value"]
-        assert 0 <= payload["dataflow_s"] < payload["value"]
+        summary = json.loads(proc.stdout)["summary"]
+        assert summary["new"] == summary["baselined"] == summary["stale"] == 0
+        assert summary["files_scanned"] >= 100 and summary["whole_program"]
+        cpu_s = ((after.ru_utime + after.ru_stime)
+                 - (before.ru_utime + before.ru_stime))
+        assert 0 < cpu_s < 10
+        # the three accounted shares sit inside the whole
+        for share in ("index_build_s", "dataflow_s", "summaries_s"):
+            assert 0 <= summary[share] < cpu_s

@@ -311,7 +311,8 @@ def test_index_map_soa_newton_matches_vmapped(rng, monkeypatch):
     offs = np.zeros(len(y), np.float32)
     ms, _ = cs.update(offs)
 
-    monkeypatch.setenv("PHOTON_DISABLE_SOA_NEWTON", "1")
+    monkeypatch.setattr("photon_ml_tpu.opt.newton_soa.soa_eligible",
+                        lambda dim, loss_name: False)
     cv = RandomEffectCoordinate("re", data, RandomEffectConfig(**kw),
                                 TaskType.LOGISTIC_REGRESSION)
     assert not cv._use_soa

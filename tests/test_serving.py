@@ -452,42 +452,3 @@ class TestServeCli:
         d = tmp_path / "not_a_model"
         d.mkdir()
         assert serve_cli.run(["--model-dir", str(d)]) == 1
-
-
-# ---------------------------------------------------------------------------
-# bench hook
-# ---------------------------------------------------------------------------
-def test_bench_serving_smoke(tmp_path):
-    import bench
-
-    out = bench.run_serving_bench(n_entities=50, d=4, n_requests=40,
-                                  max_batch=8, device_capacity=10,
-                                  out_path=str(tmp_path / "b.json"))
-    assert out["metric"] == "serving_p99_latency"
-    assert out["single_request"]["p50_s"] > 0
-    assert out["stream"]["qps"] > 0
-    assert 0 <= out["stream"]["padding_waste_ratio"] < 1
-    assert out["warm"]["executables"] == 4
-    assert out["compiles_after_warm"] == 0  # acceptance: flat after warm
-    on_disk = json.load(open(tmp_path / "b.json"))
-    assert on_disk["value"] == out["value"]
-
-
-def test_bench_serving_zipf_smoke(tmp_path):
-    import bench
-
-    out = bench.run_serving_bench(n_entities=60, d=4, n_requests=48,
-                                  max_batch=8, device_capacity=12,
-                                  zipf=1.2, deadline_us=100.0,
-                                  rebalance_every=16,
-                                  out_path=str(tmp_path / "z.json"))
-    assert out["zipf"] == 1.2
-    # the three cross-PR trajectory numbers are recorded top-level
-    assert 0 <= out["padding_waste_ratio"] < 1
-    assert 0 <= out["entity_miss_rate"] < 1
-    assert out["p99_s"] > 0
-    assert out["hot_set"]["rebalances"] >= 3
-    assert out["hot_set"]["promotions"] >= 1  # skew moved residency
-    assert out["compiles_after_warm"] == 0
-    flushes = out["flushes"]
-    assert flushes["full"] + flushes["deadline"] + flushes["forced"] >= 1

@@ -416,6 +416,68 @@ class TestHotSet:
         assert set(store.coordinates["user"].hot_slot_of) == {30, 31, 32, 33}
 
 
+@pytest.mark.parametrize("zipf", [0.0, 1.2], ids=["uniform", "zipf"])
+def test_warm_engine_compiles_nothing_under_a_stream(zipf):
+    """Requests submitted one at a time to the deadline batcher, ~5% of them
+    for unknown users, with a frequency rebalance every 16 requests of an
+    adaptation epoch, then a measured epoch on a fresh draw: a warm engine
+    compiles nothing more.  Under Zipf traffic (ranks shuffled over the
+    slots, so the first-slots residency starts uncorrelated with the head)
+    the rebalances must move residency."""
+    n_requests, every, deadline_s = 48, 16, 1e-4
+    metrics = ServingMetrics()
+    engine, _, _ = _engine(capacity=8, max_batch=8, metrics=metrics)
+    n_compiled = engine.compile_count
+    assert n_compiled == 4
+    rng = np.random.default_rng(0)
+    slot_of_rank = rng.permutation(N_ENT)
+
+    def draw(n):
+        if zipf:
+            w = (np.arange(N_ENT) + 1.0) ** -zipf
+            users = slot_of_rank[rng.choice(N_ENT, size=n, p=w / w.sum())]
+        else:
+            users = rng.integers(0, N_ENT, size=n)
+        users = np.where(rng.random(n) < 0.05, N_ENT + users, users)
+        return [_req(rng, uid=i, user=u) for i, u in enumerate(users)]
+
+    batcher = engine.async_batcher(deadline_s=deadline_s)
+    try:
+        stream = draw(n_requests)
+        for start in range(0, n_requests, every):
+            for f in [batcher.submit(r) for r in stream[start:start + every]]:
+                f.result(timeout=300)
+            engine.store.rebalance()
+        # arrival gaps over the deadline: deadline flushes
+        trickle = []
+        for r in draw(16):
+            trickle.append(batcher.submit(r))
+            time.sleep(2.0 * deadline_s)
+        for f in trickle:
+            f.result(timeout=300)
+        before = metrics.snapshot()
+        scores = [f.result(timeout=300)
+                  for f in [batcher.submit(r) for r in draw(n_requests)]]
+    finally:
+        batcher.shutdown(drain=True)
+    snap = metrics.snapshot()
+    counters = snap["counters"]
+
+    assert engine.compile_count == n_compiled
+    assert np.isfinite(scores).all()
+    padded = snap["padded_rows_launched"] - before["padded_rows_launched"]
+    real = snap["real_rows_launched"] - before["real_rows_launched"]
+    assert real == n_requests and 0 <= 1.0 - real / padded < 1
+    misses = (counters.get("entity_misses", 0)
+              - before["counters"].get("entity_misses", 0))
+    assert 0 <= misses / n_requests < 1
+    assert counters["rebalances"] >= 3
+    assert sum(counters.get(k, 0) for k in
+               ("flushes_full", "flushes_deadline", "flushes_forced")) >= 1
+    if zipf:
+        assert counters["hot_promotions"] >= 1  # skew moved residency
+
+
 # ---------------------------------------------------------------------------
 # streaming coefficient deltas
 # ---------------------------------------------------------------------------

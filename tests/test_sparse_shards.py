@@ -736,7 +736,8 @@ def test_sparse_re_soa_newton_matches_vmapped(monkeypatch):
     off = np.zeros(len(y), np.float32)
     ms, _ = cs.update(off)
 
-    monkeypatch.setenv("PHOTON_DISABLE_SOA_NEWTON", "1")
+    monkeypatch.setattr("photon_ml_tpu.opt.newton_soa.soa_eligible",
+                        lambda dim, loss_name: False)
     cv, _ = _re_coordinate(sh, uids, y, d)
     assert not cv._use_soa
     mv, _ = cv.update(off)
