@@ -166,11 +166,16 @@ class GLMObjective:
                 margin_shift=self.norm.margin_shift(w))
             return (raw_val.astype(w.dtype), g_raw.astype(w.dtype),
                     r_sum.astype(w.dtype))
-        z = self._safe_margins(w, batch)
+        return self._raw_sums_at(self._safe_margins(w, batch), batch,
+                                 w.shape[-1])
+
+    def _raw_sums_at(self, z: Array, batch: Batch, dim: int
+                     ) -> Tuple[Array, Array, Array]:
+        """raw_value_and_grad's XLA tail, from safe margins ``z``: ONE read
+        of the design, for ``X^T r``."""
         l, d1 = self.loss.loss_and_d1(z, batch.y)
         r = batch.weight * d1
-        return (jnp.sum(batch.weight * l), _xt_dot(batch, r, w.shape[-1]),
-                jnp.sum(r))
+        return jnp.sum(batch.weight * l), _xt_dot(batch, r, dim), jnp.sum(r)
 
     def finish_value_and_grad(self, w: Array, raw_val: Array, g_raw: Array,
                               r_sum: Array) -> Tuple[Array, Array]:
@@ -186,6 +191,60 @@ class GLMObjective:
 
     def gradient(self, w: Array, batch: Batch) -> Array:
         return self.value_and_grad(w, batch)[1]
+
+    # -- the objective along a direction, on the margins ---------------------------
+
+    def value_grad_margins(self, w: Array, batch: Batch
+                           ) -> Tuple[Array, Array, Array]:
+        """``value_and_grad`` by its XLA path, and the safe margins it was
+        computed from: where a search ``along`` the margins starts."""
+        return self._value_grad_from(w, self._safe_margins(w, batch), batch)
+
+    def _value_grad_from(self, w: Array, z: Array, batch: Batch
+                         ) -> Tuple[Array, Array, Array]:
+        """(value, gradient, z) at ``w``, whose safe margins are ``z``."""
+        return (*self.finish_value_and_grad(
+            w, *self._raw_sums_at(z, batch, w.shape[-1])), z)
+
+    def along(self, w: Array, z: Array, p: Array, batch: Batch):
+        """The objective along ``w + alpha p`` without reading the design
+        again.  The margins are affine in the coefficients (normalization
+        included): with ``z`` the safe margins at ``w`` and ``u = X p``,
+        ``z(w + alpha p) = z + alpha u``, and
+
+            phi(alpha)  = Σ weight·l(z + alpha u, y) + (l2/2)|w + alpha p|²
+            phi'(alpha) = Σ weight·l'(z + alpha u, y)·u + l2 (w + alpha p)·p
+
+        are elementwise work over [rows].  Returns ``(phi, value_and_grad)``:
+        ``phi(alpha) -> (phi, phi')`` reads z, u, y and weight;
+        ``value_and_grad(alpha) -> (value, gradient, z + alpha u)`` at
+        ``w + alpha p`` is value_and_grad's own XLA tail from those margins.
+        ONE read of the design to build the pair (``u``) and one for a
+        gradient (``X^T r``).  The caller carries the margins from step to
+        step (``z + alpha u`` is the next ``z``), so the search, the
+        gradient and the next search see the same numbers; what they drift
+        from ``X w`` by is a rounding of ``alpha u`` a step (float32, 30
+        steps: under 1e-6 of the largest margin,
+        tests/test_margin_linesearch.py).  Rows of weight 0 are zero in z
+        and zeroed in u (_safe_margins' contract: 0 * inf must not reach a
+        reduction).  The XLA path only: the fused kernel reads the design
+        once an evaluation as it is (opt/solve.py's rule)."""
+        u = self.norm.margin_shift(p) + batch.margins(
+            self.norm.effective_coefficients(p))
+        u = jnp.where(batch.weight > 0, u, 0.0)
+        l2 = self.reg.l2
+        ww, wp, pp = jnp.vdot(w, w), jnp.vdot(w, p), jnp.vdot(p, p)
+
+        def phi(alpha: Array) -> Tuple[Array, Array]:
+            l, d1 = self.loss.loss_and_d1(z + alpha * u, batch.y)
+            return (jnp.sum(batch.weight * l)
+                    + 0.5 * l2 * (ww + alpha * (2.0 * wp + alpha * pp)),
+                    jnp.sum(batch.weight * d1 * u) + l2 * (wp + alpha * pp))
+
+        def value_and_grad(alpha: Array) -> Tuple[Array, Array, Array]:
+            return self._value_grad_from(w + alpha * p, z + alpha * u, batch)
+
+        return phi, value_and_grad
 
     # -- Hessian-vector product --------------------------------------------------
 

@@ -41,7 +41,7 @@ from photon_ml_tpu.models.glm import Coefficients
 from photon_ml_tpu.obs import get_probe, get_registry, set_family_bounds
 from photon_ml_tpu.obs.trace import device_scope
 from photon_ml_tpu.obs.trace import span as obs_span
-from photon_ml_tpu.opt.solve import make_solver
+from photon_ml_tpu.opt.solve import line_search_kind, make_solver
 from photon_ml_tpu.opt.types import SolverResult
 from photon_ml_tpu.parallel.bucketing import bucket_by_entity, stacked_coefficients
 from photon_ml_tpu.parallel.mesh import replicate, shard_batch
@@ -114,6 +114,11 @@ class Coordinate:
         classes."""
         return 1
 
+    # How the coordinate's solver evaluates a trial step of its line search
+    # (opt/solve.line_search_kind): "margins", "passes", or "none" where it
+    # has no strong-Wolfe search.  Set by _bind_solver.
+    line_search = "none"
+
     def init_sweep_state(self, init: Optional[DatumScoringModel] = None):
         """Host: initial device state (cold or warm-started from a model)."""
         raise NotImplementedError
@@ -140,10 +145,12 @@ class Coordinate:
         this coordinate's ``sweep_data()`` passed back as traced arguments
         (None = read the coordinate's own device arrays, the host-paced
         path).  ``iterations_out``: a list the update appends ONE int32
-        array [solves, 2] to: for each of its solves (a fixed effect has
-        one, a random effect one per capacity class) the sum and the
-        maximum of ``SolverResult.iterations`` over the solve's problems;
-        a vmapped solve runs as many trips as its slowest problem."""
+        array [solves, 4] to (``_solve_counts``): for each of its solves (a
+        fixed effect has one, a random effect one per capacity class) the
+        sum and the maximum of ``SolverResult.iterations`` over the solve's
+        problems, then the sum and the maximum of ``SolverResult.trials``;
+        a vmapped solve runs as many trips as its slowest problem, and its
+        line searches as many trials as theirs."""
         raise NotImplementedError
 
     def trace_publish(self, state, data=None) -> Array:
@@ -231,6 +238,19 @@ class Coordinate:
         regime = Regularization(l1=1.0 if self.config.reg.l1 > 0.0 else 0.0)
         return (self.data_key(),
                 dataclasses.replace(self.config, reg=regime))
+
+
+def _solve_counts(res: SolverResult, valid: Optional[Array] = None) -> Array:
+    """One row of ``trace_update``'s ``iterations_out``: int32 [4], the sum
+    and the maximum of the solve's iterations over its problems (those of
+    ``valid``, where given), then of its line-search trials (0 for a solver
+    that counts none)."""
+    trials = (res.trials if res.trials is not None
+              else jnp.zeros_like(res.iterations))
+    counts = [c if valid is None else jnp.where(valid, c, 0)
+              for c in (res.iterations, trials)]
+    return jnp.stack([f(c) for c in counts
+                      for f in (jnp.sum, jnp.max)]).astype(jnp.int32)
 
 
 def _storage_np_dtype(storage_dtype: Optional[str]):
@@ -415,6 +435,10 @@ class FixedEffectCoordinate(Coordinate):
             space=self.config.constraint_space)
         solve = make_solver(objective, self.config.optimizer,
                             self.config.solver, box=box)
+        self.line_search = line_search_kind(
+            objective, self.config.optimizer, self._batch,
+            jax.ShapeDtypeStruct((self._d_pad if self._fs else self.dim,),
+                                 self._dtype), box)
 
         # reg is a TRACED argument: a reg-weight grid re-enters this exact
         # compiled program (the optimizer/L1-regime dispatch inside
@@ -601,8 +625,7 @@ class FixedEffectCoordinate(Coordinate):
                               batch.replace(offset=offs, weight=weights),
                               self.config.reg if reg is None else reg)
             if iterations_out is not None:
-                iterations_out.append(jnp.broadcast_to(
-                    res.iterations.astype(jnp.int32), (1, 2)))
+                iterations_out.append(_solve_counts(res)[None])
         w_pub = self.trace_publish(res.w)
         with device_scope("rescore"):
             if self._fs and isinstance(batch, SparseBatch):
@@ -923,11 +946,55 @@ class RandomEffectCoordinate(Coordinate):
                     existing_model_keys=existing_model_keys,
                     groups=groups, runs=runs,
                 )
+            # Optional per-entity feature projection (reference
+            # RandomEffectCoordinateInProjectedSpace.scala:149): solve each bucket
+            # in a compact feature space, back-project coefficients to full dim.
+            # (A sparse shard arrives here with self._proj already built — its
+            # buckets ARE the compact space.)
+            if not self._sparse:
+                self._proj = None
+                if config.projector != ProjectorType.IDENTITY:
+                    from photon_ml_tpu.parallel.projection import project_buckets
+
+                    self._proj = project_buckets(
+                        self.buckets, config.projector,
+                        projected_dim=config.projected_dim,
+                        features_to_samples_ratio=config.features_to_samples_ratio,
+                        intercept_index=config.intercept_index,
+                        seed=seed,
+                    )
+            solve_buckets = (self._proj.buckets if self._proj is not None
+                             else self.buckets.buckets)
+            if self._proj is not None:
+                # Device twins of each bucket's back-projection (gather indices /
+                # shared Gaussian matrix); they travel through sweep_data() into
+                # the fused program as arguments.  The Gaussian matrix is SHARED
+                # across buckets — upload it once, not once per bucket.
+                from photon_ml_tpu.parallel.projection import BucketProjection
+
+                # kinds are STATIC (python strings can't be jit-arg leaves);
+                # the arrays are the traced half
+                matrix_dev: Dict[int, Array] = {}
+                self._proj_kinds = []
+                self._proj_dev = []
+                for p in self._proj.projections:
+                    if isinstance(p, BucketProjection):
+                        self._proj_kinds.append("index")
+                        self._proj_dev.append(jnp.asarray(p.indices))
+                    else:
+                        self._proj_kinds.append("random")
+                        key = id(p.matrix)
+                        if key not in matrix_dev:  # one upload for the shared matrix
+                            matrix_dev[key] = jnp.asarray(p.matrix)
+                        self._proj_dev.append(matrix_dev[key])
+                self._proj_dev = tuple(self._proj_dev)
+            self._bind_solver()
             # what the bucketer made of the rows per entity: one vmapped
             # solve per class, lanes x capacity slots of which active_rows
             # hold a row; passive rows are scored and never trained on
             classes = self.buckets.buckets
             bucket_span.set(
+                line_search=self.line_search,
                 classes=len(classes),
                 capacities=[b.capacity for b in classes],
                 lanes=[b.num_lanes for b in classes],
@@ -996,51 +1063,6 @@ class RandomEffectCoordinate(Coordinate):
                 self._full = dict(slots=slots, x_full=device_put_counted(
                     x.T if self._x_full_is_t else x))
 
-        with obs_span("coord.bucket", coordinate=coordinate_id):
-            # Optional per-entity feature projection (reference
-            # RandomEffectCoordinateInProjectedSpace.scala:149): solve each bucket
-            # in a compact feature space, back-project coefficients to full dim.
-            # (A sparse shard arrives here with self._proj already built — its
-            # buckets ARE the compact space.)
-            if not self._sparse:
-                self._proj = None
-                if config.projector != ProjectorType.IDENTITY:
-                    from photon_ml_tpu.parallel.projection import project_buckets
-
-                    self._proj = project_buckets(
-                        self.buckets, config.projector,
-                        projected_dim=config.projected_dim,
-                        features_to_samples_ratio=config.features_to_samples_ratio,
-                        intercept_index=config.intercept_index,
-                        seed=seed,
-                    )
-            solve_buckets = (self._proj.buckets if self._proj is not None
-                             else self.buckets.buckets)
-            if self._proj is not None:
-                # Device twins of each bucket's back-projection (gather indices /
-                # shared Gaussian matrix); they travel through sweep_data() into
-                # the fused program as arguments.  The Gaussian matrix is SHARED
-                # across buckets — upload it once, not once per bucket.
-                from photon_ml_tpu.parallel.projection import BucketProjection
-
-                # kinds are STATIC (python strings can't be jit-arg leaves);
-                # the arrays are the traced half
-                matrix_dev: Dict[int, Array] = {}
-                self._proj_kinds = []
-                self._proj_dev = []
-                for p in self._proj.projections:
-                    if isinstance(p, BucketProjection):
-                        self._proj_kinds.append("index")
-                        self._proj_dev.append(jnp.asarray(p.indices))
-                    else:
-                        self._proj_kinds.append("random")
-                        key = id(p.matrix)
-                        if key not in matrix_dev:  # one upload for the shared matrix
-                            matrix_dev[key] = jnp.asarray(p.matrix)
-                        self._proj_dev.append(matrix_dev[key])
-                self._proj_dev = tuple(self._proj_dev)
-
-        self._bind_solver()
         self._refresh_lane_mult()
 
         # Device-resident bucket arrays, entity lane sharded over ALL mesh
@@ -1288,12 +1310,13 @@ class RandomEffectCoordinate(Coordinate):
         # cap 256, 2k lanes) 1.5x SLOWER.  cap*d^2/2 <= 1280 keeps the
         # winning regime: per-iteration Hessian traffic at or below the
         # vmapped path's padded-state traffic (128 lanes x m=10 history).
-        # That traffic has since shrunk: with the history newest-first
-        # (opt/lbfgs.py) a solver trip of the vmapped path over 65,536
-        # lanes x 128 rows x d=16 takes 35 ms on a v5e where it took 116,
-        # 31 ms of it the line search's ~17 objective evaluations; over
-        # 24,656 lanes x 32 rows 0.95 ms where it took 28.8 (PERF.md
-        # section 6, PR 26).  The line itself was not measured again.
+        # That traffic has since shrunk twice: with the history
+        # newest-first (opt/lbfgs.py) a solver trip of the vmapped path
+        # over 65,536 lanes x 128 rows x d=16 took 35 ms on a v5e where it
+        # had taken 116, and with the line search on the margins it takes
+        # 5.8 (PERF.md section 6, PRs 26 and 28); over 24,656 lanes x 32
+        # rows 1.0 ms where it took 28.8.  The line itself was not measured
+        # again.
         # The SOLVE-space shapes decide: compact sparse buckets and
         # projected (INDEX_MAP / RANDOM) buckets solve at their compact /
         # projected width, which is exactly where narrow dims live — the
@@ -1314,6 +1337,17 @@ class RandomEffectCoordinate(Coordinate):
             and self.config.reg.l1 == 0.0
             and self.config.optimizer in (OptimizerType.LBFGS,
                                           OptimizerType.TRON))
+        storage = (_storage_np_dtype(self.config.storage_dtype)
+                   or np.dtype(self._dtype))
+        # one lane's problem as the solver will see it (shapes alone)
+        cap, dd = solve_shapes[0] if solve_shapes else (0, self.dim)
+        rows = jax.ShapeDtypeStruct((cap,), self._dtype)
+        self.line_search = "none" if self._use_soa else line_search_kind(
+            objective, self.config.optimizer,
+            DenseBatch(x=jax.ShapeDtypeStruct((cap, dd), storage), y=rows,
+                       offset=rows, weight=rows),
+            jax.ShapeDtypeStruct((dd,), self._dtype),
+            box if self._box_lanes is None else self._box_lanes)
         if self._use_soa:
             solver_cfg = self.config.solver
 
@@ -1802,11 +1836,10 @@ class RandomEffectCoordinate(Coordinate):
                 if iterations_out is not None:
                     # an entity's lane holds a row in its first slot; a
                     # padding lane (a mesh's lane multiple) counts for none
-                    its = jnp.where(dev["valid"][:, 0], res.iterations, 0)
-                    iterations.append(jnp.stack([its.sum(), its.max()]))
+                    iterations.append(_solve_counts(res, dev["valid"][:, 0]))
             new_lanes.append(res.w)
         if iterations_out is not None:
-            iterations_out.append(jnp.stack(iterations).astype(jnp.int32))
+            iterations_out.append(jnp.stack(iterations))
         w_stack = self.trace_publish(tuple(new_lanes), data=data)
         with device_scope("rescore"):
             score = self._score_samples_full(w_stack, data)[: self._n]

@@ -126,11 +126,12 @@ class FusedSweep:
                 return (tuple(states), tuple(scores), tuple(vars_),
                         solved), None
 
-            # solved[i]: int32 [num_iterations, solves, 2], the sum and the
-            # maximum of solver iterations over each solve's problems
-            # (Coordinate.trace_update's ``iterations_out``)
+            # solved[i]: int32 [num_iterations, solves, 4], the sum and the
+            # maximum of solver iterations over each solve's problems, then
+            # of their line-search trials (Coordinate.trace_update's
+            # ``iterations_out``)
             solved0 = tuple(
-                jnp.zeros((self.num_iterations, coords[cid].num_solves, 2),
+                jnp.zeros((self.num_iterations, coords[cid].num_solves, 4),
                           jnp.int32) for cid in order)
             carry, _ = lax.scan(body, (states0, scores0, vars0, solved0),
                                 jnp.arange(self.num_iterations))
@@ -165,8 +166,8 @@ class FusedSweep:
         rather than re-deriving them.  ``on_update(i, cid, state_i)``:
         traced hook after each coordinate's update (the validated program's
         per-update held-out bookkeeping).  ``iterations_out``: a list that
-        gets one array of solver-iteration counts per coordinate
-        (``Coordinate.trace_update``)."""
+        gets one array of solver-iteration and line-search-trial counts per
+        coordinate (``Coordinate.trace_update``)."""
         order, coords = self.order, self.coordinates
         needs_rand = self._needs_rand
         states, scores = list(states), list(scores)
@@ -237,11 +238,13 @@ class FusedSweep:
         downloads — over slow transports those dominate) and for callers
         that pipeline further device work; ``run()`` wraps this with the
         host export.  The program's fourth output, the solver iterations
-        of every solve of every update, stays on the device as
-        ``self.solve_iterations`` (one int32 [num_iterations, solves, 2] a
-        coordinate, sum and maximum over the solve's problems); a traced
-        run fetches it into the span ``descent.solve_iterations``, which
-        waits for the program."""
+        and line-search trials of every solve of every update, stays on the
+        device as ``self.solve_iterations`` (one int32 [num_iterations,
+        solves, 4] a coordinate: sum and maximum of the iterations over the
+        solve's problems, then of the trials); a traced run fetches it into
+        the span ``descent.solve_iterations``, which waits for the program
+        and also says how each coordinate's line search evaluates a trial
+        (``Coordinate.line_search``)."""
         if obs_enabled() and not self._table_recorded:
             self._record_device_table(initial, regs, seed, carry0)
         # no fence: this is the ENQUEUE (argument preparation + dispatch),
@@ -255,7 +258,11 @@ class FusedSweep:
                 fetched = jax.device_get(self.solve_iterations)
                 sp.set(coordinates=list(self.order),
                        lane_iterations=[a[..., 0].tolist() for a in fetched],
-                       trips=[a[..., 1].tolist() for a in fetched])
+                       trips=[a[..., 1].tolist() for a in fetched],
+                       lane_trials=[a[..., 2].tolist() for a in fetched],
+                       trial_trips=[a[..., 3].tolist() for a in fetched],
+                       line_search=[self.coordinates[cid].line_search
+                                    for cid in self.order])
         return published, scores, vars_, carried
 
     def _program_args(self, initial, regs, seed, carry0):

@@ -16,14 +16,22 @@ TPU-first design decisions:
   two-loop recursion is a masked ``lax.fori_loop`` whose counter IS the slot:
   under ``vmap`` all lanes read one slot, a slice (through a circular
   buffer's per-lane insert position it is a gather: 7-9 ns an index, v5e).
-- Strong-Wolfe line search carries the accepted point's gradient, so each
-  iteration costs (1 + line-search-evals) fused value+grad passes, identical
-  to the reference's per-iteration treeAggregate count.
+- One strong-Wolfe state machine (opt/linesearch.py), two evaluators of a
+  trial step.  BY PASSES a trial is one value+grad evaluation at
+  ``w + alpha p`` whose gradient the search carries, so an iteration costs
+  its trials in evaluations, the reference's per-iteration treeAggregate
+  count: right where an evaluation reads the design once (the fused kernel)
+  and wherever the step is not affine (a box) or the sums are psum'd.  ON
+  THE MARGINS (``MarginSearch``; a GLM's margins are affine in the step) a
+  trial is elementwise work over ``z + alpha u`` and an iteration reads the
+  design TWICE whatever its trials: once for ``u = X p``, once for the
+  gradient at the accepted step; the margins ``z`` ride in the carry.
+  opt/solve.py's rule chooses.
 """
 
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,15 +46,28 @@ Array = jax.Array
 ValueAndGrad = Callable[[Array], Tuple[Array, Array]]
 
 
+class MarginSearch(NamedTuple):
+    """An objective whose margins are affine in the coefficients, as the
+    line search on the margins needs it (GLMObjective's methods of the same
+    names, bound to a batch)."""
+    # w0 -> (value, gradient, margins z at w0)
+    value_grad_margins: Callable[[Array], Tuple[Array, Array, Array]]
+    # (w, z, p) -> (alpha -> (phi, phi'),
+    #               alpha -> (value, gradient, margins) at w + alpha p)
+    along: Callable[[Array, Array, Array], Tuple[Callable, Callable]]
+
+
 class _LbfgsCarry(NamedTuple):
     w: Array
     f: Array
     g: Array
+    z: Any  # the margins at w where the search runs on them, else ()
     s_hist: Array  # [m, d], newest pair in slot 0
     y_hist: Array  # [m, d]
     rho: Array  # [m]
     count: Array  # int32 valid pairs
     it: Array  # int32
+    trials: Array  # int32: line-search trials over all iterations
     reason: Array  # int32
     tracker: StateTracker
 
@@ -108,8 +129,15 @@ def minimize_lbfgs(
     w0: Array,
     config: SolverConfig = SolverConfig(),
     box: Optional[Tuple[Array, Array]] = None,
+    margins: Optional[MarginSearch] = None,
 ) -> SolverResult:
     """Minimize a smooth objective with L-BFGS + strong Wolfe line search.
+
+    ``margins``: the same objective as a ``MarginSearch``: the line search
+    then evaluates its trials on the margins and the gradient is computed
+    once, at the accepted step (the module docstring).  Without it every
+    trial is one ``value_and_grad``.  Not with a ``box``: a projected step
+    is not affine.
 
     ``box`` = (lower[d], upper[d]) enables a gradient-projection variant
     (the reference's constrained path, OptimizationUtils.
@@ -122,6 +150,9 @@ def minimize_lbfgs(
     """
     dtype = w0.dtype
     m, d = config.history, w0.shape[-1]
+    if box is not None and margins is not None:
+        raise ValueError("a box-constrained line search is not affine in the "
+                         "step: it evaluates by passes")
 
     if box is not None:
         lower, upper = box
@@ -140,17 +171,20 @@ def minimize_lbfgs(
         free_mask = None
 
     w0 = project(w0) if project is not None else w0
-    f0, g0 = value_and_grad(w0)
+    if margins is None:
+        (f0, g0), z0 = value_and_grad(w0), ()
+    else:
+        f0, g0, z0 = margins.value_grad_margins(w0)
     g0norm = jnp.linalg.norm(opt_gradient(w0, g0))
 
     tracker = StateTracker.init(config.max_iters, dtype).record(f0, g0norm)
 
     init = _LbfgsCarry(
-        w=w0, f=f0, g=g0,
+        w=w0, f=f0, g=g0, z=z0,
         s_hist=jnp.zeros((m, d), dtype),
         y_hist=jnp.zeros((m, d), dtype),
         rho=jnp.zeros((m,), dtype),
-        count=jnp.int32(0), it=jnp.int32(0),
+        count=jnp.int32(0), it=jnp.int32(0), trials=jnp.int32(0),
         reason=jnp.int32(ConvergenceReason.NOT_CONVERGED),
         tracker=tracker,
     )
@@ -180,17 +214,28 @@ def minimize_lbfgs(
                            jnp.minimum(1.0, 1.0 / jnp.maximum(gnorm, 1e-12)),
                            jnp.ones((), dtype))
 
-        def phi_fn(alpha):
-            wt = c.w + alpha * dvec
-            wt = project(wt) if project is not None else wt
-            return value_and_grad(wt)
+        if margins is None:
+            def by_pass(alpha):
+                wt = c.w + alpha * dvec
+                wt = project(wt) if project is not None else wt
+                f, g = value_and_grad(wt)
+                return f, jnp.vdot(g, dvec), g
 
-        ls = strong_wolfe(phi_fn, c.f, c.g, dvec, alpha0,
+            evaluate, payload0 = by_pass, c.g
+        else:
+            phi, grad_at = margins.along(c.w, c.z, dvec)
+            evaluate, payload0 = (lambda alpha: (*phi(alpha), ())), ()
+
+        ls = strong_wolfe(evaluate, c.f, jnp.vdot(c.g, dvec), payload0, alpha0,
                           c1=config.c1, c2=config.c2, max_evals=config.max_linesearch)
 
         w_new = c.w + ls.alpha * dvec
         w_new = project(w_new) if project is not None else w_new
-        f_new, g_new = ls.phi, ls.g
+        f_new = ls.phi
+        if margins is None:
+            g_new, z_new = ls.payload, ()
+        else:
+            _, g_new, z_new = grad_at(ls.alpha)
 
         s_hist, y_hist, rho, count = push_pair(
             c.s_hist, c.y_hist, c.rho, c.count, w_new - c.w, g_new - c.g, ls.success)
@@ -210,8 +255,10 @@ def minimize_lbfgs(
             w=jnp.where(keep, w_new, c.w),
             f=jnp.where(keep, f_new, c.f),
             g=jnp.where(keep, g_new, c.g),
+            z=jax.tree.map(lambda new, old: jnp.where(keep, new, old),
+                           z_new, c.z),
             s_hist=s_hist, y_hist=y_hist, rho=rho, count=count,
-            it=it, reason=reason,
+            it=it, trials=c.trials + ls.num_evals, reason=reason,
             tracker=c.tracker.record(jnp.where(keep, f_new, c.f),
                                      jnp.where(keep, g_new_norm, gnorm)),
         )
@@ -225,6 +272,7 @@ def minimize_lbfgs(
         grad_norm=jnp.linalg.norm(opt_gradient(final.w, final.g)),
         iterations=final.it, reason=final.reason,
         tracker=final.tracker if config.track_states else None,
+        trials=final.trials,
     )
 
 

@@ -3,10 +3,19 @@ state machine — jittable and vmappable.
 
 The reference delegates line search to Breeze's StrongWolfeLineSearch
 (optimization/LBFGS.scala:39-108 wraps Breeze LBFGS which owns the search).
-On TPU each function evaluation is one fused value+grad pass (psum'd under
-SPMD), so the search is written to (a) evaluate at most ``max_evals`` times
-with static control flow and (b) carry the full gradient of the best point so
-the optimizer never re-evaluates it.
+The search decides from ``phi(alpha)`` and ``phi'(alpha)`` alone, so it takes
+an EVALUATOR ``alpha -> (phi, dphi, payload)`` and hands back the payload of
+the best point; what a trial costs is the evaluator's business (opt/lbfgs.py
+has the two):
+
+- by passes: a trial is one fused value+grad pass at ``w + alpha d`` (psum'd
+  under SPMD) and its payload the full gradient, carried along so that the
+  optimizer never re-evaluates the accepted point;
+- on the margins: a GLM's margins are affine in the step, so a trial is
+  elementwise work over ``z + alpha u`` that never reads the design, and its
+  payload is empty: the state holds scalars only.
+
+Either way at most ``max_evals`` trials, with static control flow.
 
 Algorithm: Nocedal & Wright, Algorithms 3.5 (bracketing) / 3.6 (zoom), with a
 safeguarded quadratic-interpolation zoom step.
@@ -14,7 +23,7 @@ safeguarded quadratic-interpolation zoom step.
 
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Tuple
+from typing import Any, Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +37,7 @@ _BRACKET, _ZOOM, _DONE, _FAILED = 0, 1, 2, 3
 class LineSearchResult(NamedTuple):
     alpha: Array  # accepted step (0.0 on failure)
     phi: Array  # f(w + alpha*d)
-    g: Array  # grad f(w + alpha*d)  [d]
+    payload: Any  # the evaluator's payload at alpha (payload0 on failure)
     success: Array  # bool: some Armijo-satisfying step found
     wolfe: Array  # bool: strong Wolfe conditions met
     num_evals: Array  # int32
@@ -47,31 +56,33 @@ class _State(NamedTuple):
     phi_lo: Array
     dphi_lo: Array
     phi_hi: Array
-    # best Armijo point so far (its full gradient rides along)
+    # best Armijo point so far (its payload rides along)
     best_alpha: Array
     best_phi: Array
-    best_g: Array
+    best_payload: Any
     wolfe: Array
 
 
 def strong_wolfe(
-    phi_fn: Callable[[Array], Tuple[Array, Array]],
+    evaluate: Callable[[Array], Tuple[Array, Array, Any]],
     phi0: Array,
-    g0: Array,
-    d: Array,
+    dphi0: Array,
+    payload0: Any,
     alpha0: Array,
     c1: float = 1e-4,
     c2: float = 0.9,
     max_evals: int = 25,
     max_alpha: float = 1e10,
 ) -> LineSearchResult:
-    """Find alpha satisfying strong Wolfe conditions along direction d.
+    """Find alpha satisfying strong Wolfe conditions along a direction.
 
-    phi_fn(alpha) -> (f(w + alpha d), grad f(w + alpha d)).
-    phi0/g0: objective value/gradient at alpha=0.
+    evaluate(alpha) -> (phi(alpha), phi'(alpha), payload): the objective and
+    its directional derivative at ``w + alpha d``, and a pytree the search
+    carries for the caller (the gradient there, or nothing).
+    phi0 / dphi0 / payload0: the same three at alpha = 0.
     """
     dtype = phi0.dtype
-    dphi0 = jnp.vdot(g0, d).astype(dtype)
+    dphi0 = dphi0.astype(dtype)
     # Approximate-Wolfe slack (Hager & Zhang 2005's remedy, eq. 4.1): near
     # an optimum the available decrease c1*alpha*dphi0 drops below the
     # ROUNDING of phi itself (easy at f32 with large-n objectives, where
@@ -89,24 +100,21 @@ def strong_wolfe(
     slack = (PLATEAU_ULPS * jnp.asarray(jnp.finfo(dtype).eps, dtype)
              * jnp.abs(phi0))
 
-    def eval_at(alpha):
-        phi, g = phi_fn(alpha)
-        return phi, g, jnp.vdot(g, d).astype(dtype)
-
     def armijo_ok(alpha, phi):
         return phi <= phi0 + c1 * alpha * dphi0 + slack
 
     def curvature_ok(dphi):
         return jnp.abs(dphi) <= -c2 * dphi0
 
-    def bracket_step(s: _State, phi, g, dphi):
+    def bracket_step(s: _State, phi, dphi, payload):
         fail_cond = ~armijo_ok(s.alpha, phi) | ((s.i > 0) & (phi >= s.phi_prev))
         curv = curvature_ok(dphi)
         pos = dphi >= 0
 
         # case 1: Armijo violated (or no decrease) -> zoom(alpha_prev, alpha).
-        # phi_lo/dphi_lo describe alpha_prev; its gradient is already in best_g
-        # (alpha_prev always satisfied Armijo, or is 0 with best_g = g0).
+        # phi_lo/dphi_lo describe alpha_prev; its payload is already in
+        # best_payload (alpha_prev always satisfied Armijo, or is 0 with
+        # best_payload = payload0).
         z1 = s._replace(
             stage=jnp.int32(_ZOOM),
             lo=s.alpha_prev, hi=s.alpha,
@@ -115,19 +123,19 @@ def strong_wolfe(
         )
         # case 2: strong Wolfe satisfied -> done at alpha.
         z2 = s._replace(stage=jnp.int32(_DONE), best_alpha=s.alpha, best_phi=phi,
-                        best_g=g, wolfe=jnp.bool_(True))
+                        best_payload=payload, wolfe=jnp.bool_(True))
         # case 3: derivative >= 0 -> zoom(alpha, alpha_prev); alpha is best.
         z3 = s._replace(
             stage=jnp.int32(_ZOOM),
             lo=s.alpha, hi=s.alpha_prev,
             phi_lo=phi, dphi_lo=dphi, phi_hi=s.phi_prev,
-            best_alpha=s.alpha, best_phi=phi, best_g=g,
+            best_alpha=s.alpha, best_phi=phi, best_payload=payload,
         )
         # case 4: keep expanding; alpha satisfies Armijo and decreases -> best.
         z4 = s._replace(
             alpha=jnp.minimum(2.0 * s.alpha, max_alpha),
             alpha_prev=s.alpha, phi_prev=phi, dphi_lo=dphi,
-            best_alpha=s.alpha, best_phi=phi, best_g=g,
+            best_alpha=s.alpha, best_phi=phi, best_payload=payload,
         )
 
         out = jax.tree.map(
@@ -136,7 +144,7 @@ def strong_wolfe(
         )
         return out
 
-    def zoom_step(s: _State, phi, g, dphi):
+    def zoom_step(s: _State, phi, dphi, payload):
         # s.alpha is the interpolated trial inside [lo, hi].
         fail_cond = ~armijo_ok(s.alpha, phi) | (phi >= s.phi_lo)
         curv = curvature_ok(dphi)
@@ -146,13 +154,13 @@ def strong_wolfe(
         z1 = s._replace(hi=s.alpha, phi_hi=phi)
         # done
         z2 = s._replace(stage=jnp.int32(_DONE), best_alpha=s.alpha, best_phi=phi,
-                        best_g=g, wolfe=jnp.bool_(True))
+                        best_payload=payload, wolfe=jnp.bool_(True))
         # new lo, possibly flipping hi to old lo
         z3 = s._replace(
             lo=s.alpha, phi_lo=phi, dphi_lo=dphi,
             hi=jnp.where(flip, s.lo, s.hi),
             phi_hi=jnp.where(flip, s.phi_lo, s.phi_hi),
-            best_alpha=s.alpha, best_phi=phi, best_g=g,
+            best_alpha=s.alpha, best_phi=phi, best_payload=payload,
         )
         out = jax.tree.map(
             lambda a, b, c: jnp.where(fail_cond, a, jnp.where(curv, b, c)),
@@ -177,10 +185,11 @@ def strong_wolfe(
         return jnp.where(bad, mid, safe)
 
     def body(s: _State) -> _State:
-        phi, g, dphi = eval_at(s.alpha)
+        phi, dphi, payload = evaluate(s.alpha)
+        dphi = dphi.astype(dtype)
         s2 = lax.cond(s.stage == _BRACKET,
-                      lambda: bracket_step(s, phi, g, dphi),
-                      lambda: zoom_step(s, phi, g, dphi))
+                      lambda: bracket_step(s, phi, dphi, payload),
+                      lambda: zoom_step(s, phi, dphi, payload))
         s2 = s2._replace(i=s.i + 1)
         # pick the next zoom trial point
         nz = next_zoom_alpha(s2)
@@ -203,7 +212,7 @@ def strong_wolfe(
         phi_hi=phi0,
         best_alpha=jnp.zeros((), dtype),
         best_phi=phi0,
-        best_g=g0,
+        best_payload=payload0,
         wolfe=jnp.bool_(False),
     )
     # Non-descent direction: fail immediately (caller restarts with -g).
@@ -214,7 +223,7 @@ def strong_wolfe(
     return LineSearchResult(
         alpha=final.best_alpha,
         phi=final.best_phi,
-        g=final.best_g,
+        payload=final.best_payload,
         success=success,
         wolfe=final.wolfe,
         num_evals=final.i,

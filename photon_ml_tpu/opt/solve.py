@@ -20,9 +20,10 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.core.batch import Batch
+from photon_ml_tpu.core.batch import Batch, DenseBatch
 from photon_ml_tpu.core.objective import GLMObjective
-from photon_ml_tpu.opt.lbfgs import minimize_lbfgs, minimize_owlqn
+from photon_ml_tpu.opt.lbfgs import (MarginSearch, minimize_lbfgs,
+                                     minimize_owlqn)
 from photon_ml_tpu.opt.tron import minimize_tron
 from photon_ml_tpu.opt.types import SolverConfig, SolverResult
 from photon_ml_tpu.types import OptimizerType, VarianceComputationType
@@ -40,6 +41,44 @@ def check_box_support(optimizer: OptimizerType, has_l1: bool) -> None:
         raise ValueError("TRON does not support box constraints")
     if optimizer == OptimizerType.OWLQN or has_l1:
         raise ValueError("OWLQN does not support box constraints")
+
+
+def line_search_kind(objective, optimizer: OptimizerType, batch: Batch,
+                     w0: Array, box=None) -> str:
+    """How the solver that ``make_solver`` builds for ``objective`` evaluates
+    a trial step of its line search: ``"margins"`` or ``"passes"``
+    (``lbfgs_trials``), or ``"none"`` where it has no strong-Wolfe search
+    (TRON; OWLQN, which an L1 weight selects).  For the build-time
+    objective, whose weights are concrete."""
+    if optimizer != OptimizerType.LBFGS or objective.reg.l1 > 0.0:
+        return "none"
+    return lbfgs_trials(objective, batch, w0, box)
+
+
+def lbfgs_trials(objective, batch: Batch, w0: Array, box=None) -> str:
+    """THE rule for the L-BFGS's line search, from what the code can observe
+    of the problem (types, shapes and dtypes only: arrays, tracers or
+    ``jax.ShapeDtypeStruct``s serve).  One algorithm, two costs of an
+    evaluation:
+
+    - ``"margins"`` where an evaluation costs TWO reads of the design and
+      the step is affine, so that searching along ``z + alpha u``
+      (GLMObjective.along) makes an iteration two reads whatever its
+      trials: a plain ``GLMObjective`` on its XLA path, no box, storage as
+      wide as the solver's state (narrower storage rounds
+      ``X bf16(w + alpha p)`` and ``X bf16(w) + alpha X bf16(p)``
+      differently);
+    - ``"passes"`` everywhere else, a trial one evaluation: the fused Mosaic
+      kernel (ONE read an evaluation, so an iteration of few trials costs
+      less than two reads and a gradient tail), a box (``project`` is not
+      affine), objectives whose sums are psum'd (``ShardMapObjective``,
+      ``ShardSparseObjective``)."""
+    storage = batch.x if isinstance(batch, DenseBatch) else batch.values
+    affine = (isinstance(objective, GLMObjective) and box is None
+              and storage.dtype == w0.dtype
+              and not (objective.fused
+                       and objective._fused_eligible(batch, w0)))
+    return "margins" if affine else "passes"
 
 
 def make_solver(
@@ -87,7 +126,12 @@ def make_solver(
             # presence rule): the random-effect coordinate passes per-lane
             # bound arrays through vmap for compact-space constrained solves.
             vg = lambda w: objective.value_and_grad(w, batch)
-            return minimize_lbfgs(vg, w0, config, box=box)
+            margins = None
+            if lbfgs_trials(objective, batch, w0, box) == "margins":
+                margins = MarginSearch(
+                    lambda w: objective.value_grad_margins(w, batch),
+                    lambda w, z, p: objective.along(w, z, p, batch))
+            return minimize_lbfgs(vg, w0, config, box=box, margins=margins)
 
         return solve_lbfgs
 

@@ -107,6 +107,10 @@ class SolverResult:
     iterations: Array  # int32
     reason: Array  # int32 ConvergenceReason
     tracker: Optional[StateTracker] = None
+    # int32: trial steps of the strong-Wolfe line search, summed over the
+    # iterations (LineSearchResult.num_evals); None where the solver has no
+    # such search (TRON, OWLQN, SoA Newton)
+    trials: Optional[Array] = None
 
     def convergence_reason(self) -> ConvergenceReason:
         return ConvergenceReason(int(self.reason))

@@ -201,15 +201,49 @@ def test_solver_iterations_leave_the_program(built):
     assert len(out) == 4
     its = [np.asarray(a) for a in sweep.solve_iterations]
     solves = [1] + [spans[c]["classes"] for c in ("per-user", "per-item")]
-    assert [a.shape for a in its] == [(int(cfg["sweeps"]), s, 2)
+    assert [a.shape for a in its] == [(int(cfg["sweeps"]), s, 4)
                                       for s in solves]
     assert all(a.dtype == np.int32 for a in its)
     for a, cid in zip(its[1:], ("per-user", "per-item")):
-        total, most = a[..., 0], a[..., 1]
+        total, most, trials, most_trials = np.moveaxis(a, -1, 0)
         lanes = np.asarray(spans[cid]["lanes"])
         assert (most >= 1).all() and (most <= 30).all()
         assert (total <= most * lanes).all() and (total >= most).all()
+        # a trip makes 1 to max_linesearch trials in the lane that makes most
+        assert (most_trials >= most).all() and (most_trials <= 25 * most).all()
+        assert (trials <= most_trials * lanes).all()
+        assert (trials >= total).all()
     assert (its[0][..., 0] == its[0][..., 1]).all()  # one problem
+    assert (its[0][..., 2] == its[0][..., 3]).all()
+    # the dry run's CPU takes no fused kernel: all three search the margins
+    assert [coords[c].line_search for c in ("fixed", "per-user", "per-item")
+            ] == ["margins"] * 3
+    assert [spans[c]["line_search"] for c in ("per-user", "per-item")
+            ] == ["margins"] * 2
+
+
+def test_a_traced_fit_reports_trials_and_the_line_search(built):
+    """The span ``descent.solve_iterations`` of a traced fit: what
+    ``solve_lane_waste_share`` reads, unchanged, and beside it the line
+    search's trials and how each coordinate's search evaluates one."""
+    cfg, _, coords, _ = built[SEEDS[0]]
+    sweep = FusedSweep(coords, num_iterations=int(cfg["sweeps"]))
+    prev = set_tracer(Tracer(capacity=4096, enabled=True))
+    try:
+        sweep.run_device()
+        (span,) = [r["attrs"] for r in obs.get_tracer().records()
+                   if r["name"] == "descent.solve_iterations"]
+    finally:
+        set_tracer(prev)
+    assert list(span) == ["coordinates", "lane_iterations", "trips",
+                          "lane_trials", "trial_trips", "line_search"]
+    assert span["coordinates"] == ["fixed", "per-user", "per-item"]
+    assert span["line_search"] == [coords[c].line_search
+                                   for c in span["coordinates"]]
+    for k, name in enumerate(["lane_iterations", "trips", "lane_trials",
+                              "trial_trips"]):
+        assert span[name] == [np.asarray(a)[..., k].tolist()
+                              for a in sweep.solve_iterations]
 
 
 # -- (b) the class rule --------------------------------------------------------
@@ -369,20 +403,29 @@ def traced():
 
 def test_readers_on_a_synthetic_reading(traced):
     traced.record_device_table("jit_program", TABLE)
+    # per-user as the program records it since PR 28 (one span, with how
+    # the line search evaluates), per-item as its parent did (two spans)
     traced.complete("coord.bucket", 0, 10, coordinate="per-user", classes=2,
+                    line_search="margins",
                     capacities=[64, 256], lanes=[10, 4], slots=1664,
                     active_rows=1000, capped_entities=1, passive_rows=7)
-    traced.complete("coord.bucket", 0, 10, coordinate="per-user")  # projection
     traced.complete("coord.bucket", 0, 10, coordinate="per-item", classes=2,
                     capacities=[16, 1024], lanes=[6, 2], slots=2144,
                     active_rows=904, capped_entities=2, passive_rows=9)
-    for _ in range(2):  # two traced fits of two updates
+    traced.complete("coord.bucket", 0, 10, coordinate="per-item")  # projection
+    for fit in range(2):  # two traced fits of two updates
+        since_pr28 = dict(
+            lane_trials=[[[9], [7]], [[80, 31], [66, 20]],
+                         [[41, 12], [30, 13]]],
+            trial_trips=[[[9], [7]], [[22, 9], [19, 7]], [[11, 6], [9, 6]]],
+            line_search=["passes", "margins", "margins"]) if fit else {}
         traced.complete(
             "descent.solve_iterations", 0, 10,
             coordinates=["fixed", "per-user", "per-item"],
             lane_iterations=[[[5], [4]], [[50, 20], [40, 16]],
                              [[30, 10], [24, 10]]],
-            trips=[[[5], [4]], [[10, 5], [10, 4]], [[6, 5], [6, 5]]])
+            trips=[[[5], [4]], [[10, 5], [10, 4]], [[6, 5], [6, 5]]],
+            **since_pr28)
     r = readings()
     assert reader("solve_classes").read(r) == 4
     assert reader("solve_slot_fill").read(r) == pytest.approx(
