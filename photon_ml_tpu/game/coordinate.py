@@ -27,7 +27,7 @@ from typing import Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import Mesh, NamedSharding
 
 from photon_ml_tpu.core.batch import DenseBatch, SparseBatch
 from photon_ml_tpu.core.losses import loss_for_task
@@ -44,9 +44,16 @@ from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.opt.solve import line_search_kind, make_solver
 from photon_ml_tpu.opt.types import SolverResult
 from photon_ml_tpu.parallel.bucketing import bucket_by_entity, stacked_coefficients
-from photon_ml_tpu.parallel.mesh import replicate, shard_batch
+from photon_ml_tpu.parallel.mesh import (SAMPLE_TILE, exchange_bytes,
+                                         lanes_of, on_chips, over_chips,
+                                         padded_samples, put_over_chips,
+                                         replicate, samples_on_device,
+                                         score_entity_major,
+                                         score_in_sample_order, shard_batch,
+                                         spans_chips, stack_lanes)
 from photon_ml_tpu.types import (OptimizerType, ProjectorType, TaskType,
                                  VarianceComputationType)
+from photon_ml_tpu.utils.transfer import device_put_counted
 
 Array = jax.Array
 
@@ -80,6 +87,21 @@ class Coordinate:
     @property
     def num_samples(self) -> int:
         return self._n
+
+    @property
+    def carry_samples(self) -> int:
+        """Length of the ``[n]`` vectors the traceable steps exchange
+        (``trace_update``'s offsets and scores): ``num_samples``, or under
+        a mesh ``padded_samples`` of it (the sample axis is sharded in
+        whole tiles a device; the rows behind ``num_samples`` are
+        padding)."""
+        return padded_samples(self._n, getattr(self, "mesh", None))
+
+    def exchange_bytes(self) -> Dict[str, int]:
+        """{exchange kind: bytes a chip sends in ONE update (``psum``: one
+        objective evaluation)}, from the shapes and shardings of what
+        crosses chips; empty without a mesh."""
+        return {}
 
     def _base_offset_host(self) -> np.ndarray:
         """Dataset base offsets [n] (residual offsets are added on top)."""
@@ -264,15 +286,30 @@ def _storage_np_dtype(storage_dtype: Optional[str]):
 
 
 @contextlib.contextmanager
-def _upload_span(coordinate_id: str):
+def _upload_span(coordinate_id: str, mesh: Optional[Mesh]):
     """``coord.upload``: a coordinate's arrays on their way to the device;
     ``bytes`` is what ``device_put_counted`` counted inside (0 where the
-    design was already there — the span is recorded all the same)."""
+    design was already there — the span is recorded all the same).  Yields
+    ``placed(tree)``, which the coordinate hands what it keeps on the
+    device: over the ``devices`` of the mesh, ``bytes_sharded`` are held
+    once (each device a part) and ``bytes_replicated`` whole on every
+    device (counted once); on one device nothing is replicated."""
     with obs_span("coord.upload", coordinate=coordinate_id) as sp:
         probe = get_probe()
         before = probe.transfer_bytes("h2d", site="device_put")
-        yield
-        sp.set(bytes=probe.transfer_bytes("h2d", site="device_put") - before)
+        held = {"bytes_sharded": 0, "bytes_replicated": 0}
+
+        def placed(tree):
+            for a in jax.tree.leaves(tree):
+                if isinstance(a, jax.Array):
+                    whole = (mesh is not None
+                             and a.sharding.shard_shape(a.shape) == a.shape)
+                    held["bytes_replicated" if whole
+                         else "bytes_sharded"] += a.nbytes
+
+        yield placed
+        sp.set(bytes=probe.transfer_bytes("h2d", site="device_put") - before,
+               devices=1 if mesh is None else mesh.size, **held)
 
 
 def _where_it_is(a, dtype=None):
@@ -292,13 +329,24 @@ class FixedEffectCoordinate(Coordinate):
         self.coordinate_id = coordinate_id
         self.config = config
         self.task = task
-        self.mesh = mesh
+        self.mesh = mesh = spans_chips(mesh)
         self.dim = data.shard_dim(config.feature_shard)
         self._n = data.num_samples
         self._dtype = dtype
         self._base_offset = np.asarray(data.offset, np.float64)
 
         shard_data = data.features[config.feature_shard]
+        # a design handed over in row shards ends in the sample axis's
+        # padding rows (GameData): they get label 0, offset 0 and weight 0
+        tail = shard_data.shape[0] - self._n
+        if tail and (mesh is None or shard_data.shape[0]
+                     != padded_samples(self._n, mesh)):
+            raise ValueError(
+                f"coordinate {coordinate_id!r}: feature shard "
+                f"{config.feature_shard!r} has {shard_data.shape[0]} rows, "
+                f"expected {self._n}"
+                + ("" if mesh is None else
+                   f" or, in row shards, {padded_samples(self._n, mesh)}"))
         # Storage narrowing happens ON HOST so the device transfer and the
         # resident array are storage-width from the start (an on-device cast
         # would transfer f32 and transiently hold both copies in HBM).
@@ -308,9 +356,8 @@ class FixedEffectCoordinate(Coordinate):
                                                  storage_narrowing_ok)
         from photon_ml_tpu.parallel.mesh import (DATA_AXIS, FEATURE_AXIS,
                                                  padded_dim)
-        from photon_ml_tpu.utils.transfer import device_put_counted
 
-        with _upload_span(coordinate_id):
+        with _upload_span(coordinate_id, mesh) as placed:
             # Under a mesh every leaf goes from where it is (host, for loaded
             # data) straight to its shards: staging the whole design on the
             # default device first would need one chip to hold all of it.
@@ -326,9 +373,8 @@ class FixedEffectCoordinate(Coordinate):
                 wt0 = (jnp.ones(self._n, dtype) if np.all(wt_np == 1.0)
                        else jnp.asarray(wt_np))
             else:
-                y = np.asarray(data.y, dtype)
-                offs0 = np.asarray(data.offset, dtype)
-                wt0 = np.asarray(data.weight, dtype)
+                y, offs0, wt0 = (np.pad(np.asarray(a, dtype), (0, tail))
+                                 for a in (data.y, data.offset, data.weight))
             if isinstance(shard_data, SparseShard):
                 batch = SparseBatch(
                     indices=put(shard_data.indices),
@@ -357,18 +403,27 @@ class FixedEffectCoordinate(Coordinate):
             n_dev = 1 if mesh is None else mesh.shape[DATA_AXIS]
             pad_to = None
             if fused_ok:
-                # pad so each device's LOCAL shard is a block multiple.  On
-                # one device a design of MANY blocks stays as it is: the
-                # kernels take its whole blocks in place and its last rows
-                # as a batch of their own (fused_glm.runs_in_place), where
-                # padding would copy all of it beside the original that
-                # the caller's data still holds
+                # pad so each device's LOCAL shard is a block multiple.  A
+                # shard of MANY blocks stays as it is, on one device and
+                # under a mesh (``ShardMapObjective`` runs the kernels on
+                # each device's own rows): they take its whole blocks in
+                # place and its last rows as a batch of their own
+                # (fused_glm.runs_in_place), where padding would copy all
+                # of it beside the original that the caller's data still
+                # holds.  Under a mesh the rows are then padded to the next
+                # multiple of the devices only (shard_batch), and a design
+                # that arrives in its shards with that many rows is not
+                # touched at all
                 local = -(-batch.num_examples // n_dev)
                 bn = _pick_block_rows(local, batch.dim,
                                       np.dtype(batch.x.dtype).itemsize)
-                if mesh is not None or not runs_in_place(local, bn):
+                if not runs_in_place(local, bn):
                     pad_to = (-(-local // bn) * bn) * n_dev
             if mesh is not None:
+                # at least the sweep's sample axis (carry_samples: over
+                # EVERY device, where the batch is over the data axis)
+                pad_to = max(pad_to or 0,
+                             padded_samples(batch.num_examples, mesh))
                 batch = shard_batch(
                     batch, mesh, pad_to=pad_to,
                     feature_axis=FEATURE_AXIS
@@ -377,6 +432,7 @@ class FixedEffectCoordinate(Coordinate):
                 from photon_ml_tpu.ops.fused_glm import _pad_rows
 
                 batch = _pad_rows(batch, pad_to)
+            placed(batch)
         self._batch = batch
         self._padded_n = batch.num_examples
         self._base_weight = batch.weight
@@ -542,7 +598,10 @@ class FixedEffectCoordinate(Coordinate):
         original-space, so warm starts convert back in."""
         ii = self.config.intercept_index
         w0 = self._initial_state(init)
-        offs = jnp.asarray(self._pad(np.asarray(total_offsets, self._dtype)))
+        offs = self._pad(np.asarray(total_offsets, self._dtype))
+        # under a mesh each shard goes to its own device, like the batch
+        offs = (jnp.asarray(offs) if self.mesh is None
+                else jax.device_put(offs, self._batch.offset.sharding))
         weights = self._down_sample_weights(seed)
         res = self._solve(w0, self._batch.replace(offset=offs, weight=weights),
                           self.config.reg)
@@ -605,7 +664,7 @@ class FixedEffectCoordinate(Coordinate):
         sweep update sees; trace_update and trace_variances must agree on it
         (down-sampled weights are re-drawn from the same key, so XLA CSEs the
         duplicate draw and the variance weights match the update's exactly)."""
-        pad = self._padded_n - self._n
+        pad = self._padded_n - self.carry_samples
         offs = (jnp.pad(offsets, (0, pad)) if pad else offsets).astype(self._dtype)
         if self.config.down_sampling_rate < 1.0 and key is not None:
             keep = (jax.random.uniform(key, (self._padded_n,))
@@ -632,9 +691,9 @@ class FixedEffectCoordinate(Coordinate):
                 # pinned communication: one [n_local] feature-axis psum
                 # instead of GSPMD all-gathering the full sharded
                 # coefficient vector
-                return res.w, self._objective.margins(w_pub,
-                                                      batch)[: self._n]
-            return res.w, batch.margins(w_pub)[: self._n]
+                return res.w, self._objective.margins(
+                    w_pub, batch)[: self.carry_samples]
+            return res.w, batch.margins(w_pub)[: self.carry_samples]
 
     def trace_publish(self, state: Array, data=None) -> Array:
         with device_scope("publish"):
@@ -646,6 +705,16 @@ class FixedEffectCoordinate(Coordinate):
             coefficients=Coefficients(
                 means=np.asarray(published)[: self.dim]),
             feature_shard=self.config.feature_shard, task=self.task)
+
+    def exchange_bytes(self) -> Dict[str, int]:
+        """``psum``: ShardMapObjective's one all-reduce of (value, gradient
+        [d], residual sum) an objective evaluation."""
+        from photon_ml_tpu.parallel.fixed import ShardMapObjective
+
+        if not isinstance(self._objective, ShardMapObjective):
+            return {}
+        return exchange_bytes(self.mesh, {}, {
+            "psum": (self.dim + 2) * np.dtype(self._dtype).itemsize})
 
     def init_sweep_variances(self) -> Array:
         if self.config.variance == VarianceComputationType.NONE:
@@ -685,8 +754,6 @@ class FixedEffectCoordinate(Coordinate):
         """Held-out design for this shard, device-resident once (dense
         [n, d] or the SparseShard COO pair) — the same layout
         FixedEffectModel.score consumes."""
-        from photon_ml_tpu.utils.transfer import device_put_counted
-
         shard = data.features[self.config.feature_shard]
         if isinstance(shard, SparseShard):
             return {"x_idx": device_put_counted(shard.indices, np.int32),
@@ -779,7 +846,7 @@ class RandomEffectCoordinate(Coordinate):
         self.coordinate_id = coordinate_id
         self.config = config
         self.task = task
-        self.mesh = mesh
+        self.mesh = mesh = spans_chips(mesh)
         self._n = data.num_samples
         self._dtype = dtype
         self.dim = data.shard_dim(config.feature_shard)
@@ -818,8 +885,13 @@ class RandomEffectCoordinate(Coordinate):
         self._base_offset = np.asarray(data.offset, np.float64)
 
         shard_data = data.features[config.feature_shard]
+        if shard_data.shape[0] != self._n:  # only a fixed design may be padded
+            raise ValueError(
+                f"coordinate {coordinate_id!r}: feature shard "
+                f"{config.feature_shard!r} has {shard_data.shape[0]} rows, "
+                f"expected {self._n}")
         entity_ids = data.id_tags[config.random_effect_type]
-        lane_multiple = int(np.prod(list(mesh.shape.values()))) if mesh is not None else 1
+        lane_multiple = 1 if mesh is None else mesh.size
         self._sparse = isinstance(shard_data, SparseShard)
         if (self._norm is not None and self._norm.shifts is not None
                 and config.intercept_index is None
@@ -998,6 +1070,8 @@ class RandomEffectCoordinate(Coordinate):
                 classes=len(classes),
                 capacities=[b.capacity for b in classes],
                 lanes=[b.num_lanes for b in classes],
+                lanes_per_device=[b.num_lanes // lane_multiple
+                                  for b in classes],
                 slots=sum(b.num_lanes * b.capacity for b in classes),
                 active_rows=sum(int(b.counts.sum()) for b in classes),
                 capped_entities=self.buckets.capped_entities,
@@ -1009,7 +1083,7 @@ class RandomEffectCoordinate(Coordinate):
             # out-of-range index so device scatters drop them (stack_bucket_lanes)
             ne = len(self._sorted_ids)
             self._slot_idx_dev = [
-                jnp.asarray(np.where(
+                (jnp.asarray if mesh is None else self._put_entity)(np.where(
                     (s := _slots_from(self._slot_of,
                                       np.asarray(b.entity_lanes, np.int64))) < 0,
                     ne, s).astype(np.int32))
@@ -1022,13 +1096,24 @@ class RandomEffectCoordinate(Coordinate):
             # the entity of each sample); the entity-major one by per-CHUNK
             # slots (the entity of each chunk), its design and ``pos`` in the
             # place of ``x_full`` and ``slots``, not beside them.
-            from photon_ml_tpu.parallel.bucketing import entity_major_layout
+            from photon_ml_tpu.parallel.bucketing import (EM_ROW,
+                                                          entity_major_layout)
             self._em = None
             self._x_full_is_t = False
             with obs_span("coord.rescore_layout",
                           coordinate=coordinate_id) as layout_span:
                 if narrow:
-                    self._em = entity_major_layout(runs)
+                    # whole tiles of the entity-major scores a device, as
+                    # of every [n] vector (mesh.padded_samples)
+                    self._em = entity_major_layout(
+                        runs, 1 if mesh is None else lane_multiple
+                        * SAMPLE_TILE // EM_ROW)
+                if (mesh is not None and self._em is not None
+                        and self._em.pos is None and self._em.lanes
+                        * self._em.chunk != self.carry_samples):
+                    # under a mesh the chunks are the sample order only
+                    # where they are so shard for shard
+                    self._em.pos = np.arange(self._n, dtype=np.int32)
                 if self._em is not None:
                     self._slot_ids = self._em.entities
                     layout_span.set(layout="entity_major", chunk=self._em.chunk,
@@ -1040,12 +1125,38 @@ class RandomEffectCoordinate(Coordinate):
                     layout_span.set(layout="sparse" if self._sparse
                                     else "transposed" if narrow
                                     else "row_major")
-                slots = jnp.asarray(self._slots_under(self._slot_of))
-        from photon_ml_tpu.parallel.bucketing import entity_major_design
-        from photon_ml_tpu.utils.transfer import device_put_counted
-        with _upload_span(coordinate_id):
-            # what sweep_data() hands the rescore of _score_samples_full
-            if self._sparse:
+                slots = self._put_slots(self._slots_under(self._slot_of))
+        from photon_ml_tpu.parallel.bucketing import (entity_major_design,
+                                                      entity_major_design_over)
+        with _upload_span(coordinate_id, mesh) as placed:
+            # what sweep_data() hands the rescore of _score_samples_full.
+            # Under a mesh the sample axis (the entity-major design's rows
+            # of chunks) goes over every device, each shard from the host
+            # straight to its chip, padded to the devices' multiple: slot
+            # -1 and zero features score 0, a padding sample's position is
+            # the layout's last, which is nobody's
+            if mesh is not None:
+                def put(a, axis=0, **kw):
+                    return put_over_chips(_where_it_is(a), mesh, axis, **kw)
+
+                if self._sparse:
+                    self._full = dict(
+                        slots=slots,
+                        x_idx=put(np.asarray(shard_data.indices, np.int32)),
+                        x_val=put(np.asarray(shard_data.values, dtype)))
+                elif self._em is not None:
+                    # a streamed (device) shard is fetched: it is narrow
+                    self._full = dict(
+                        lane_slot=slots,
+                        x_em=entity_major_design_over(self._em,
+                                                      np.asarray(x), mesh),
+                        pos=None if self._em.pos is None else put(
+                            self._em.pos, length=self.carry_samples,
+                            fill=self._em.lanes * self._em.chunk - 1))
+                else:
+                    self._full = dict(slots=slots, x_full=put(
+                        x.T, 1) if self._x_full_is_t else put(x))
+            elif self._sparse:
                 # full-sample scoring stays sparse: [n, k] gather arrays, never
                 # an [n, d_full] densified design (score_samples_sparse)
                 self._full = dict(
@@ -1062,21 +1173,11 @@ class RandomEffectCoordinate(Coordinate):
             else:
                 self._full = dict(slots=slots, x_full=device_put_counted(
                     x.T if self._x_full_is_t else x))
+            placed(self._full)
 
         self._refresh_lane_mult()
 
-        # Device-resident bucket arrays, entity lane sharded over ALL mesh
-        # devices (the reference's balanced entity partitioner,
-        # RandomEffectDatasetPartitioner.scala:30-171).
-        def put(a):
-            if mesh is None:
-                return device_put_counted(a)
-            # host arrays go straight to their shards; nothing is staged
-            # whole on the default device first
-            spec = PartitionSpec(tuple(mesh.axis_names), *([None] * (a.ndim - 1)))
-            return jax.device_put(a, NamedSharding(mesh, spec))
-
-        self._put_entity = put
+        put = self._put_entity
         sd = _storage_np_dtype(self.config.storage_dtype)  # host-side cast:
         # transfer + HBM residency are storage-width from the start
 
@@ -1089,7 +1190,7 @@ class RandomEffectCoordinate(Coordinate):
                 return bx.astype(sd)
             return np.asarray(bx).astype(sd)
 
-        with _upload_span(coordinate_id):
+        with _upload_span(coordinate_id, mesh) as placed:
             self._dev = [
                 dict(x=put(_narrow(b.x)),
                      y=put(b.y), w=put(b.weight),
@@ -1097,6 +1198,7 @@ class RandomEffectCoordinate(Coordinate):
                      valid=put(b.rows >= 0))
                 for b in solve_buckets
             ]
+            placed((self._dev, self._slot_idx_dev))
         # INDEX_MAP/sparse + normalization: project the coordinate context
         # into each entity's compact space (the reference's per-REId
         # contexts, NormalizationContextRDD through the per-entity
@@ -1148,6 +1250,60 @@ class RandomEffectCoordinate(Coordinate):
                 self._norm_ii_np = lanes_ii
                 self._norm_shift_dev = [put(s) for s in lanes_sh]
                 self._norm_ii_dev = [put(i) for i in lanes_ii]
+
+    def _put_entity(self, a):
+        """Device-resident per-lane arrays, the entity lane sharded over ALL
+        mesh devices (the reference's balanced entity partitioner,
+        RandomEffectDatasetPartitioner.scala:30-171): host arrays go
+        straight to their shards, nothing is staged whole on the default
+        device first."""
+        if self.mesh is None:
+            return device_put_counted(a)
+        return jax.device_put(a, NamedSharding(
+            self.mesh, over_chips(self.mesh, a.ndim)))
+
+    def _put_slots(self, slots: np.ndarray) -> Array:
+        """The full-sample layout's slot vector (``_slots_under``) on the
+        device; under a mesh its sample axis ([n], padded with -1) or its
+        rows of chunks ([k, R]) over every device."""
+        if self.mesh is None:
+            return jnp.asarray(slots)
+        if slots.ndim == 2:  # the layout's R is a multiple of the devices
+            return put_over_chips(slots, self.mesh, 1, slots.shape[1])
+        return put_over_chips(slots, self.mesh, fill=-1)
+
+    def _offsets_into_lanes(self, offsets: Array, devs):
+        """``gather(bi)``: the residual offsets of bucket ``bi``'s lanes,
+        ``where(valid, offsets[rows], 0)``.  Under a mesh the sample-sharded
+        offsets meet entity-sharded lanes: exchange ``offsets``, ONE
+        all-gather of the ``[n]`` vector an update and each chip's own
+        lane gathers, every class's at once (``lanes_of``)."""
+        if self.mesh is None:
+            def gather(bi):
+                dev = devs[bi]
+                with device_scope("entity_gather"):
+                    return jnp.where(dev["valid"], offsets[dev["rows"]], 0.0)
+            return gather
+        lanes = lanes_of(offsets, [dev["rows"] for dev in devs],
+                         [dev["valid"] for dev in devs], self.mesh)
+        return lanes.__getitem__
+
+    def exchange_bytes(self) -> Dict[str, int]:
+        """What one update of this coordinate sends over the chips: the
+        ``[n]`` offsets (all-gather), the coefficient table (psum), where
+        the entity-major scores have to go back to sample order their
+        ``[R x 128]`` (all-gather), and the solves' iteration counts (four
+        int32 a class, all-reduced)."""
+        if self.mesh is None:
+            return {}
+        size = np.dtype(self._dtype).itemsize
+        scores = (self._em.lanes * self._em.chunk * size
+                  if self._em is not None and self._em.pos is not None else 0)
+        return exchange_bytes(
+            self.mesh, {"offsets": self.carry_samples * size,
+                        "scores": scores},
+            {"publish": len(self._sorted_ids) * self.dim * size,
+             "counts": self.num_solves * 4 * 4})
 
     def _bind_solver(self) -> None:
         # shared-context normalization (IDENTITY projector) bakes into the
@@ -1286,6 +1442,14 @@ class RandomEffectCoordinate(Coordinate):
         def _vsolve(w0, x_b, y_b, off_b, wt_b, reg, *extras_b):
             return jax.vmap(_one)(w0, x_b, y_b, off_b, wt_b, reg, *extras_b)
 
+        if self.mesh is not None:
+            # lanes are independent problems, entity-sharded over every
+            # mesh axis: each device solves its own lanes as a local
+            # program whose loops run to ITS slowest lane, with no
+            # collective (under GSPMD every trip's any-lane-active
+            # reduction would be one)
+            lanes = over_chips(self.mesh)
+            _vsolve = on_chips(_vsolve, self.mesh, lanes, lanes)
         self._vsolve = jax.jit(_vsolve)
 
         # Narrow dense lanes swap in the structure-of-arrays Newton solver:
@@ -1367,12 +1531,8 @@ class RandomEffectCoordinate(Coordinate):
                 # be explicit — Mosaic kernels cannot be partitioned
                 # automatically, so under plain GSPMD the pallas Newton step
                 # fails at lowering.  check_vma off: see ShardMapObjective.
-                from jax import shard_map
-
-                lanes = PartitionSpec(tuple(self.mesh.axis_names))
-                _solve_lanes = shard_map(
-                    _solve_lanes, mesh=self.mesh, in_specs=lanes,
-                    out_specs=lanes, check_vma=False)
+                lanes = over_chips(self.mesh)
+                _solve_lanes = on_chips(_solve_lanes, self.mesh, lanes, lanes)
 
             def _vsolve_soa(w0, x_b, y_b, off_b, wt_b, reg):
                 return _solve_lanes(w0, x_b, y_b, off_b, wt_b, reg.l2)
@@ -1619,7 +1779,9 @@ class RandomEffectCoordinate(Coordinate):
                init: Optional[RandomEffectModel] = None
                ) -> Tuple[RandomEffectModel, List[SolverResult]]:
         init = self._dense_init(init)
-        offs = jnp.asarray(np.asarray(total_offsets, self._dtype))
+        lane_offsets = self._offsets_into_lanes(
+            samples_on_device(total_offsets, self.mesh, self._dtype),
+            self._dev)
         coeffs = []
         variances = [] if self._vvar is not None else None
         results = []
@@ -1631,7 +1793,7 @@ class RandomEffectCoordinate(Coordinate):
             else:
                 w0 = self._put_entity(np.zeros((b.num_lanes, solve_dim), self._dtype))
             # residual offsets gathered into the bucket layout
-            off_b = jnp.where(dev["valid"], offs[dev["rows"]], 0.0).astype(self._dtype)
+            off_b = lane_offsets(bi).astype(self._dtype)
             # one span + histogram sample per bucket solve, device-accurate
             # (block inside the span — the host-paced loop is per-phase
             # dispatch anyway; the fused sweep is where pipelining lives)
@@ -1740,7 +1902,7 @@ class RandomEffectCoordinate(Coordinate):
         data = self._full
         if only is not None or slot_of != self._slot_of:
             key = "slots" if self._em is None else "lane_slot"
-            data = dict(data, **{key: jnp.asarray(
+            data = dict(data, **{key: self._put_slots(
                 self._slots_under(slot_of, only))})
         w = jnp.asarray(np.asarray(w_stack, self._dtype))
         return np.asarray(self._score_samples_full(w, data))[: self._n]
@@ -1769,15 +1931,25 @@ class RandomEffectCoordinate(Coordinate):
                                                       score_samples_sparse,
                                                       score_samples_t)
 
-        if self._sparse:
-            return score_samples_sparse(w_stack, data["slots"],
-                                        data["x_idx"], data["x_val"])
         if self._em is not None:
+            if self.mesh is not None:
+                return score_entity_major(w_stack, data["lane_slot"],
+                                          data["x_em"], data["pos"],
+                                          self.mesh)
             return score_samples_em(w_stack, data["lane_slot"], data["x_em"],
                                     data["pos"])
-        if self._x_full_is_t:
-            return score_samples_t(w_stack, data["slots"], data["x_full"])
-        return score_samples(w_stack, data["slots"], data["x_full"])
+        if self._sparse:
+            score, design = score_samples_sparse, (data["x_idx"],
+                                                   data["x_val"])
+        else:
+            score = score_samples_t if self._x_full_is_t else score_samples
+            design = (data["x_full"],)
+        if self.mesh is not None:
+            # each chip scores its own samples from the replicated table
+            return score_in_sample_order(
+                score, w_stack, self.mesh, (data["slots"], *design),
+                sample_axis=int(self._x_full_is_t))
+        return score(w_stack, data["slots"], *design)
 
     # --- traceable-step interface (game/fused.py) ---
     # State = tuple of per-bucket lane coefficient arrays [(lanes, d), ...].
@@ -1824,25 +1996,33 @@ class RandomEffectCoordinate(Coordinate):
             data = self.sweep_data()
         reg = self.config.reg if reg is None else reg
         lane_regs = self._lane_regs(reg)
-        offsets = offsets.astype(self._dtype)
+        lane_offsets = self._offsets_into_lanes(offsets.astype(self._dtype),
+                                                data["dev"])
         new_lanes, iterations = [], []
         for bi, (lanes, dev) in enumerate(zip(state, data["dev"])):
-            with device_scope("entity_gather"):
-                off_b = jnp.where(dev["valid"], offsets[dev["rows"]], 0.0)
+            off_b = lane_offsets(bi)
             with device_scope("entity_solve", f"b{bi}"):
                 res = self._vsolve(lanes, dev["x"], dev["y"], off_b,
                                    dev["w"], lane_regs[bi],
                                    *self._solve_extras(bi, data))
                 if iterations_out is not None:
                     # an entity's lane holds a row in its first slot; a
-                    # padding lane (a mesh's lane multiple) counts for none
-                    iterations.append(_solve_counts(res, dev["valid"][:, 0]))
+                    # padding lane (a mesh's lane multiple) counts for none.
+                    # Under a mesh the sum and the maximum over the lanes
+                    # cross chips: exchange ``counts``, four scalars a
+                    # class, the partitioner's all-reduce
+                    with (device_scope("exchange", "counts")
+                          if self.mesh is not None
+                          else contextlib.nullcontext()):
+                        iterations.append(
+                            _solve_counts(res, dev["valid"][:, 0]))
             new_lanes.append(res.w)
         if iterations_out is not None:
             iterations_out.append(jnp.stack(iterations))
         w_stack = self.trace_publish(tuple(new_lanes), data=data)
         with device_scope("rescore"):
-            score = self._score_samples_full(w_stack, data)[: self._n]
+            score = self._score_samples_full(
+                w_stack, data)[: self.carry_samples]
         return tuple(new_lanes), score
 
     def trace_publish(self, state: Tuple[Array, ...], data=None) -> Array:
@@ -1870,6 +2050,9 @@ class RandomEffectCoordinate(Coordinate):
             state = tuple(self._traced_back_project(bi, proj[bi], lanes,
                                                     fill=data.get("box_fill"))
                           for bi, lanes in enumerate(state))
+        if self.mesh is not None:
+            return stack_lanes(state, self._slot_idx_dev,
+                               len(self._sorted_ids), self.mesh)
         return stack_bucket_lanes(state, self._slot_idx_dev,
                                   len(self._sorted_ids))
 
@@ -1917,11 +2100,12 @@ class RandomEffectCoordinate(Coordinate):
         traced ``reg``, vmapped per bucket exactly as the host path's
         update() does."""
         dev_buckets = self._dev if data is None else data["dev"]
-        offs = offsets.astype(self._dtype)
+        lane_offsets = self._offsets_into_lanes(offsets.astype(self._dtype),
+                                                dev_buckets)
         lane_regs = self._lane_regs(self.config.reg if reg is None else reg)
         out = []
         for bi, (lanes, dev) in enumerate(zip(state, dev_buckets)):
-            off_b = jnp.where(dev["valid"], offs[dev["rows"]], 0.0)
+            off_b = lane_offsets(bi)
             v = self._vvar(lanes, dev["x"], dev["y"], off_b,
                            dev["w"], lane_regs[bi])
             if self._compact_variances:
@@ -1942,8 +2126,6 @@ class RandomEffectCoordinate(Coordinate):
         slot order (the stacked layout ``trace_publish`` emits); entities
         this run never trained get -1 and score 0 — carried warm-start
         entities are a host-side CONSTANT (``carry_through_scores_on``)."""
-        from photon_ml_tpu.utils.transfer import device_put_counted
-
         shard = data.features[self.config.feature_shard]
         ids = np.asarray(data.id_tags[self.config.random_effect_type],
                          np.int64)

@@ -54,7 +54,13 @@ class GameData:
     """Columnar GAME dataset (training or validation)."""
 
     y: np.ndarray  # [n]
-    features: Dict[str, "ShardData"]  # shard id -> [n, d] dense matrix or SparseShard
+    #: shard id -> [n, d] dense matrix or SparseShard.  A dense design handed
+    #: over in ROW SHARDS (a ``jax.Array`` over more than one device) may
+    #: have more rows than ``n``: a row count that does not divide by the
+    #: devices cannot be sharded, so the rows behind ``n`` are padding.  Only
+    #: a fixed effect under that mesh takes such a shard, and holds it to
+    #: ``parallel/mesh.padded_samples(n, mesh)`` rows.
+    features: Dict[str, "ShardData"]
     offset: Optional[np.ndarray] = None  # [n]
     weight: Optional[np.ndarray] = None  # [n]
     id_tags: Dict[str, np.ndarray] = dataclasses.field(default_factory=dict)  # tag -> [n] int64
@@ -75,7 +81,9 @@ class GameData:
         self.offset = np.asarray(self.offset)
         self.weight = np.asarray(self.weight)
         for shard, x in self.features.items():
-            if x.shape[0] != n:
+            in_row_shards = (x.shape[0] > n and len(getattr(
+                getattr(x, "sharding", None), "device_set", ())) > 1)
+            if x.shape[0] != n and not in_row_shards:
                 raise ValueError(f"feature shard {shard!r} has {x.shape[0]} rows, expected {n}")
         if self.uids is not None and len(self.uids) != n:
             raise ValueError(f"uids has {len(self.uids)} rows, expected {n}")

@@ -36,10 +36,12 @@ from jax import lax
 
 from photon_ml_tpu.game.coordinate import Coordinate
 from photon_ml_tpu.models.game import GameModel
-from photon_ml_tpu.obs.trace import (device_scope, get_tracer, hlo_op_table,
+from photon_ml_tpu.obs.trace import (device_scope, get_tracer,
+                                     hlo_collectives, hlo_op_table,
                                      metadata_keyed_compile_cache)
 from photon_ml_tpu.obs.trace import enabled as obs_enabled
 from photon_ml_tpu.obs.trace import span as obs_span
+from photon_ml_tpu.parallel.mesh import samples_on_device
 from photon_ml_tpu.types import VarianceComputationType
 
 Array = jax.Array
@@ -70,6 +72,11 @@ class FusedSweep:
         first = coordinates[self.order[0]]
         self._n = first.num_samples
         self._dtype = first.dtype
+        # under a mesh the program's [n] vectors (base offsets, scores) have
+        # their sample axis over every device, padded to the devices'
+        # multiple (Coordinate.carry_samples); every host-facing result is
+        # cut back to n
+        self._mesh = getattr(first, "mesh", None)
         order, coords = self.order, self.coordinates
 
         needs_var = [coords[cid].config.variance != VarianceComputationType.NONE
@@ -83,6 +90,7 @@ class FusedSweep:
         self._grid_snap_program = None  # built lazily by run_grid_snapshots
         self._val_program = None   # built lazily by run_validated
         self._table_recorded = False  # the main program's op-to-layer table
+        self._collectives = {}  # its collective instructions (traced runs)
         self.solve_iterations = None  # the last run_device's (see there)
 
         def program(states0, scores0, vars0, regs, base_key, base, datas):
@@ -143,8 +151,7 @@ class FusedSweep:
 
         self._program_fn = program  # unjitted: the grid path vmaps it
         self._program = jax.jit(program)
-        self._base = jnp.asarray(np.asarray(first._base_offset_host(),
-                                            self._dtype))
+        self._base = self._samples(first._base_offset_host())
         self._datas = tuple(coords[cid].sweep_data() for cid in self.order)
         # Cold-start carry built eagerly: surfaces a coordinate without the
         # traceable-step interface at construction time (base-class
@@ -201,6 +208,10 @@ class FusedSweep:
                     on_update(i, cid, states[i])
         return states, scores, partials, keys
 
+    def _samples(self, v: np.ndarray) -> Array:
+        """A host ``[n]`` vector as the program carries it."""
+        return samples_on_device(v, self._mesh, self._dtype)
+
     def _init_carry(self, initial: Optional[GameModel]):
         states, scores = [], []
         for cid in self.order:
@@ -208,7 +219,9 @@ class FusedSweep:
             init = initial[cid] if initial is not None and cid in initial else None
             states.append(coord.init_sweep_state(init))
             if init is None:
-                scores.append(jnp.zeros(self._n, self._dtype))
+                scores.append(jnp.zeros(self._n, self._dtype)
+                              if self._mesh is None
+                              else self._samples(np.zeros(self._n)))
                 continue
             s = np.asarray(coord.score(init), self._dtype)
             c = coord.carry_through_scores(init)
@@ -218,7 +231,7 @@ class FusedSweep:
                 # keeping it out of the per-coordinate carry score prevents
                 # double-counting it in the first update's residual
                 s = s - np.asarray(c, self._dtype)
-            scores.append(jnp.asarray(s))
+            scores.append(self._samples(s))
         return tuple(states), tuple(scores)
 
     def init_carry(self, initial: Optional[GameModel]):
@@ -254,6 +267,7 @@ class FusedSweep:
             published, scores, vars_, self.solve_iterations = self._program(
                 *args)
         if obs_enabled():
+            self._record_exchange()
             with obs_span("descent.solve_iterations") as sp:
                 fetched = jax.device_get(self.solve_iterations)
                 sp.set(coordinates=list(self.order),
@@ -274,6 +288,26 @@ class FusedSweep:
         return (*carry, self._vars0, tuple(regs), jax.random.PRNGKey(seed),
                 base, self._datas), carried
 
+    def _record_exchange(self) -> None:
+        """Span ``descent.exchange`` (traced fits under a mesh): per
+        coordinate and exchange kind, the bytes a chip sends in ONE FIT,
+        from the shapes and shardings of what crosses chips
+        (``Coordinate.exchange_bytes``, an update's, times the outer
+        iterations; ``psum`` stays per objective evaluation: how many a
+        fit makes is the solver's), and ``collectives``: the main program's
+        collective instructions by name (``hlo_collectives``: what a device
+        trace has to be read by)."""
+        if self._mesh is None:
+            return
+        with obs_span("descent.exchange") as sp:
+            sent = [self.coordinates[cid].exchange_bytes()
+                    for cid in self.order]
+            sp.set(coordinates=list(self.order), devices=self._mesh.size,
+                   collectives=dict(self._collectives),
+                   bytes_sent=[{k: b * (1 if k == "psum"
+                                        else self.num_iterations)
+                                for k, b in e.items()} for e in sent])
+
     def _record_device_table(self, initial, regs, seed, carry0) -> None:
         """Once per sweep object, traced runs only: which layer each
         instruction of the main program's executable belongs to, read off
@@ -292,6 +326,7 @@ class FusedSweep:
                 text = self._program.lower(*args).compile().as_text()
             get_tracer().record_device_table("jit_program",
                                              hlo_op_table(text))
+            self._collectives = hlo_collectives(text)
 
     def run(self, initial: Optional[GameModel] = None,
             regs: Optional[Sequence] = None, seed: int = 0,
@@ -314,7 +349,7 @@ class FusedSweep:
                 initial, regs, seed, carry0)
         models = {cid: self.coordinates[cid].export_model(np.asarray(published[i]))
                   for i, cid in enumerate(self.order)}
-        final_scores = {cid: np.asarray(scores[i])
+        final_scores = {cid: np.asarray(scores[i])[: self._n]
                         for i, cid in enumerate(self.order)}
         for cid, c in carried.items():
             # published scores include the carried contribution, exactly as
@@ -339,7 +374,7 @@ class FusedSweep:
                     initial[cid] if cid in initial else None)
                 if c is not None:
                     carried[cid] = c
-                    base = base + jnp.asarray(np.asarray(c, self._dtype))
+                    base = base + self._samples(c)
         return base, carried
 
     def _merge_carry_through(self, models, initial: Optional[GameModel]):
@@ -587,7 +622,7 @@ class FusedSweep:
         # grid point (B*C per-slice transfers would multiply round-trip
         # latency on slow transports)
         published = [np.asarray(jax.device_get(p)) for p in published]
-        scores = [np.asarray(s) for s in scores]
+        scores = [np.asarray(s)[..., : self._n] for s in scores]
         vars_ = tuple(np.asarray(v) for v in vars_)
         out = []
         for b in range(len(regs_grid)):

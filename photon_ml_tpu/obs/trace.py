@@ -243,6 +243,26 @@ def hlo_op_table(hlo_text: str) -> Dict[str, str]:
     return table
 
 
+_HLO_COLLECTIVES = frozenset(
+    kind + half for kind in ("all-reduce", "all-gather", "all-to-all",
+                             "reduce-scatter", "collective-permute")
+    for half in ("", "-start", "-done"))
+
+
+def hlo_collectives(hlo_text: str) -> Dict[str, str]:
+    """Compiled HLO text -> ``{instruction name: opcode}`` of its collective
+    operations, the ``-start`` and ``-done`` halves of asynchronous ones
+    among them.  By OPCODE: a name does not tell (the TPU compiler calls a
+    ``psum``'s all-reduce ``psum.80``, and gives an all-gather it rewrote
+    as an all-reduce no metadata at all)."""
+    found: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is not None and m.group(2) in _HLO_COLLECTIVES:
+            found[m.group(1)] = m.group(2)
+    return found
+
+
 @contextlib.contextmanager
 def metadata_keyed_compile_cache():
     """Compile inside with JAX's persistent cache keyed on HLO metadata.

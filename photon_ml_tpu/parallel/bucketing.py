@@ -687,10 +687,16 @@ class EntityMajorLayout:
         return src
 
 
-def entity_major_layout(runs: EntityRuns) -> Optional[EntityMajorLayout]:
+def entity_major_layout(runs: EntityRuns, row_multiple: int = 1
+                        ) -> Optional[EntityMajorLayout]:
     """The entity-major layout of ``entity_runs``' grouping, or None where
     ``entity_major_chunk`` finds no chunk length.  Counts and row order
-    only: the design is not touched."""
+    only: the design is not touched.  ``row_multiple`` (under a mesh, its
+    devices times the stored rows of a tile):
+    R is a multiple of it, so that the rows of chunks shard evenly, and at
+    least one chunk behind the last entity's is nobody's: the LAST flat
+    position is then padding, which scores exactly 0 (where the sample
+    axis is padded too, the padding samples are sent there)."""
     entities, counts, order = runs
     c = entity_major_chunk(counts)
     if c is None:
@@ -700,6 +706,9 @@ def entity_major_layout(runs: EntityRuns) -> Optional[EntityMajorLayout]:
     first = np.cumsum(chunks) - chunks            # its first chunk
     starts = np.cumsum(counts) - counts           # its first grouped row
     rows = -(-int(chunks.sum()) // k)             # R
+    if row_multiple > 1:
+        rows = -(-(int(chunks.sum()) + 1) // k)
+        rows = -(-rows // row_multiple) * row_multiple
     ce = np.full(rows * k, -1, np.int32)
     ce[:int(chunks.sum())] = np.repeat(
         np.arange(len(counts), dtype=np.int32), chunks)
@@ -724,6 +733,28 @@ def entity_major_layout(runs: EntityRuns) -> Optional[EntityMajorLayout]:
 def _columns_at(x_t: Array, src: Array) -> Array:
     return jnp.take(x_t, src, axis=1, mode="fill",
                     fill_value=0).reshape(x_t.shape[0], -1, EM_ROW)
+
+
+def entity_major_design_over(layout: EntityMajorLayout, x: np.ndarray,
+                             mesh: Mesh) -> Array:
+    """``entity_major_design`` under a mesh, from the HOST design ``x``
+    [n, d]: [d, R, EM_ROW] with the rows of chunks over every device.  Each
+    chip's rows are gathered and transposed on the host and go straight to
+    that chip: neither design is ever whole on one device (the device
+    gather of ``entity_major_design`` would need both there)."""
+    from photon_ml_tpu.parallel.mesh import over_chips
+
+    n, d = x.shape
+    src = layout.source_rows().reshape(-1, EM_ROW)   # [R, EM_ROW]
+
+    def shard(index):
+        rows = src[index[1]]
+        part = x[np.minimum(rows, n - 1)]            # [R_s, EM_ROW, d]
+        part[rows >= n] = 0
+        return np.ascontiguousarray(part.transpose(2, 0, 1))
+
+    return jax.block_until_ready(jax.make_array_from_callback(
+        (d,) + src.shape, NamedSharding(mesh, over_chips(mesh, 3, 1)), shard))
 
 
 def entity_major_design(layout: EntityMajorLayout, x_t: Array) -> Array:

@@ -24,6 +24,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from photon_ml_tpu.core.batch import Batch, DenseBatch, SparseBatch
 from photon_ml_tpu.core.objective import GLMObjective
+from photon_ml_tpu.obs.trace import device_scope
 from photon_ml_tpu.opt.solve import make_solver
 from photon_ml_tpu.opt.types import SolverConfig, SolverResult
 from photon_ml_tpu.parallel.mesh import (
@@ -83,12 +84,18 @@ class ShardMapObjective:
         row_sharded = lambda a: P(self.axis, *([None] * (a.ndim - 1)))
         return jax.tree.map(row_sharded, batch)
 
+    def _psum(self, tree):
+        """The one all-reduce of an evaluation, named as what crosses chips
+        (scope ``photon.exchange.psum``, parallel/mesh.py's vocabulary)."""
+        with device_scope("exchange", "psum"):
+            return jax.lax.psum(tree, self.axis)
+
     def value_and_grad(self, w: Array, batch: Batch) -> Tuple[Array, Array]:
-        obj, axis = self.obj, self.axis
+        obj, psum = self.obj, self._psum
 
         def local(w, b):
             # one psum call over the tuple = one pinned fused all-reduce
-            return jax.lax.psum(obj.raw_value_and_grad(w, b), axis)
+            return psum(obj.raw_value_and_grad(w, b))
 
         rv, gr, rs = shard_map(
             local, mesh=self.mesh, in_specs=(P(), self._specs(batch)),
@@ -96,10 +103,10 @@ class ShardMapObjective:
         return obj.finish_value_and_grad(w, rv, gr, rs)
 
     def hvp(self, w: Array, batch: Batch, v: Array) -> Array:
-        obj, axis = self.obj, self.axis
+        obj, psum = self.obj, self._psum
 
         def local(w, b, v):
-            return jax.lax.psum(obj.raw_hvp(w, b, v), axis)
+            return psum(obj.raw_hvp(w, b, v))
 
         hv, qs = shard_map(
             local, mesh=self.mesh, in_specs=(P(), self._specs(batch), P()),
@@ -114,22 +121,22 @@ class ShardMapObjective:
     # aggregators for the same reason, HessianDiagonalAggregator.scala:128).
 
     def hessian_diag(self, w: Array, batch: Batch) -> Array:
-        obj, axis = self.obj, self.axis
+        obj, psum = self.obj, self._psum
 
         def local(w, b):
-            return jax.lax.psum(obj.hessian_diag(w, b) - obj.reg.l2, axis)
+            return psum(obj.hessian_diag(w, b) - obj.reg.l2)
 
         return shard_map(
             local, mesh=self.mesh, in_specs=(P(), self._specs(batch)),
             out_specs=P())(w, batch) + obj.reg.l2
 
     def hessian(self, w: Array, batch: Batch) -> Array:
-        obj, axis = self.obj, self.axis
+        obj, psum = self.obj, self._psum
         d = w.shape[-1]
 
         def local(w, b):
             eye = jnp.eye(d, dtype=w.dtype)
-            return jax.lax.psum(obj.hessian(w, b) - obj.reg.l2 * eye, axis)
+            return psum(obj.hessian(w, b) - obj.reg.l2 * eye)
 
         h = shard_map(
             local, mesh=self.mesh, in_specs=(P(), self._specs(batch)),

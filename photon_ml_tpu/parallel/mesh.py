@@ -25,17 +25,29 @@ Mesh axes:
                   margins rides ICI (GSPMD inserts the psum from the shardings).
 Multi-host later slices these over DCN by constructing the mesh from
 ``jax.devices()`` spanning hosts; the code below is agnostic.
+
+What crosses chips inside a fit is written down HERE, not left to the
+partitioner (the second half of this file): a random effect's update moves
+its residuals from sample order into entity lanes (``lanes_of``),
+its lanes into the replicated coefficient table (``stack_lanes``) and its
+entity-major scores back to sample order (``score_entity_major``): the
+reference's shuffle by entity (RandomEffectCoordinate.scala:104-231) as
+three exchanges of ``[n]`` vectors and tables.  No design array crosses a
+chip.  Each runs under ``device_scope("exchange", <kind>)``, and
+``exchange_bytes`` says what a chip sends for each.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Callable, Dict, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.core.batch import Batch, DenseBatch, SparseBatch
+from photon_ml_tpu.obs.trace import device_scope
 
 DATA_AXIS = "data"
 ENTITY_AXIS = "entity"
@@ -96,8 +108,6 @@ def _pad_axis(a, target: int, axis: int):
         return a
     widths = [(0, pad if i == axis else 0) for i in range(a.ndim)]
     if isinstance(a, jax.Array):
-        import jax.numpy as jnp
-
         return jnp.pad(a, widths)
     return np.pad(np.asarray(a), widths)
 
@@ -118,8 +128,6 @@ def shard_coefficients(w, mesh: Mesh, axis: str = FEATURE_AXIS):
     warm-starting from a previous sweep's sharded w never all-gathers the
     full vector to the host.
     """
-    import jax.numpy as jnp
-
     sharding = NamedSharding(mesh, P(axis))
     if jax.process_count() > 1 and getattr(w, "is_fully_addressable", True):
         # multihost: any PROCESS-LOCAL input (host numpy or a
@@ -184,3 +192,174 @@ def shard_batch(batch: Batch, mesh: Mesh, axis: str = DATA_AXIS,
                            values=place(batch.values, P(axis, None)),
                            dim=batch.dim, **vectors)
     raise TypeError(f"unknown batch type {type(batch)!r}")
+
+
+# -- samples, chunks and lanes over every chip; what crosses them -------------
+# A coordinate's sample axis (its full-sample arrays, the sweep's [n]
+# vectors), the chunk axis of an entity-major design and the lane axis of
+# every capacity class are sharded over ALL the mesh's devices.  On
+# ``make_mesh``'s default mesh (all on ``data``) that is the fixed design's
+# row sharding too.
+
+
+def spans_chips(mesh: Optional[Mesh]) -> Optional[Mesh]:
+    """``mesh`` where it holds more than one device, else None: a
+    one-device mesh gives the one-chip program, with no collective and
+    nothing that stands in for absent chips."""
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
+SAMPLE_TILE = 1024  # a 1-D float32 array's (8, 128) tile on the chip
+
+
+def padded_samples(n: int, mesh: Optional[Mesh]) -> int:
+    """The sample axis under ``mesh``: ``n`` up to the next multiple of
+    ``SAMPLE_TILE`` a device (the rows behind ``n`` are padding: weight 0,
+    no entity).  Whole tiles a device, not just a multiple of the devices:
+    an all-gather of shards that end inside a tile is no all-gather on the
+    chip (the TPU compiler makes it an all-reduce of a zero-padded vector,
+    twice the bytes)."""
+    if mesh is None:
+        return n
+    granule = mesh.size * SAMPLE_TILE
+    return -(-n // granule) * granule
+
+
+def over_chips(mesh: Mesh, ndim: int = 1, axis: int = 0) -> P:
+    """``axis`` of an ``ndim``-array over every device of ``mesh``."""
+    spec = [None] * ndim
+    spec[axis] = tuple(mesh.axis_names)
+    return P(*spec)
+
+
+def put_over_chips(a, mesh: Mesh, axis: int = 0, length: Optional[int] = None,
+                   fill=0) -> jax.Array:
+    """``a`` with ``axis`` padded to ``length`` (``fill``; default:
+    ``padded_samples``) and sharded over every device: a host
+    array goes shard by shard straight to its chips, a device array is
+    padded and resharded where it is.  Nothing is staged whole on one
+    device."""
+    length = padded_samples(a.shape[axis], mesh) if length is None else length
+    widths = [(0, length - a.shape[axis] if i == axis else 0)
+              for i in range(a.ndim)]
+    if any(w for _, w in widths):
+        pad = jnp.pad if isinstance(a, jax.Array) else np.pad
+        a = pad(a, widths, constant_values=fill)
+    return jax.device_put(a, NamedSharding(mesh, over_chips(mesh, a.ndim,
+                                                            axis)))
+
+
+def samples_on_device(v, mesh: Optional[Mesh], dtype) -> jax.Array:
+    """A host ``[n]`` vector as the traceable steps carry it: on the
+    default device, or under a mesh ``padded_samples`` long, shard by
+    shard to its chips."""
+    v = np.asarray(v, dtype)
+    return jnp.asarray(v) if mesh is None else put_over_chips(v, mesh)
+
+
+def on_chips(fn: Callable, mesh: Mesh, in_specs, out_specs) -> Callable:
+    """``fn`` as each chip's own program over its shards (``shard_map``).
+    ``check_vma`` off as in ``ShardMapObjective``: the bodies hold Mosaic
+    kernels and loops the check cannot type; every replicated output is an
+    explicit collective's."""
+    from jax import shard_map
+
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
+
+
+def lanes_of(offsets: jax.Array, rows: Sequence[jax.Array],
+             valid: Sequence[jax.Array], mesh: Mesh) -> tuple:
+    """Exchange ``offsets``: ``where(valid, offsets[rows], 0)`` for each
+    capacity class, the sample-sharded ``[n]`` offsets meeting
+    entity-sharded ``[lanes, capacity]`` row indices.  ONE all-gather makes
+    the vector whole on every chip, then each chip gathers its own lanes'
+    rows out of it, class by class.  One program a chip for both halves:
+    handed from one ``shard_map`` to the next, the TPU compiler turned the
+    all-gather into an all-reduce of a zero-padded vector, twice the
+    bytes."""
+    axes = tuple(mesh.axis_names)
+
+    def local(v, rows, valid):
+        with device_scope("exchange", "offsets"):
+            whole = jax.lax.all_gather(v, axes, tiled=True)
+            with device_scope("entity_gather"):
+                return tuple(jnp.where(ok, whole[r], 0.0)
+                             for r, ok in zip(rows, valid))
+
+    lanes = over_chips(mesh, 2)
+    return on_chips(local, mesh, (over_chips(mesh), lanes, lanes), lanes)(
+        offsets, tuple(rows), tuple(valid))
+
+
+def stack_lanes(lane_ws: Sequence[jax.Array], slot_idx: Sequence[jax.Array],
+                num_entities: int, mesh: Mesh) -> jax.Array:
+    """Exchange ``publish``: ``bucketing.stack_bucket_lanes`` with the lanes
+    on their chips.  Each chip scatters its own lanes into a table of
+    zeros, ONE psum makes the table whole on every chip (an entity has one
+    lane, so every row is one chip's value plus zeros: exact)."""
+    from photon_ml_tpu.parallel.bucketing import stack_bucket_lanes
+
+    axes = tuple(mesh.axis_names)
+
+    def local(lws, idxs):
+        return jax.lax.psum(stack_bucket_lanes(lws, idxs, num_entities), axes)
+
+    lanes = P(axes)
+    with device_scope("exchange", "publish"):
+        return on_chips(local, mesh, (lanes, lanes), P())(
+            tuple(lane_ws), tuple(slot_idx))
+
+
+def score_entity_major(w_stack: jax.Array, lane_slot: jax.Array,
+                       x_em: jax.Array, pos: Optional[jax.Array],
+                       mesh: Mesh) -> jax.Array:
+    """``bucketing.score_samples_em`` with the chunk rows on their chips,
+    and exchange ``scores``: each chip scores its own rows of chunks from
+    the replicated table, ONE all-gather makes the entity-major scores
+    whole on every chip, and each chip gathers its own samples' positions
+    out of them.  ``pos`` None: the chunks ARE the sample order, shard for
+    shard, and nothing crosses."""
+    from photon_ml_tpu.parallel.bucketing import score_samples_em
+
+    axes = tuple(mesh.axis_names)
+
+    def local(w, slots, x, p=None):
+        acc = score_samples_em(w, slots, x)
+        if p is None:
+            return acc
+        with device_scope("exchange", "scores"):
+            return jax.lax.all_gather(acc, axes, tiled=True)[p]
+
+    specs = (P(), over_chips(mesh, 2, 1), over_chips(mesh, 3, 1))
+    if pos is None:
+        return on_chips(local, mesh, specs, over_chips(mesh))(
+            w_stack, lane_slot, x_em)
+    return on_chips(local, mesh, specs + (over_chips(mesh),),
+                    over_chips(mesh))(w_stack, lane_slot, x_em, pos)
+
+
+def score_in_sample_order(score: Callable, w_stack: jax.Array, mesh: Mesh,
+                          by_sample: Sequence[jax.Array],
+                          sample_axis: int = 0) -> jax.Array:
+    """A sample-order layout's scoring, ``score(w_stack, *by_sample)``, on
+    each chip's own samples against the replicated table: nothing crosses.
+    ``by_sample``: the slot vector ``[n]``, then the design, its samples on
+    ``sample_axis``."""
+    slots, *design = by_sample
+    specs = (over_chips(mesh),) + tuple(
+        over_chips(mesh, a.ndim, sample_axis) for a in design)
+    return on_chips(score, mesh, (P(),) + specs, over_chips(mesh))(
+        w_stack, slots, *design)
+
+
+def exchange_bytes(mesh: Mesh, gathered: Dict[str, int],
+                   summed: Dict[str, int]) -> Dict[str, int]:
+    """{kind: bytes a chip sends}, from shapes alone.  ``gathered``: the
+    bytes of each all-gathered array (a chip sends its shard to every other
+    chip: (chips - 1) / chips of it); ``summed``: the bytes of each psum'd
+    one (a ring all-reduce sends 2 (chips - 1) / chips of it)."""
+    chips = mesh.size
+    out = {k: (chips - 1) * b // chips for k, b in gathered.items()}
+    out.update({k: 2 * (chips - 1) * b // chips for k, b in summed.items()})
+    return out

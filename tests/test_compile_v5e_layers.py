@@ -33,15 +33,19 @@ from photon_ml_tpu.obs.trace import hlo_op_table  # noqa: E402
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -232,3 +236,70 @@ def test_fixed_effect_kernels_take_an_undivisible_design_in_place(one_chip):
         jax.config.update("jax_enable_x64", x64_was)
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
+
+
+def test_glmix_ml25m_exchanges_compile_for_four_chips(topo, compiled_text):
+    """The sharded descent program (ISSUE 30) at ``glmix_ml25m``'s dry-run
+    sizes, its random effects' designs entity-major as in the cell,
+    compiled for the DESCRIBED 2x2 host: the coordinates are built under a
+    mesh of four of conftest's virtual CPU devices, then every mesh the
+    program closes over is swapped for the described chips'
+    (benchmarks/tools/compile_for_v5e_x4.py does the same at any size).
+    The TPU compiler takes the Mosaic kernels under ``shard_map``, and the
+    exchanges stay what ``parallel/mesh.py`` wrote: the four all-gathers
+    of an ``[n]`` vector (shards that end inside a tile came back as
+    all-reduces of a zero-padded vector, with no scope: hence
+    ``mesh.padded_samples``), each under its ``photon.exchange`` scope,
+    and no collective moves a design."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    sys.path.insert(0, os.path.join(BENCH, "tools"))
+    from compile_for_v5e_x4 import compile_described
+
+    from photon_ml_tpu.obs.trace import hlo_collectives
+    from photon_ml_tpu.parallel import bucketing
+    from photon_ml_tpu.parallel.mesh import make_mesh
+
+    compiled_text  # its patches: has_tpu, x64 off, no persistent cache
+    catalog = harness.Catalog()
+    cfg = harness.sized(catalog.json("configs", "glmix_ml25m"), True)
+    here = make_mesh(devices=jax.devices()[:4])
+    there = Mesh(np.asarray(topo.devices[:4]).reshape(here.devices.shape),
+                 here.axis_names)
+    data = catalog.module("recipes", cfg["recipe"]).make_training(cfg, 0, here)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 1)
+        coords = catalog.module("traffic", "train_fits").build_coordinates(
+            cfg, data, here)
+    assert all(c._em is not None and c._em.pos is not None
+               for c in list(coords.values())[1:])
+    sweep = FusedSweep(coords, num_iterations=int(cfg["sweeps"]))
+    args, _ = sweep._program_args(None, None, 0, None)
+    compiled = compile_described(sweep, there)
+    text = compiled.as_text()
+    table, named = hlo_op_table(text), hlo_collectives(text)
+    assert layers_of(table, "fused_glm_value_grad") == {
+        "photon.update.fixed/photon.fixed_solve"}
+    gathers = sorted("/".join(p for p in table[n].split("/")
+                              if p.startswith("photon."))
+                     for n, kind in named.items()
+                     if kind.startswith("all-gather"))
+    assert gathers == [
+        "photon.update.per_item/photon.exchange.offsets",
+        "photon.update.per_item/photon.rescore/photon.exchange.scores",
+        "photon.update.per_user/photon.exchange.offsets",
+        "photon.update.per_user/photon.rescore/photon.exchange.scores"]
+    # every collective the program has sits under an exchange scope, but
+    # the fixed design's global padding at this size (section 3 of the
+    # issue: a small design is padded to a block a device, and the [n]
+    # vectors are shifted to its shards by collective-permutes)
+    for name, kind in named.items():
+        assert "photon.exchange." in table[name] or (
+            kind.startswith("collective-permute")
+            and "photon.update.fixed" in table[name]), (name, table[name])
+    n_pad = sweep._base.shape[0]
+    assert n_pad % (4 * 1024) == 0 and n_pad - 9217 < 4 * 1024
+    # what a device must hold is a quarter of the arguments, not all
+    held = sum(a.nbytes for a in jax.tree.leaves(args) if hasattr(a, "nbytes"))
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.5 * held

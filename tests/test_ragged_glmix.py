@@ -458,13 +458,14 @@ def test_the_new_metrics_are_appended_and_well_formed(name):
     names = [p["name"] for p in m["per_layer"]]
     assert names.index(name) > names.index("soa_newton_busy_share")
     listed = next(p for p in m["per_layer"] if p["name"] == name)
-    assert listed["workloads"] == ["glmix_ml20m.train"]
+    # the cell that brought them; later cells (glmix_ml25m.train_x4) append
+    assert listed["workloads"][0] == "glmix_ml20m.train"
     assert listed["layer"] == entry["layer"] \
         == "per-entity solve (ops/soa_newton.py)"
-    assert m["workloads"][-1]["name"] == "glmix_ml20m.train"
-    assert m["workloads"][-1]["chips"] == 1
-    assert m["configs"][-1]["name"] == "glmix_ml20m"
-    assert m["configs"][-1]["reduced"] == ["users"]
+    cell = next(w for w in m["workloads"] if w["name"] == "glmix_ml20m.train")
+    assert m["workloads"].index(cell) == 2 and cell["chips"] == 1
+    cfg = next(c for c in m["configs"] if c["name"] == "glmix_ml20m")
+    assert m["configs"].index(cfg) == 2 and cfg["reduced"] == ["users"]
 
 
 # -- (e) a design whose rows do not divide into the kernels' blocks -----------
