@@ -43,7 +43,9 @@ from photon_ml_tpu.obs.trace import device_scope
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.opt.solve import line_search_kind, make_solver
 from photon_ml_tpu.opt.types import SolverResult
-from photon_ml_tpu.parallel.bucketing import bucket_by_entity, stacked_coefficients
+from photon_ml_tpu.parallel.bucketing import (bucket_by_entity,
+                                              offsets_into_lanes,
+                                              stacked_coefficients)
 from photon_ml_tpu.parallel.mesh import (SAMPLE_TILE, exchange_bytes,
                                          lanes_of, on_chips, over_chips,
                                          padded_samples, put_over_chips,
@@ -1065,6 +1067,7 @@ class RandomEffectCoordinate(Coordinate):
             # solve per class, lanes x capacity slots of which active_rows
             # hold a row; passive rows are scored and never trained on
             classes = self.buckets.buckets
+            by_run = [b.run_lanes * lane_multiple for b in classes]
             bucket_span.set(
                 line_search=self.line_search,
                 classes=len(classes),
@@ -1073,6 +1076,13 @@ class RandomEffectCoordinate(Coordinate):
                 lanes_per_device=[b.num_lanes // lane_multiple
                                   for b in classes],
                 slots=sum(b.num_lanes * b.capacity for b in classes),
+                # lanes addressed by the start of their run of samples
+                # (bucketing._class_lanes), their slots, and the slots that
+                # keep one gathered index each
+                run_lanes=by_run,
+                run_slots=sum(r * b.capacity for r, b in zip(by_run, classes)),
+                index_slots=sum((b.num_lanes - r) * b.capacity
+                                for r, b in zip(by_run, classes)),
                 active_rows=sum(int(b.counts.sum()) for b in classes),
                 capped_entities=self.buckets.capped_entities,
                 passive_rows=self.buckets.passive_rows)
@@ -1190,12 +1200,24 @@ class RandomEffectCoordinate(Coordinate):
                 return bx.astype(sd)
             return np.asarray(bx).astype(sd)
 
+        def _lane_rows(b):
+            """What ``offsets_into_lanes`` addresses a class's lanes by:
+            the row of every slot, or where the bucketer found run lanes
+            (the first ``b.run_lanes`` of each device's share) their starts
+            and the rows of the lanes behind them."""
+            rows = np.where(b.rows < 0, 0, b.rows)
+            if not b.run_lanes:
+                return dict(rows=put(rows))
+            rows = rows.reshape(lane_multiple, -1, b.capacity)
+            return dict(
+                run_start=put(rows[:, :b.run_lanes, 0].reshape(-1)),
+                rows=put(rows[:, b.run_lanes:].reshape(-1, b.capacity)))
+
         with _upload_span(coordinate_id, mesh) as placed:
             self._dev = [
                 dict(x=put(_narrow(b.x)),
                      y=put(b.y), w=put(b.weight),
-                     rows=put(np.where(b.rows < 0, 0, b.rows)),
-                     valid=put(b.rows >= 0))
+                     valid=put(b.rows >= 0), **_lane_rows(b))
                 for b in solve_buckets
             ]
             placed((self._dev, self._slot_idx_dev))
@@ -1274,19 +1296,20 @@ class RandomEffectCoordinate(Coordinate):
 
     def _offsets_into_lanes(self, offsets: Array, devs):
         """``gather(bi)``: the residual offsets of bucket ``bi``'s lanes,
-        ``where(valid, offsets[rows], 0)``.  Under a mesh the sample-sharded
-        offsets meet entity-sharded lanes: exchange ``offsets``, ONE
-        all-gather of the ``[n]`` vector an update and each chip's own
-        lane gathers, every class's at once (``lanes_of``)."""
+        ``where(valid, offsets[rows], 0)``, its run lanes addressed by
+        their start (``bucketing.offsets_into_lanes``).  Under a mesh the
+        sample-sharded offsets meet entity-sharded lanes: exchange
+        ``offsets``, ONE all-gather of the ``[n]`` vector an update and
+        each chip's own lane gathers, every class's at once
+        (``lanes_of``)."""
+        classes = [{k: dev[k] for k in ("rows", "valid", "run_start")
+                    if k in dev} for dev in devs]
         if self.mesh is None:
             def gather(bi):
-                dev = devs[bi]
                 with device_scope("entity_gather"):
-                    return jnp.where(dev["valid"], offsets[dev["rows"]], 0.0)
+                    return offsets_into_lanes(offsets, **classes[bi])
             return gather
-        lanes = lanes_of(offsets, [dev["rows"] for dev in devs],
-                         [dev["valid"] for dev in devs], self.mesh)
-        return lanes.__getitem__
+        return lanes_of(offsets, classes, self.mesh).__getitem__
 
     def exchange_bytes(self) -> Dict[str, int]:
         """What one update of this coordinate sends over the chips: the

@@ -55,7 +55,10 @@ class Bucket:
     Arrays: x [E, S, d], y/offset/weight [E, S], rows [E, S] int32 (original
     sample row of each slot, -1 for padding), counts [E] int32 (real samples
     per entity), entity_lanes [E] int64 (original entity id per lane, -1 for
-    padding lanes).
+    padding lanes).  ``run_lanes``: in each of the ``lane_multiple`` equal
+    shares of the lanes, the first ``run_lanes`` hold rows that are one
+    consecutive run of samples (``_class_lanes``; 0: the order the entities
+    came in, padding lanes last).
     """
 
     x: np.ndarray
@@ -65,6 +68,7 @@ class Bucket:
     rows: np.ndarray
     counts: np.ndarray
     entity_lanes: np.ndarray
+    run_lanes: int = 0
 
     @property
     def num_lanes(self) -> int:
@@ -200,22 +204,68 @@ def _passive(kept_rows: List[np.ndarray], rescale: List[float]) -> dict:
                                          * (scale[capped] - 1.0)).sum()))
 
 
-def _pack_lane_meta(n_lanes, cap, idxs, kept_rows, kept_entities, rescale,
+def _class_lanes(idxs: np.ndarray, kept_rows: List[np.ndarray], cap: int,
+                 lane_multiple: int, row_ids: Optional[np.ndarray] = None
+                 ) -> Tuple[np.ndarray, int]:
+    """``(lanes [n_lanes], run_lanes)``: the entity (an index into
+    ``kept_rows``; -1: a padding lane) of each lane of one capacity class,
+    the lane count padded to ``lane_multiple``, and how many lanes at the
+    head of each of the ``lane_multiple`` equal shares are RUN lanes.
+
+    A run lane's rows, as ``Bucket.rows`` stores them, are one consecutive
+    run of samples ``start .. start + k - 1`` (an uncapped entity of data
+    that arrives grouped by entity), so ``offsets_into_lanes`` addresses it
+    by its start.  The rule reads the rows and nothing else: a reservoir, an
+    entity whose rows lie anywhere and every lane of a class under
+    ``RUN_CAPACITY_MIN`` keep one index a slot.  Every share holds the SAME
+    number of run lanes (under a mesh a share is a chip's, and the program
+    is one for all chips): they are dealt out in turn, and those that do
+    not fill a round stay index lanes.  With no run lane the order is the
+    entities' own, padding lanes last."""
+    def is_run(rows):  # ``_group_rows``' rows: ascending and distinct
+        if int(rows[-1]) - int(rows[0]) != len(rows) - 1:
+            return False
+        return row_ids is None or bool(np.all(np.diff(row_ids[rows]) == 1))
+
+    n_lanes = -(-len(idxs) // lane_multiple) * lane_multiple
+    lanes = np.full(n_lanes, -1, np.int64)
+    run = (np.fromiter((is_run(kept_rows[ei]) for ei in idxs), bool,
+                       len(idxs)) if cap >= RUN_CAPACITY_MIN else np.zeros(0, bool))
+    per_share = int(run.sum()) // lane_multiple
+    if per_share == 0:
+        lanes[:len(idxs)] = idxs
+        return lanes, 0
+    share = n_lanes // lane_multiple
+    dealt = np.flatnonzero(run)[:per_share * lane_multiple]
+    lanes.reshape(lane_multiple, share)[:, :per_share] = idxs[dealt].reshape(
+        per_share, lane_multiple).T
+    rest = np.delete(idxs, dealt)
+    tails = (np.arange(lane_multiple)[:, None] * share
+             + np.arange(per_share, share)[None, :]).ravel()
+    lanes[tails[:len(rest)]] = rest
+    return lanes, per_share
+
+
+def _pack_lane_meta(cap, lanes, kept_rows, kept_entities, rescale,
                     y, offset, weight, dtype, lane_of, bucket_index,
                     row_ids=None):
     """Fill one capacity class's NON-design lane arrays (labels, offsets,
     rescaled weights, row map, counts, entity directory) — identical between
     the dense and row-sparse bucketers, factored so their padding/rescale
-    semantics cannot diverge.  Returns (by, boff, bw, brows, bcounts,
+    semantics cannot diverge.  ``lanes``: ``_class_lanes``' entity of each
+    lane.  Returns (by, boff, bw, brows, bcounts,
     blanes); ``lane_of`` is updated in place.  ``row_ids`` maps local row
     positions to the GLOBAL sample-row ids stored in ``brows`` (multihost)."""
+    n_lanes = len(lanes)
     by = np.zeros((n_lanes, cap), dtype)
     boff = np.zeros((n_lanes, cap), dtype)
     bw = np.zeros((n_lanes, cap), dtype)
     brows = np.full((n_lanes, cap), -1, np.int32)
     bcounts = np.zeros((n_lanes,), np.int32)
     blanes = np.full((n_lanes,), -1, np.int64)
-    for lane, ei in enumerate(idxs):
+    for lane, ei in enumerate(lanes):
+        if ei < 0:
+            continue
         rows = kept_rows[ei]
         k = len(rows)
         by[lane, :k] = y[rows]
@@ -305,10 +355,10 @@ def bucket_by_entity(
     buckets: List[Bucket] = []
     lane_of: Dict[int, Tuple[int, int]] = {}
     for cap in sorted(set(caps.tolist())):
-        idxs = np.nonzero(caps == cap)[0]
-        n_lanes = ((len(idxs) + lane_multiple - 1) // lane_multiple) * lane_multiple
+        lanes, run_lanes = _class_lanes(np.nonzero(caps == cap)[0], kept_rows,
+                                        cap, lane_multiple, row_ids)
         by, boff, bw, brows, bcounts, blanes = _pack_lane_meta(
-            n_lanes, cap, idxs, kept_rows, kept_entities, rescale,
+            cap, lanes, kept_rows, kept_entities, rescale,
             y, offset, weight, dtype, lane_of, len(buckets), row_ids=row_ids)
         if x_is_device:
             # on-device lane gather: rows copy exactly, padding lanes/slots
@@ -318,12 +368,14 @@ def bucket_by_entity(
             bx = jnp.where(jnp.asarray(valid)[..., None],
                            x[jnp.asarray(safe)], jnp.zeros((), x.dtype))
         else:
-            bx = np.zeros((n_lanes, cap, d), dtype)
-            for lane, ei in enumerate(idxs):
-                rows = kept_rows[ei]
-                bx[lane, :len(rows)] = x[rows]
+            bx = np.zeros((len(lanes), cap, d), dtype)
+            for lane, ei in enumerate(lanes):
+                if ei >= 0:
+                    rows = kept_rows[ei]
+                    bx[lane, :len(rows)] = x[rows]
         buckets.append(Bucket(x=bx, y=by, offset=boff, weight=bw, rows=brows,
-                              counts=bcounts, entity_lanes=blanes))
+                              counts=bcounts, entity_lanes=blanes,
+                              run_lanes=run_lanes))
 
     return EntityBuckets(buckets=buckets, lane_of=lane_of, dim=d,
                          num_entities=len(kept_entities),
@@ -414,24 +466,24 @@ def bucket_by_entity_sparse(
     projections: List[object] = []
     lane_of: Dict[int, Tuple[int, int]] = {}
     for cap in sorted(set(caps.tolist())):
-        idxs = np.nonzero(caps == cap)[0]
-        compacted = [_compact_lane(kept_rows[ei]) for ei in idxs]
-        d_proj = _pow2_at_least(max((len(o) for o, _ in compacted),
+        lanes, run_lanes = _class_lanes(np.nonzero(caps == cap)[0], kept_rows,
+                                        cap, lane_multiple, row_ids)
+        compacted = {lane: _compact_lane(kept_rows[ei])
+                     for lane, ei in enumerate(lanes) if ei >= 0}
+        d_proj = _pow2_at_least(max((len(o) for o, _ in compacted.values()),
                                     default=1))
         d_proj = min(d_proj, dim)
-        n_lanes = ((len(idxs) + lane_multiple - 1) // lane_multiple) * lane_multiple
         by, boff, bw, brows, bcounts, blanes = _pack_lane_meta(
-            n_lanes, cap, idxs, kept_rows, kept_entities, rescale,
+            cap, lanes, kept_rows, kept_entities, rescale,
             y, offset, weight, dtype, lane_of, len(buckets), row_ids=row_ids)
-        bx = np.zeros((n_lanes, cap, d_proj), dtype)
-        bidx = np.full((n_lanes, d_proj), -1, np.int32)
-        for lane, ei in enumerate(idxs):
-            k = len(kept_rows[ei])
-            obs, x = compacted[lane]
-            bx[lane, :k, :len(obs)] = x
+        bx = np.zeros((len(lanes), cap, d_proj), dtype)
+        bidx = np.full((len(lanes), d_proj), -1, np.int32)
+        for lane, (obs, x) in compacted.items():
+            bx[lane, :len(x), :len(obs)] = x
             bidx[lane, :len(obs)] = obs
         buckets.append(Bucket(x=bx, y=by, offset=boff, weight=bw, rows=brows,
-                              counts=bcounts, entity_lanes=blanes))
+                              counts=bcounts, entity_lanes=blanes,
+                              run_lanes=run_lanes))
         projections.append(BucketProjection(indices=bidx, d_full=dim))
 
     ents = EntityBuckets(buckets=buckets, lane_of=lane_of, dim=dim,
@@ -621,6 +673,59 @@ def score_samples_t(w_stack: Array, slots: Array, x_t: Array) -> Array:
 EM_ROW = 128  # lanes of a stored row: a chunk is EM_ROW / k of them
 EM_CHUNK_MIN = 8
 EM_PAD_MAX = 1.3  # padded rows over rows; per-item at C = 128 pads to 1.25
+# The capacity from which a lane whose rows are one run of samples is
+# addressed by its start (``_class_lanes``, ``offsets_into_lanes``).  On a
+# v5e (PR 31, scratch, 24,656 lanes a class out of 13.0M offsets): a run
+# lane costs 14.7 ns at every capacity up to 64 (two gathered ROWS of 128
+# and the shift), a lane of one index a slot 9.4 / 17.1 / 30.4 / 56.3 / 109 /
+# 216 ns at capacity 1 / 2 / 4 / 8 / 16 / 32 (6.7 ns a slot from 8 up): even
+# at 2, twice as fast from 4.  At glmix_ml20m's per-user classes (32 to
+# 1,024) 2.25 ms an update for 110.4.
+RUN_CAPACITY_MIN = 4
+
+
+def _runs_at(offsets: Array, run_start: Array, capacity: int) -> Array:
+    """``offsets[run_start[l] + s]`` for ``s < capacity`` [lanes, capacity]
+    (whatever lies there: the caller masks), addressed by ROWS: ``offsets``
+    read as ``[n / EM_ROW, EM_ROW]``, a lane takes the ``capacity / EM_ROW +
+    1`` (at least 2) rows from ``run_start // EM_ROW`` (indices past the
+    last row clamp: what they stand for lies past the vector), and its rows,
+    laid end to end, are rotated left by ``run_start % EM_ROW``: seven
+    static rolls, each kept where the shift has that bit.  Of the lowerings
+    measured on the chip (PERF.md section 6, PR 31) the fastest; a
+    ``dynamic_slice`` a lane under ``vmap`` is a loop over the lanes."""
+    n = offsets.shape[0]
+    whole = -(-n // EM_ROW) * EM_ROW
+    table = (offsets if whole == n else jnp.pad(offsets, (0, whole - n))
+             ).reshape(-1, EM_ROW)
+    take = max(capacity // EM_ROW, 1) + 1
+    first = (run_start // EM_ROW)[:, None]
+    picked = table[jnp.minimum(first + jnp.arange(take, dtype=first.dtype),
+                               table.shape[0] - 1)]   # [lanes, take, EM_ROW]
+    picked = picked.reshape(picked.shape[0], -1)
+    shift = (run_start % EM_ROW)[:, None]
+    for bit in range(EM_ROW.bit_length() - 1):
+        picked = jnp.where((shift >> bit) & 1 == 1,
+                           jnp.roll(picked, -(1 << bit), axis=1), picked)
+    return picked[:, :capacity]
+
+
+def offsets_into_lanes(offsets: Array, rows: Array, valid: Array,
+                       run_start: Optional[Array] = None) -> Array:
+    """One capacity class's residual offsets, ``where(valid, offsets[rows
+    of each slot], 0)`` [lanes, capacity], from the ``[n]`` vector.  The
+    class's first ``len(run_start)`` lanes are RUN lanes (``_class_lanes``)
+    addressed by the sample their run starts at; ``rows`` [index lanes,
+    capacity] holds the rows of the lanes behind them, one index a slot;
+    ``valid`` masks all of them.  Copies either way: the lanes are bitwise
+    what one index a slot gives.  ``run_start`` None: a class with no run
+    lane, ``rows`` its every lane's."""
+    if run_start is None:
+        return jnp.where(valid, offsets[rows], 0.0)
+    picked = _runs_at(offsets, run_start, valid.shape[1])
+    if rows.shape[0]:
+        picked = jnp.concatenate([picked, offsets[rows]])
+    return jnp.where(valid, picked, 0.0)
 
 
 def entity_major_chunk(counts: np.ndarray) -> Optional[int]:

@@ -268,28 +268,32 @@ def on_chips(fn: Callable, mesh: Mesh, in_specs, out_specs) -> Callable:
                      check_vma=False)
 
 
-def lanes_of(offsets: jax.Array, rows: Sequence[jax.Array],
-             valid: Sequence[jax.Array], mesh: Mesh) -> tuple:
-    """Exchange ``offsets``: ``where(valid, offsets[rows], 0)`` for each
+def lanes_of(offsets: jax.Array, classes: Sequence[Dict[str, jax.Array]],
+             mesh: Mesh) -> tuple:
+    """Exchange ``offsets``: ``bucketing.offsets_into_lanes`` for each
     capacity class, the sample-sharded ``[n]`` offsets meeting
-    entity-sharded ``[lanes, capacity]`` row indices.  ONE all-gather makes
-    the vector whole on every chip, then each chip gathers its own lanes'
-    rows out of it, class by class.  One program a chip for both halves:
+    entity-sharded lanes (``classes``: that function's ``rows``, ``valid``
+    and, where the class has run lanes, ``run_start`` of each, their lanes
+    over every chip).  ONE all-gather makes the vector whole on every chip,
+    then each chip gathers its own lanes' rows out of it, class by class.
+    One program a chip for both halves:
     handed from one ``shard_map`` to the next, the TPU compiler turned the
     all-gather into an all-reduce of a zero-padded vector, twice the
     bytes."""
+    from photon_ml_tpu.parallel.bucketing import offsets_into_lanes
+
     axes = tuple(mesh.axis_names)
 
-    def local(v, rows, valid):
+    def local(v, classes):
         with device_scope("exchange", "offsets"):
             whole = jax.lax.all_gather(v, axes, tiled=True)
             with device_scope("entity_gather"):
-                return tuple(jnp.where(ok, whole[r], 0.0)
-                             for r, ok in zip(rows, valid))
+                return tuple(offsets_into_lanes(whole, **c) for c in classes)
 
-    lanes = over_chips(mesh, 2)
-    return on_chips(local, mesh, (over_chips(mesh), lanes, lanes), lanes)(
-        offsets, tuple(rows), tuple(valid))
+    classes = tuple(classes)
+    by_lane = jax.tree.map(lambda a: over_chips(mesh, a.ndim), classes)
+    return on_chips(local, mesh, (over_chips(mesh), by_lane),
+                    over_chips(mesh, 2))(offsets, classes)
 
 
 def stack_lanes(lane_ws: Sequence[jax.Array], slot_idx: Sequence[jax.Array],

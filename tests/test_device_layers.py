@@ -328,6 +328,45 @@ def test_coordinate_spans_carry_coordinate_and_bytes():
             if r["name"] == "coord.bucket"} == {"per-user"}
 
 
+@pytest.mark.parametrize("config, by_run", [
+    # rows by user, most users under the cap: lanes addressed by the start
+    # of their run (``glmix_ml25m``, under its mesh: tests/
+    # test_mesh_exchange.py); a movie's rows lie anywhere
+    ("glmix_ml20m", {"per-user": True, "per-item": False}),
+    # correlated_shards shuffles both id columns: no lane is a run
+    ("glmix3_wide", {"per-user": False, "per-item": False}),
+    # every user over the cap: reservoirs only
+    ("glmix_chip", {"per-user": False}),
+])
+def test_bucket_span_counts_the_lanes_addressed_by_run(config, by_run):
+    """ISSUE 31's counter that says how often the mechanism engages:
+    ``coord.bucket`` carries ``run_lanes`` (a class), ``run_slots`` and
+    ``index_slots`` (summed; a class's are its lanes x its capacity)."""
+    catalog = harness.Catalog()
+    cfg = harness.sized(catalog.json("configs", config), True)
+    data = catalog.module("recipes", cfg["recipe"]).make_training(cfg, 6)
+    with tracing() as tracer:
+        coords = catalog.module("traffic", "train_fits").build_coordinates(
+            cfg, data, None)
+        spans = {r["attrs"]["coordinate"]: r["attrs"]
+                 for r in tracer.records() if r["name"] == "coord.bucket"}
+    assert set(spans) == set(by_run)
+    for cid, engaged in by_run.items():
+        a = spans[cid]
+        assert len(a["run_lanes"]) == a["classes"]
+        assert a["run_slots"] == sum(
+            r * c for r, c in zip(a["run_lanes"], a["capacities"]))
+        assert a["run_slots"] + a["index_slots"] == a["slots"]
+        assert (a["run_slots"] > 0) == engaged, (cid, a)
+        assert any("run_start" in dev for dev in coords[cid]._dev) == engaged
+    if config == "glmix_ml20m":  # the cell's share: most of the slots
+        a = spans["per-user"]
+        by_class = dict(zip(a["capacities"], a["run_lanes"]))
+        assert a["run_slots"] > 0.5 * a["slots"]
+        assert by_class[4] > 0 and by_class[64] > 0
+        assert by_class[1] == by_class[2] == 0  # under RUN_CAPACITY_MIN
+
+
 # -- (vi) the join and the readers, on a synthetic trace ----------------------
 
 U = "jit(program)/while/body/closed_call/photon.update.per_user/"

@@ -178,3 +178,61 @@ def test_solver_rule_follows_the_worst_bucket(rng, d, rows, loss, extra, soa):
                                 "reg": Regularization(l2=1.0), **extra})
     coord = build_coordinate("user", data, cfg, TASKS[loss])
     assert coord._use_soa == soa
+
+
+ROW_GATHER = "slice_sizes = array<i64: 1, %d>" % bucketing.EM_ROW
+
+
+@pytest.mark.parametrize("rows_lie, cap, run_classes", [
+    ("by_user", None, 3),    # the control: classes of 64 to 256, all runs
+    ("by_user", 128, 2),     # 150 rows capped to a reservoir beside runs
+    ("anywhere", None, 0),   # the ids shuffled: no lane is a run
+    ("by_user", 32, 0),      # every user over the cap: reservoirs only
+    ("short", None, 0),      # runs, in classes under RUN_CAPACITY_MIN
+])
+def test_run_rule_reads_the_rows(rng, rows_lie, cap, run_classes):
+    """``bucketing._class_lanes``: a lane is addressed by its start where
+    its rows are one run of samples and its class holds at least
+    ``RUN_CAPACITY_MIN`` rows; every other lane, and with it the program
+    of a coordinate that has none, keeps one index a slot."""
+    counts = ([1, 2, 1, 2] * 4 if rows_lie == "short"
+              else [40, 50, 60, 64, 100, 128, 150, 90])
+    uid = np.repeat(np.arange(len(counts)), counts)
+    if rows_lie == "anywhere":
+        uid = rng.permutation(uid)
+    n = len(uid)
+    data = GameData(y=(rng.random(n) < 0.5).astype(float),
+                    features={"u": rng.normal(size=(n, 4))},
+                    id_tags={"userId": uid})
+    cfg = RandomEffectConfig(random_effect_type="userId", feature_shard="u",
+                             reg=Regularization(l2=1.0), active_cap=cap,
+                             solver=SolverConfig(max_iters=5))
+    prev = set_tracer(Tracer(capacity=256, enabled=True))
+    try:
+        coord = build_coordinate("user", data, cfg, TASKS["logistic"])
+        (span,) = [r["attrs"] for r in obs.get_tracer().records()
+                   if r["name"] == "coord.bucket"]
+    finally:
+        set_tracer(prev)
+    classes = coord.buckets.buckets
+    assert sum(b.run_lanes > 0 for b in classes) == run_classes
+    assert ["run_start" in dev for dev in coord._dev] == [
+        b.run_lanes > 0 for b in classes]
+    assert span["run_lanes"] == [b.run_lanes for b in classes]
+    assert span["run_slots"] + span["index_slots"] == span["slots"]
+    assert span["run_slots"] == sum(b.run_lanes * b.capacity
+                                    for b in classes)
+    if cap == 128:  # the capped user's lane stands behind the runs
+        b = classes[-1]
+        assert (b.capacity, b.num_lanes, b.run_lanes) == (128, 4, 3)
+        assert b.counts[-1] == 128 and np.any(np.diff(b.rows[-1]) > 1)
+    sweep = FusedSweep({"user": coord}, num_iterations=1)
+    args, _ = sweep._program_args(None, None, 0, None)
+    text = sweep._program.lower(*args).as_text()
+    assert (ROW_GATHER in text) == (run_classes > 0)
+    # either way the lanes are what one index a slot gives
+    offsets = rng.normal(size=n)
+    gather = coord._offsets_into_lanes(np.asarray(offsets), coord._dev)
+    for bi, b in enumerate(classes):
+        want = np.where(b.rows >= 0, offsets[np.maximum(b.rows, 0)], 0.0)
+        assert np.array_equal(np.asarray(gather(bi)), want)

@@ -48,7 +48,8 @@ from photon_ml_tpu.obs.trace import Tracer, set_tracer  # noqa: E402
 from photon_ml_tpu.opt.types import SolverConfig  # noqa: E402
 from photon_ml_tpu.parallel import bucketing  # noqa: E402
 from photon_ml_tpu.parallel.mesh import (make_mesh, over_chips,  # noqa: E402
-                                         padded_samples, spans_chips)
+                                         padded_samples, samples_on_device,
+                                         spans_chips)
 from photon_ml_tpu.types import TaskType  # noqa: E402
 
 CATALOG = harness.Catalog()
@@ -271,6 +272,9 @@ def test_every_sample_chunk_and_lane_axis_is_sharded(on_mesh):
     for name, a in leaves:
         if a.ndim == 1 and a.shape[0] == 8:  # the fixed effect's state [d]
             continue
+        if a.size == 0:  # ``rows`` of a class whose every lane is a run lane
+            assert name.endswith("['rows']") and a.shape[0] == 0, name
+            continue
         seen += 1
         assert len(a.sharding.device_set) == CHIPS, name
         local = a.sharding.shard_shape(a.shape)
@@ -454,6 +458,47 @@ def test_kernels_run_a_shard_in_place_inside_shard_map(mesh, monkeypatch):
     np.testing.assert_allclose(grad, ref_grad, rtol=1e-10, atol=1e-12)
 
 
+def test_lanes_by_run_under_the_mesh(on_mesh):
+    """ISSUE 31: per-user lanes whose rows are one run of samples are
+    addressed by their start on every chip, the same number of them in
+    every chip's share; the lanes are the one-device lanes, entity by
+    entity, and the offsets still cross in ONE all-gather an update."""
+    data, coords, _, text, *_ = on_mesh
+    n = len(data["y"])
+    alone = build(data, "entity_major", None, dtype=np.float32)
+    offsets = np.random.default_rng(5).normal(size=n).astype(np.float32)
+    for cid in ("per-user", "per-item"):
+        coord, one = coords[cid], alone[cid]
+        classes = coord.buckets.buckets
+        has_runs = [b.run_lanes > 0 for b in classes]
+        # rows by user: runs; a movie's rows lie anywhere
+        assert any(has_runs) == (cid == "per-user")
+        assert ["run_start" in dev for dev in coord._dev] == has_runs
+        for b, dev in zip(classes, coord._dev):
+            if b.run_lanes:
+                assert dev["run_start"].shape == (CHIPS * b.run_lanes,)
+                assert dev["run_start"].sharding.shard_shape(
+                    dev["run_start"].shape) == (b.run_lanes,)
+                assert dev["rows"].shape == (
+                    b.num_lanes - CHIPS * b.run_lanes, b.capacity)
+            assert dev["valid"].shape == (b.num_lanes, b.capacity)
+        gather = coord._offsets_into_lanes(
+            samples_on_device(offsets, coord.mesh, np.float32), coord._dev)
+        gather_one = one._offsets_into_lanes(jnp.asarray(offsets), one._dev)
+        for bi, b in enumerate(classes):
+            got, got_one = np.asarray(gather(bi)), np.asarray(gather_one(bi))
+            assert np.array_equal(got, np.where(
+                b.rows >= 0, offsets[np.maximum(b.rows, 0)], 0.0))
+            for e, (bj, lane) in coord.buckets.lane_of.items():
+                if bj == bi:
+                    bk, lane_one = one.buckets.lane_of[e]
+                    assert bk == bi
+                    assert np.array_equal(got[lane], got_one[lane_one])
+    crossing = [line for line in text.splitlines()
+                if COLLECTIVE.match(line) and "photon.exchange.offsets" in line]
+    assert len(crossing) == 2 and all("all-gather" in c for c in crossing)
+
+
 # -- (c) tracing ---------------------------------------------------------------
 
 def test_exchange_scopes_are_in_the_op_table(on_mesh):
@@ -494,6 +539,11 @@ def test_spans_say_what_is_sharded_and_what_crosses(on_mesh):
     for a in spans_named(records, "coord.bucket"):
         assert a["lanes_per_device"] == [l // CHIPS for l in a["lanes"]]
         assert all(l % CHIPS == 0 for l in a["lanes"])
+        # ISSUE 31: the lanes addressed by their run's start, a multiple
+        # of the chips in every class; none of a movie's
+        assert all(r % CHIPS == 0 for r in a["run_lanes"])
+        assert a["run_slots"] + a["index_slots"] == a["slots"]
+        assert (a["run_slots"] > 0) == (a["coordinate"] == "per-user")
     (ex,) = spans_named(records, "descent.exchange")
     assert ex["coordinates"] == list(coords) and ex["devices"] == CHIPS
     assert ex["collectives"] == sweep._collectives != {}
