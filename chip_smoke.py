@@ -220,16 +220,9 @@ def lowered_sweep_text(seen):
     with: a pallas kernel that went to Mosaic is a ``tpu_custom_call``
     carrying the kernel's name; one that was interpreted or replaced by
     XLA is not there."""
-    import jax
-    import jax.numpy as jnp
-
     sweep, plan = seen["sweep"], seen["plan"]
-    regs = tuple(sweep.coordinates[cid].config.reg for cid in sweep.order)
-    vscores0, val_base = plan.initial_state(None)
-    return sweep._val_program.lower(
-        *sweep.init_carry(None), vscores0, regs, jax.random.PRNGKey(0),
-        sweep._base, sweep._datas, plan.datas, jnp.asarray(val_base),
-        plan.y_dev, plan.wt_dev).as_text()
+    _key, program = sweep._validated_program(plan)
+    return program.lower(*sweep._validated_args(plan)).as_text()
 
 
 def check_fit(ck, sz, data, result, seen, on_chip):

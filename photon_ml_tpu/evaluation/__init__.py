@@ -13,6 +13,7 @@ from photon_ml_tpu.evaluation.evaluator import (  # noqa: F401
     EvaluatorType,
     EvaluationSuite,
     EvaluationResults,
+    GroupLayout,
     make_evaluator,
     grouped_evaluate,
 )

@@ -6,15 +6,20 @@ SmoothedHingeLoss, PrecisionAtK), MultiEvaluator.scala:36-70 (group by id tag,
 evaluate each group with a LocalEvaluator, average the per-group metrics),
 EvaluationSuite.scala:33-115 (evaluator set + distinguished primary).
 
-Grouped evaluation on TPU: groups are padded to a common size and the metric
-is ``vmap``-ed over the group lane (weight-0 padding rows are inert in every
-metric) — the reference's shuffle-and-iterate becomes one batched kernel.
+Grouped evaluation on TPU: the groups of a sample set are a LAYOUT built once
+(``GroupLayout``: each row's dense group index, uploaded once); a call is one
+sort by (group, score) and segmented scans on the device, O(n) memory whatever
+the largest group (``metrics.grouped_metric``) — the reference's
+shuffle-and-iterate becomes one jitted program.  ``EvaluationSuite.
+device_inputs`` + ``trace_evaluate`` are the traced form the validated sweep
+runs inside its program (game/fused.py).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -84,14 +89,30 @@ class Evaluator:
             return lambda s, l, w: M.precision_at_k(k, s, l, w)
         return _METRIC_FNS[self.kind]
 
-    def evaluate(self, scores: Array, labels: Array, weights: Array,
-                 group_ids: Optional[np.ndarray] = None) -> float:
-        fn = self.metric_fn()
+    def trace_evaluate(self, scores: Array, labels: Array, weights: Array,
+                       layout: "Optional[GroupLayout]" = None) -> Array:
+        """Traceable: the metric as a device scalar; for a Multi- evaluator
+        over the rows' groups in ``layout``."""
         if self.group_name is None:
-            return float(fn(scores, labels, weights))
+            return self.metric_fn()(scores, labels, weights)
+        if layout is None:
+            raise ValueError(f"evaluator {self.name} needs group ids '{self.group_name}'")
+        return M.grouped_metric(self.kind.value, scores, labels, weights,
+                                layout.gid, layout.num_groups, k=self.k)
+
+    def evaluate(self, scores: Array, labels: Array, weights: Array,
+                 group_ids: "Optional[np.ndarray | GroupLayout]" = None) -> float:
+        """Host: the metric as a float.  ``group_ids``: the rows' group
+        labels, or the ``GroupLayout`` a caller that evaluates the same
+        rows again built of them once."""
+        if self.group_name is None:
+            return float(self.metric_fn()(scores, labels, weights))
         if group_ids is None:
             raise ValueError(f"evaluator {self.name} needs group ids '{self.group_name}'")
-        return float(grouped_evaluate(fn, group_ids, scores, labels, weights))
+        layout = (group_ids if isinstance(group_ids, GroupLayout)
+                  else GroupLayout.build(group_ids))
+        return float(_grouped_jit(self, jnp.asarray(scores), jnp.asarray(labels),
+                                  jnp.asarray(weights), layout))
 
 
 def make_evaluator(spec: str) -> Evaluator:
@@ -105,13 +126,47 @@ def make_evaluator(spec: str) -> Evaluator:
     return Evaluator(EvaluatorType(spec), group_name=group)
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class GroupLayout:
+    """The groups of one sample set under one id tag, built ONCE
+    (``np.unique`` on the host, one upload): ``gid`` [n] int32 on the device,
+    each row's group as a dense index in ``[0, num_groups)``.  The groups of
+    a held-out set never change during a fit or a search; what changes is
+    the scores, and those are sorted on the device."""
+
+    gid: Array
+    num_groups: int
+
+    @classmethod
+    def build(cls, group_ids: np.ndarray) -> "GroupLayout":
+        uniq, inverse = np.unique(np.asarray(group_ids), return_inverse=True)
+        return cls(gid=jnp.asarray(inverse.astype(np.int32)),
+                   num_groups=len(uniq))
+
+    def tree_flatten(self):
+        return (self.gid,), self.num_groups
+
+    @classmethod
+    def tree_unflatten(cls, num_groups, children):
+        return cls(gid=children[0], num_groups=num_groups)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _grouped_jit(evaluator: Evaluator, scores, labels, weights, layout):
+    return evaluator.trace_evaluate(scores, labels, weights, layout)
+
+
 def grouped_evaluate(metric_fn: MetricFn, group_ids: np.ndarray, scores: Array,
                      labels: Array, weights: Array) -> float:
-    """Per-group metric, unweighted-averaged over groups with >0 total weight
-    (reference MultiEvaluator.evaluate:36-70).
+    """The padded ORACLE of ``metrics.grouped_metric``, for small sizes
+    (tests): per-group metric, unweighted-averaged over groups with >0 total
+    weight (reference MultiEvaluator.evaluate:36-70).
 
-    Pads groups to the max group size and vmaps the metric; padding rows have
-    weight 0 and score -inf is NOT needed because every metric is weight-aware.
+    Pads groups to the max group size and vmaps the metric (groups x largest
+    group slots, rebuilt on the host on every call: at 90,112 users with up
+    to 1,790 held-out rows that is 1.9 GB for 2.6M rows, which is why
+    nothing in the package calls it any more); padding rows have weight 0.
     """
     group_ids = np.asarray(group_ids)
     uniq, inverse, counts = np.unique(group_ids, return_inverse=True, return_counts=True)
@@ -171,6 +226,40 @@ class EvaluationSuite:
             gids = (group_ids or {}).get(ev.group_name) if ev.group_name else None
             out[ev.name] = ev.evaluate(scores, labels, weights, gids)
         return EvaluationResults(values=out, primary_name=self.primary.name)
+
+    def device_inputs(self, labels, weights, group_ids: Optional[Dict[str, np.ndarray]],
+                      dtype) -> dict:
+        """What ``trace_evaluate`` reads besides the scores, on the device,
+        built ONCE per sample set: labels, weights and one ``GroupLayout``
+        per id tag a Multi- evaluator groups by.  A pytree: it enters a
+        jitted program as an argument."""
+        tags = {ev.group_name for ev in self.evaluators if ev.group_name}
+        missing = tags - set(group_ids or {})
+        if missing:
+            raise ValueError(f"the suite groups by {sorted(missing)}: no such id tag")
+        return {"labels": jnp.asarray(np.asarray(labels, dtype)),
+                "weights": jnp.asarray(np.asarray(weights, dtype)),
+                "layouts": {t: GroupLayout.build(group_ids[t]) for t in sorted(tags)}}
+
+    def trace_evaluate(self, scores: Array, inputs: dict) -> Array:
+        """Traceable: every evaluator's metric of ``scores`` [n] against
+        ``device_inputs``, one device array [evaluators] in the suite's
+        order, each under its own ``photon.evaluate.<metric>`` scope."""
+        from photon_ml_tpu.obs.trace import device_scope
+
+        out = []
+        for ev in self.evaluators:
+            with device_scope("evaluate", ev.name):
+                out.append(ev.trace_evaluate(
+                    scores, inputs["labels"], inputs["weights"],
+                    inputs["layouts"].get(ev.group_name)))
+        return jnp.stack(out).astype(scores.dtype)
+
+    def results(self, values) -> EvaluationResults:
+        """One row of ``trace_evaluate``'s output, fetched, as results."""
+        return EvaluationResults(
+            values={ev.name: float(v) for ev, v in zip(self.evaluators, values)},
+            primary_name=self.primary.name)
 
     def better_than(self, a: EvaluationResults, b: Optional[EvaluationResults]) -> bool:
         if b is None:

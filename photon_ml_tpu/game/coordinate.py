@@ -760,7 +760,8 @@ class FixedEffectCoordinate(Coordinate):
         if isinstance(shard, SparseShard):
             return {"x_idx": device_put_counted(shard.indices, np.int32),
                     "x_val": device_put_counted(shard.values, self._dtype)}
-        return {"x": device_put_counted(np.asarray(shard), self._dtype)}
+        # a design the caller made on the device stays there: no byte moves
+        return {"x": device_put_counted(shard, self._dtype)}
 
     def trace_score_external(self, published: Array, vdata) -> Array:
         """== FixedEffectModel.score: x @ w (dense) or the gather-einsum
@@ -2148,24 +2149,67 @@ class RandomEffectCoordinate(Coordinate):
         once.  Slots map the external entity ids through THIS RUN's trained
         slot order (the stacked layout ``trace_publish`` emits); entities
         this run never trained get -1 and score 0 — carried warm-start
-        entities are a host-side CONSTANT (``carry_through_scores_on``)."""
+        entities are a host-side CONSTANT (``carry_through_scores_on``).
+
+        The design lies as the training twin's does, by the same one rule
+        over what the held-out rows show (``parallel/bucketing.py``): under
+        ``use_transposed_scoring``'s line row-major ``[n, d]``; over it the
+        samples go on the lanes, entity-major where ``entity_major_chunk``
+        finds a chunk length for the held-out rows of each entity, else
+        transposed ``[d, n]``.  Span ``coord.external_layout`` says which."""
+        from photon_ml_tpu.parallel.bucketing import (entity_major_design,
+                                                      entity_major_layout,
+                                                      entity_runs,
+                                                      use_transposed_scoring)
+
         shard = data.features[self.config.feature_shard]
         ids = np.asarray(data.id_tags[self.config.random_effect_type],
                          np.int64)
-        out = {"slots": jnp.asarray(_slots_from(self._slot_of, ids))}
-        if isinstance(shard, SparseShard):
-            out["x_idx"] = device_put_counted(shard.indices, np.int32)
-            out["x_val"] = device_put_counted(shard.values, self._dtype)
-        else:
-            out["x"] = device_put_counted(np.asarray(shard), self._dtype)
-        return out
+        with obs_span("coord.external_layout",
+                      coordinate=self.coordinate_id, rows=len(ids)) as sp:
+            if isinstance(shard, SparseShard):
+                sp.set(layout="sparse")
+                return {"slots": jnp.asarray(_slots_from(self._slot_of, ids)),
+                        "x_idx": device_put_counted(shard.indices, np.int32),
+                        "x_val": device_put_counted(shard.values,
+                                                    self._dtype)}
+            n, d = shard.shape
+            if not use_transposed_scoring(n, d,
+                                          np.dtype(self._dtype).itemsize):
+                sp.set(layout="row_major")
+                return {"slots": jnp.asarray(_slots_from(self._slot_of, ids)),
+                        "x": device_put_counted(shard, self._dtype)}
+            x_t = device_put_counted(shard.T, self._dtype)
+            em = entity_major_layout(entity_runs(ids))
+            if em is None:
+                sp.set(layout="transposed")
+                return {"slots": jnp.asarray(_slots_from(self._slot_of, ids)),
+                        "x_t": x_t}
+            pos = em.pos
+            if pos is None and em.lanes * em.chunk != n:
+                pos = np.arange(n, dtype=np.int32)  # cut the tail's zeros
+            sp.set(layout="entity_major", chunk=em.chunk, lanes=em.lanes,
+                   fill=em.fill, identity=pos is None)
+            return {"lane_slot": jnp.asarray(em.lane_slots(
+                        _slots_from(self._slot_of, em.entities))),
+                    "x_em": entity_major_design(em, x_t),
+                    "pos": None if pos is None else jnp.asarray(pos)}
 
     def trace_score_external(self, published: Array, vdata) -> Array:
-        """== RandomEffectModel.score on the published stack: gather + row
-        dot (dense) or the two-level sparse gather."""
+        """== RandomEffectModel.score on the published stack, in whichever
+        layout ``external_data`` chose: the entity-major or transposed
+        narrow layouts, a gather + row dot (row-major) or the two-level
+        sparse gather."""
         from photon_ml_tpu.parallel.bucketing import (score_samples,
-                                                      score_samples_sparse)
+                                                      score_samples_em,
+                                                      score_samples_sparse,
+                                                      score_samples_t)
 
+        if "x_em" in vdata:
+            return score_samples_em(published, vdata["lane_slot"],
+                                    vdata["x_em"], vdata["pos"])
+        if "x_t" in vdata:
+            return score_samples_t(published, vdata["slots"], vdata["x_t"])
         if "x" in vdata:
             return score_samples(published, vdata["slots"], vdata["x"])
         return score_samples_sparse(published, vdata["slots"],

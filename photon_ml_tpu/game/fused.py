@@ -36,6 +36,7 @@ from jax import lax
 
 from photon_ml_tpu.game.coordinate import Coordinate
 from photon_ml_tpu.models.game import GameModel
+from photon_ml_tpu.obs import get_registry
 from photon_ml_tpu.obs.trace import (device_scope, get_tracer,
                                      hlo_collectives, hlo_op_table,
                                      metadata_keyed_compile_cache)
@@ -45,6 +46,13 @@ from photon_ml_tpu.parallel.mesh import samples_on_device
 from photon_ml_tpu.types import VarianceComputationType
 
 Array = jax.Array
+
+
+@jax.jit
+def _take_iteration(pubs, t):
+    """Iteration ``t`` (traced: one program whatever it is) of every
+    coordinate's stacked published coefficients."""
+    return tuple(p[t] for p in pubs)
 
 
 class FusedSweep:
@@ -88,7 +96,8 @@ class FusedSweep:
         self._snap_program = None  # built lazily by run_snapshots
         self._grid_program = None  # built lazily by run_grid
         self._grid_snap_program = None  # built lazily by run_grid_snapshots
-        self._val_program = None   # built lazily by run_validated
+        self._val_programs = {}    # built lazily by run_validated
+        self._val_tables = set()   # their op-to-layer tables (traced runs)
         self._table_recorded = False  # the main program's op-to-layer table
         self._collectives = {}  # its collective instructions (traced runs)
         self.solve_iterations = None  # the last run_device's (see there)
@@ -259,7 +268,10 @@ class FusedSweep:
         and also says how each coordinate's line search evaluates a trial
         (``Coordinate.line_search``)."""
         if obs_enabled() and not self._table_recorded:
-            self._record_device_table(initial, regs, seed, carry0)
+            self._table_recorded = True
+            args, _ = self._program_args(initial, regs, seed, carry0)
+            self._collectives = hlo_collectives(self._record_device_table(
+                "jit_program", self._program, args))
         # no fence: this is the ENQUEUE (argument preparation + dispatch),
         # what the device waits for between back-to-back fits
         with obs_span("descent.dispatch"):
@@ -308,25 +320,24 @@ class FusedSweep:
                                         else self.num_iterations)
                                 for k, b in e.items()} for e in sent])
 
-    def _record_device_table(self, initial, regs, seed, carry0) -> None:
-        """Once per sweep object, traced runs only: which layer each
-        instruction of the main program's executable belongs to, read off
-        that executable's own text and kept with the tracer
-        (``hlo_op_table``).  Lowers with the call's own arguments, so the
-        dispatch that follows finds this lowering and this executable in
-        jit's own caches: ONE executable serves the table, the run and an
-        operator's profile, and tracing adds no second compile or load.
-        Its persistent-cache key holds the metadata (the scopes are this
-        tree's, whoever filled the cache): the first traced run in a cache
-        compiles the program once more, later ones load it."""
-        self._table_recorded = True
-        with obs_span("descent.device_table"):
-            args, _ = self._program_args(initial, regs, seed, carry0)
+    def _record_device_table(self, name: str, program, args) -> str:
+        """Once per program of a sweep object, traced runs only: which layer
+        each instruction of ``program``'s executable belongs to, read off
+        that executable's own text and kept with the tracer under ``name``
+        (``hlo_op_table``; ``jit_program`` the main program's,
+        ``jit_validated`` the validated one's).  Lowers with the call's own
+        arguments, so the dispatch that follows finds this lowering and
+        this executable in jit's own caches: ONE executable serves the
+        table, the run and an operator's profile, and tracing adds no
+        second compile or load.  Its persistent-cache key holds the
+        metadata (the scopes are this tree's, whoever filled the cache):
+        the first traced run in a cache compiles the program once more,
+        later ones load it.  Returns the executable's text."""
+        with obs_span("descent.device_table", program=name):
             with metadata_keyed_compile_cache():
-                text = self._program.lower(*args).compile().as_text()
-            get_tracer().record_device_table("jit_program",
-                                             hlo_op_table(text))
-            self._collectives = hlo_collectives(text)
+                text = program.lower(*args).compile().as_text()
+            get_tracer().record_device_table(name, hlo_op_table(text))
+        return text
 
     def run(self, initial: Optional[GameModel] = None,
             regs: Optional[Sequence] = None, seed: int = 0,
@@ -463,25 +474,28 @@ class FusedSweep:
         estimator then falls back to the host-paced CoordinateDescent)."""
         return ValidationPlan(self, data, suite)
 
-    def _validated_fn(self):
+    def _validated_fn(self, loss, suite):
         """The validated program: the same ``_sweep_iteration`` core as the
         main program, with per-update held-out bookkeeping fused in —
         after every coordinate update the scanned body re-scores THAT
-        coordinate's held-out margins from its published coefficients,
-        folds them into the running held-out total with the same
-        residual-style replace the training scores use, and records the
-        weighted held-out loss (the in-program twin of the host loop's
-        per-update ``descent.validate`` evaluation).  Each iteration also
-        emits its published coefficients and held-out totals, so the host
-        evaluates the full metric suite per sweep boundary from ONE
-        device->host pull — a validated multi-iteration fit is ONE XLA
-        program."""
+        coordinate's held-out margins from its published coefficients
+        (scope ``photon.validate.score.<cid>``), folds them into the running
+        held-out total with the same residual-style replace the training
+        scores use, and records the weighted held-out loss
+        (``photon.validate.loss``; the in-program twin of the host loop's
+        per-update ``descent.validate`` evaluation).  At each sweep boundary
+        it evaluates the whole metric suite on the held-out totals
+        (``EvaluationSuite.trace_evaluate``, scopes
+        ``photon.evaluate.<metric>``) — a validated multi-iteration fit is
+        ONE XLA program whose host-bound outputs are a ``[T, evaluators]``
+        and a ``[T, C]`` matrix; the held-out totals and every iteration's
+        published coefficients stay on the device."""
         order, coords = self.order, self.coordinates
         needs_rand = self._needs_rand
-        loss = self._val_loss
 
         def program(states0, scores0, vscores0, regs, base_key, base, datas,
-                    vdatas, val_base, val_y, val_wt):
+                    vdatas, val_base, suite_inputs):
+            val_y, val_wt = suite_inputs["labels"], suite_inputs["weights"]
             wt_sum = jnp.maximum(val_wt.sum(), jnp.asarray(1e-30, self._dtype))
 
             def body(carry, it):
@@ -500,39 +514,70 @@ class FusedSweep:
                 def on_update(i, cid, state_i):
                     nonlocal vtotal
                     w_pub = coords[cid].trace_publish(state_i, data=datas[i])
-                    vm = coords[cid].trace_score_external(
-                        w_pub, vdatas[i]).astype(self._dtype)
-                    vtotal = vtotal - vscores[i] + vm
+                    with device_scope("validate.score", cid):
+                        vm = coords[cid].trace_score_external(
+                            w_pub, vdatas[i]).astype(self._dtype)
+                        vtotal = vtotal - vscores[i] + vm
                     vscores[i] = vm
                     published[i] = w_pub
-                    z = vtotal + val_base
-                    losses.append((val_wt * loss.loss(z, val_y)).sum()
-                                  / wt_sum)
+                    with device_scope("validate.loss"):
+                        z = vtotal + val_base
+                        losses.append((val_wt * loss.loss(z, val_y)).sum()
+                                      / wt_sum)
 
                 states, scores, _, _ = self._sweep_iteration(
                     states, scores, regs, it_key, base, datas,
                     on_update=on_update)
+                metrics = suite.trace_evaluate(vtotal + val_base,
+                                               suite_inputs)
                 return ((tuple(states), tuple(scores), tuple(vscores)),
-                        (tuple(published), vtotal, jnp.stack(losses)))
+                        (tuple(published), vtotal, jnp.stack(losses),
+                         metrics))
 
-            carry, (pubs, vtotals, losses) = lax.scan(
+            carry, (pubs, vtotals, losses, metrics) = lax.scan(
                 body, (states0, scores0, vscores0),
                 jnp.arange(self.num_iterations))
-            return pubs, vtotals, losses
+            return pubs, vtotals, losses, metrics
 
         return program
+
+    def _validated_program(self, plan: "ValidationPlan"):
+        """(key, the jitted validated program) for ``plan``'s loss and
+        evaluators: static program structure, so every plan over this
+        sweep that asks for the same metrics shares one program (the
+        held-out arrays and group layouts are its arguments)."""
+        key = (plan.loss, tuple(plan.suite.evaluators))
+        if key not in self._val_programs:
+            self._val_programs[key] = jax.jit(
+                self._validated_fn(plan.loss, plan.suite))
+        return key, self._val_programs[key]
+
+    def _validated_args(self, plan: "ValidationPlan", initial=None,
+                        regs=None, seed: int = 0, carry0=None) -> tuple:
+        """The validated program's positional arguments."""
+        carry = carry0 if carry0 is not None else self.init_carry(initial)
+        if regs is None:
+            regs = tuple(self.coordinates[cid].config.reg
+                         for cid in self.order)
+        base, _carried = self._base_with_carry_through(initial)
+        vscores0, val_base = plan.initial_state(initial)
+        return (*carry, vscores0, tuple(regs), jax.random.PRNGKey(seed),
+                base, self._datas, plan.datas, val_base, plan.inputs)
 
     def run_validated(self, plan: "ValidationPlan",
                       initial: Optional[GameModel] = None,
                       regs: Optional[Sequence] = None, seed: int = 0,
                       carry0=None):
         """One fused descent WITH the validation suite: training updates,
-        held-out scoring and per-update held-out losses all run inside one
-        compiled program; the host evaluates ``plan.suite`` on each
-        iteration's held-out totals and keeps the best full model — the
-        exact best-model retention the host loop applies (full models at
-        sweep boundaries only, CoordinateDescent.scala:163-167 /
-        descent.py), without any per-update device round-trips.
+        held-out scoring, per-update held-out losses and the suite's
+        metrics at every sweep boundary all run inside one compiled
+        program; the host reads the ``[T, evaluators]`` metrics, keeps the
+        best full model — the exact best-model retention the host loop
+        applies (full models at sweep boundaries only,
+        CoordinateDescent.scala:163-167 / descent.py) — and pulls the
+        retained iteration's coefficients alone.  The held-out totals of
+        every iteration stay on the device as ``plan.totals`` [T, n_val]
+        (offsets not included), until the next run over the plan.
 
         Returns ``(best_model, evals, best_eval, losses)``: the retained
         GameModel, one EvaluationResults per outer iteration (boundary
@@ -550,41 +595,42 @@ class FusedSweep:
                 "run_validated does not compute coefficient variances; use "
                 "the host CoordinateDescent for variance-computing validated "
                 "fits")
-        if self._val_program is None:
-            # the held-out loss fn is static program structure; it derives
-            # from the sweep's task, so every plan over this sweep agrees
-            self._val_loss = plan.loss
-            self._val_program = jax.jit(self._validated_fn())
-        carry = carry0 if carry0 is not None else self.init_carry(initial)
-        if regs is None:
-            regs = tuple(self.coordinates[cid].config.reg
-                         for cid in self.order)
-        base, _carried = self._base_with_carry_through(initial)
-        vscores0, val_base_np = plan.initial_state(initial)
+        key, program = self._validated_program(plan)
+        args = self._validated_args(plan, initial, regs, seed, carry0)
+        if obs_enabled() and key not in self._val_tables:
+            self._val_tables.add(key)
+            self._record_device_table("jit_validated", program, args)
+        suite = plan.suite
         with obs_span("descent.fused_validated", device_sync=True,
                       coordinates=len(self.order),
                       iterations=self.num_iterations):
-            pubs, vtotals, losses = self._val_program(
-                *carry, vscores0, tuple(regs), jax.random.PRNGKey(seed),
-                base, self._datas, plan.datas,
-                jnp.asarray(val_base_np), plan.y_dev, plan.wt_dev)
-            # one bulk pull per output (the only device->host transfers of
-            # the whole validated fit)
-            vtotals = np.asarray(vtotals)
+            pubs, plan.totals, losses, metrics = program(*args)
+            # the program's host-bound outputs: [T, C] and [T, evaluators]
             losses = np.asarray(losses)
-            pubs = [np.asarray(jax.device_get(p)) for p in pubs]
-        evals, best_t, best_ev = [], 0, None
-        for t in range(self.num_iterations):
-            ev = plan.suite.evaluate(vtotals[t] + val_base_np, plan.y,
-                                     plan.weight, group_ids=plan.group_ids)
-            evals.append(ev)
-            # strict-improvement retention in iteration order — identical
-            # tie-breaking to the host loop's better_than chain
-            if plan.suite.better_than(ev, best_ev):
-                best_ev, best_t = ev, t
-        models = {cid: self.coordinates[cid].export_model(pubs[i][best_t])
-                  for i, cid in enumerate(self.order)}
-        model = GameModel(models=self._merge_carry_through(models, initial))
+            metrics = np.asarray(metrics)
+        with obs_span("validate.evaluate", rows=plan.n,
+                      evaluators=[ev.name for ev in suite.evaluators],
+                      groups={tag: layout.num_groups for tag, layout
+                              in plan.inputs["layouts"].items()}):
+            evals, best_t, best_ev = [], 0, None
+            for t in range(self.num_iterations):
+                ev = suite.results(metrics[t])
+                evals.append(ev)
+                # strict-improvement retention in iteration order —
+                # identical tie-breaking to the host loop's better_than
+                # chain
+                if suite.better_than(ev, best_ev):
+                    best_ev, best_t = ev, t
+        with obs_span("validate.export", iteration=best_t):
+            # the retained iteration's coefficients alone cross to the host
+            kept = jax.device_get(_take_iteration(pubs, np.int32(best_t)))
+            models = {cid: self.coordinates[cid].export_model(
+                          np.asarray(kept[i]))
+                      for i, cid in enumerate(self.order)}
+            model = GameModel(
+                models=self._merge_carry_through(models, initial))
+        get_registry().inc("validate.pull_bytes", losses.nbytes
+                           + metrics.nbytes + sum(k.nbytes for k in kept))
         return model, evals, best_ev, losses
 
     # --- regularization-grid batching -----------------------------------
@@ -692,11 +738,14 @@ class ValidationPlan:
 
     Built ONCE per (sweep, held-out set, suite): per-coordinate scoring
     pytrees (``Coordinate.external_data`` — designs + trained-slot maps,
-    uploaded once), the label/weight device twins the in-program loss
-    consumes, and the host-side arrays/suite the per-iteration metric
-    evaluation reads.  The per-fit constants (warm-start held-out margins,
-    carried-entity contributions) are computed by ``initial_state`` at run
-    time — they depend on the initial model, not the plan.
+    uploaded or taken where they lie, once), and the suite's device inputs
+    (``EvaluationSuite.device_inputs``: labels, weights — which the
+    in-program loss reads too — and one group layout per id tag a Multi-
+    evaluator groups by).  The per-fit constants (warm-start held-out
+    margins, carried-entity contributions) are computed by
+    ``initial_state`` at run time — they depend on the initial model, not
+    the plan; a cold start's are built once.  ``totals``: the last run's
+    held-out totals [T, n_val] on the device (``run_validated``).
     """
 
     def __init__(self, sweep: FusedSweep, data, suite):
@@ -706,36 +755,42 @@ class ValidationPlan:
         self.data = data
         self.suite = suite
         self.n = data.num_samples
-        self.y = np.asarray(data.y)
-        self.weight = np.asarray(data.weight)
         self.offset = np.asarray(data.offset)
-        self.group_ids = data.id_tags
-        # raises NotImplementedError for a coordinate without the
-        # external-scoring interface — callers fall back to the host loop
-        self.datas = tuple(
-            sweep.coordinates[cid].external_data(data)
-            for cid in sweep.order)
+        with obs_span("validate.plan", rows=self.n):
+            # raises NotImplementedError for a coordinate without the
+            # external-scoring interface — callers fall back to the host
+            # loop
+            self.datas = tuple(
+                sweep.coordinates[cid].external_data(data)
+                for cid in sweep.order)
+            self.inputs = suite.device_inputs(data.y, data.weight,
+                                              data.id_tags, sweep._dtype)
         first = sweep.coordinates[sweep.order[0]]
         self.loss = loss_for_task(first.task)
-        self.y_dev = jnp.asarray(np.asarray(self.y, sweep._dtype))
-        self.wt_dev = jnp.asarray(np.asarray(self.weight, sweep._dtype))
+        self.totals = None
+        self._cold = None
 
     def initial_state(self, initial):
-        """(per-coordinate initial held-out margins as device arrays,
-        host ``val_base`` = offsets + carried-entity contributions) — the
-        held-out twin of ``FusedSweep._init_carry`` +
-        ``_base_with_carry_through``: warm-start models contribute their
-        held-out score from the start, carried (never-retrained) entities
-        ride the base as a constant so every in-program replace matches the
-        host loop's full-model re-scoring."""
+        """(per-coordinate initial held-out margins, ``val_base`` = offsets
+        + carried-entity contributions), device arrays — the held-out twin
+        of ``FusedSweep._init_carry`` + ``_base_with_carry_through``:
+        warm-start models contribute their held-out score from the start,
+        carried (never-retrained) entities ride the base as a constant so
+        every in-program replace matches the host loop's full-model
+        re-scoring."""
         sweep = self.sweep
         dtype = sweep._dtype
+        if initial is None:
+            if self._cold is None:
+                self._cold = (
+                    tuple(jnp.zeros(self.n, dtype) for _ in sweep.order),
+                    jnp.asarray(np.asarray(self.offset, dtype)))
+            return self._cold
         val_base = np.asarray(self.offset, dtype).copy()
         vscores = []
         for i, cid in enumerate(sweep.order):
             coord = sweep.coordinates[cid]
-            init = (initial[cid] if initial is not None and cid in initial
-                    else None)
+            init = initial[cid] if cid in initial else None
             if init is None:
                 vscores.append(jnp.zeros(self.n, dtype))
                 continue
@@ -747,4 +802,4 @@ class ValidationPlan:
                 s = s - np.asarray(c, dtype)
                 val_base += np.asarray(c, dtype)
             vscores.append(jnp.asarray(s))
-        return tuple(vscores), val_base
+        return tuple(vscores), jnp.asarray(val_base)

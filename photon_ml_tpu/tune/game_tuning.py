@@ -9,6 +9,7 @@ estimator.fit) and GameTrainingDriver.runHyperparameterTuning:643-674
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -21,6 +22,8 @@ from photon_ml_tpu.game.data import GameData
 from photon_ml_tpu.game.descent import DescentHistory
 from photon_ml_tpu.game.estimator import (GameEstimator, GameFitResult,
                                           GameTransformer)
+from photon_ml_tpu.obs import get_registry
+from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.tune.search import DomainDim, GaussianProcessSearch, RandomSearch, SearchDomain
 
 
@@ -32,7 +35,13 @@ def _with_l2(cfg, l2: float):
 class GameEstimatorEvaluationFunction:
     """params vector (one L2 weight per coordinate, log-tuned) -> validation
     metric via a full GAME retrain (the reference retrains per tuning
-    iteration too, GameEstimatorEvaluationFunction.apply)."""
+    iteration too, GameEstimatorEvaluationFunction.apply).
+
+    A call is the span ``tune.trial`` (``l2`` per coordinate, ``primary``,
+    ``iterations_kept``) and counts one ``tune.trials``; what the fit and
+    the evaluation inside it take is in the spans they open themselves
+    (``descent.fused_validated``, ``validate.evaluate``, ``validate.export``
+    on the fully fused path)."""
 
     def __init__(self, estimator: GameEstimator, base_config: GameConfig,
                  data: GameData, validation_data: GameData, seed: int = 0,
@@ -54,9 +63,6 @@ class GameEstimatorEvaluationFunction:
             raise ValueError("all coordinates are locked; nothing to tune")
         self.results: List[GameFitResult] = []
         self._sweep = None  # None = not built; False = un-fusable
-        # phase accounting (reset_phases())
-        self.fit_seconds = 0.0
-        self.eval_seconds = 0.0
 
     def config_for(self, params: np.ndarray) -> GameConfig:
         # keep every coordinate (locked ones must stay in the config so the
@@ -114,25 +120,31 @@ class GameEstimatorEvaluationFunction:
     def _select_and_record(self, config: GameConfig, snapshots) -> float:
         """Evaluate each snapshot on validation, keep the best (host-loop
         best-model retention semantics), record the fit."""
-        import time
-
         suite = self.estimator.validation_suite
-        t0 = time.perf_counter()
         best_model, best_ev = None, None
-        for m in snapshots:
-            ev = GameTransformer(m, config.task).evaluate(
-                self.validation_data, suite)
-            if best_ev is None or suite.better_than(ev, best_ev):
-                best_model, best_ev = m, ev
-        self.eval_seconds += time.perf_counter() - t0
+        with obs_span("validate.evaluate", rows=len(self.validation_data.y),
+                      evaluators=[ev.name for ev in suite.evaluators],
+                      snapshots=len(snapshots)):
+            for m in snapshots:
+                ev = GameTransformer(m, config.task).evaluate(
+                    self.validation_data, suite)
+                if best_ev is None or suite.better_than(ev, best_ev):
+                    best_model, best_ev = m, ev
         self.results.append(GameFitResult(model=best_model, config=config,
                                           evaluation=best_ev,
                                           history=DescentHistory()))
         return best_ev.primary
 
     def __call__(self, params: np.ndarray) -> float:
-        import time
+        with obs_span("tune.trial", l2={
+                cid: float(v) for cid, v
+                in zip(self.coordinate_ids, params)}) as sp:
+            primary = self._trial(params, sp)
+            sp.set(primary=primary)
+        get_registry().inc("tune.trials")
+        return primary
 
+    def _trial(self, params: np.ndarray, sp) -> float:
         config = self.config_for(params)
         # Fused fast path: train WITHOUT per-update validation (the whole
         # retrain is one jitted sweep, reused across every tuning fit).
@@ -147,15 +159,14 @@ class GameEstimatorEvaluationFunction:
         if sweep is not None:
             sweep_obj, carry0, plan = sweep
             regs = [config.coordinates[cid].reg for cid in config.coordinates]
-            t0 = time.perf_counter()
             if plan is not None:
-                # fully fused validated fit: training, held-out scoring and
-                # per-update losses in ONE compiled program; the suite runs
-                # per sweep boundary on the stacked in-program scores
-                model, _evals, best_ev, _losses = sweep_obj.run_validated(
+                # fully fused validated fit: training, held-out scoring,
+                # per-update losses and the suite at every sweep boundary
+                # in ONE compiled program
+                model, evals, best_ev, _losses = sweep_obj.run_validated(
                     plan, initial=self.initial_model, carry0=carry0,
                     regs=regs, seed=self.seed)
-                self.fit_seconds += time.perf_counter() - t0
+                sp.set(iterations_kept=1 + evals.index(best_ev))
                 self.results.append(GameFitResult(
                     model=model, config=config, evaluation=best_ev,
                     history=DescentHistory()))
@@ -169,14 +180,11 @@ class GameEstimatorEvaluationFunction:
                 snapshots = sweep_obj.run_snapshots(
                     initial=self.initial_model, carry0=carry0, regs=regs,
                     seed=self.seed)
-            self.fit_seconds += time.perf_counter() - t0
             return self._select_and_record(config, snapshots)
-        t0 = time.perf_counter()
         res = self.estimator.fit(self.data, [config],
                                  validation_data=self.validation_data, seed=self.seed,
                                  initial_model=self.initial_model,
                                  locked_coordinates=self.locked or None)[0]
-        self.fit_seconds += time.perf_counter() - t0
         self.results.append(res)
         return res.evaluation.primary
 
@@ -188,8 +196,6 @@ class GameEstimatorEvaluationFunction:
         optimization (the search picks the q candidates).  Order of
         ``results`` matches sequential evaluation.  Falls back to
         sequential calls when the fused path is unavailable."""
-        import time
-
         params_batch = [np.asarray(p, float) for p in params_batch]
         if not params_batch:
             return []
@@ -201,7 +207,6 @@ class GameEstimatorEvaluationFunction:
         configs = [self.config_for(p) for p in params_batch]
         regs_grid = [[c.coordinates[cid].reg for cid in c.coordinates]
                      for c in configs]
-        t0 = time.perf_counter()
         # key off the per-candidate configs like __call__ does (advisor r4);
         # a batched fused grid shares ONE program, so candidates that
         # disagree on iteration count cannot ride it — fall back to
@@ -217,13 +222,9 @@ class GameEstimatorEvaluationFunction:
             snap_lists = sweep_obj.run_grid_snapshots(
                 regs_grid, initial=self.initial_model, carry0=carry0,
                 seed=self.seed)
-        self.fit_seconds += time.perf_counter() - t0
+        get_registry().inc("tune.trials", len(configs))
         return [self._select_and_record(config, snaps)
                 for config, snaps in zip(configs, snap_lists)]
-
-    def reset_phases(self) -> None:
-        self.fit_seconds = 0.0
-        self.eval_seconds = 0.0
 
     def vectorize(self, config: GameConfig) -> np.ndarray:
         """Config -> params vector (reference configurationToVector)."""
@@ -240,22 +241,51 @@ class GameEstimatorEvaluationFunction:
         callers that tune with ``batch_size=q`` warm q here so no compile
         lands inside their measured window."""
         n = len(self.results)
-        fit_s, eval_s = self.fit_seconds, self.eval_seconds
         base = self.vectorize(self.base_config)
-        self(base)
-        fused_ok = (not self.locked and self.estimator.fused is not False
-                    and self._fused_sweep() is not None)
-        if fused_ok:  # without a fused sweep there is no grid program to
-            for q in grid_sizes:  # compile — evaluate_batch would just run
-                if q > 1:  # q discarded sequential retrains
-                    self.evaluate_batch([base] * q)
-        del self.results[n:]
-        # warmup contributes nothing to phase accounting, but a reused
-        # evaluation function keeps the history it accumulated before
-        self.fit_seconds, self.eval_seconds = fit_s, eval_s
+        try:
+            self(base)
+            fused_ok = (not self.locked and self.estimator.fused is not False
+                        and self._fused_sweep() is not None)
+            if fused_ok:  # without a fused sweep there is no grid program
+                for q in grid_sizes:  # to compile — evaluate_batch would
+                    if q > 1:  # just run q discarded sequential retrains
+                        self.evaluate_batch([base] * q)
+        finally:
+            # a warm-up records nothing, whether it ended or raised: a
+            # reused evaluation function keeps the fits it had before
+            del self.results[n:]
 
 
 DEFAULT_L2_RANGE = (1e-4, 1e4)
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_searching = 0  # searches in flight: the listener below counts for them
+_listening = False  # the listener is registered (once a process)
+
+
+def _count_compile(event, *_a, **_k) -> None:
+    if _searching and event == _COMPILE_EVENT:
+        get_registry().inc("tune.compiles_in_search")
+
+
+@contextlib.contextmanager
+def _counting_compiles():
+    """Counter ``tune.compiles_in_search``: programs built (or loaded from
+    the persistent cache) while a search runs.  The regularisation weights
+    are traced inputs of ONE program (``Coordinate.sweep_key``), so past a
+    warm-up it stays where it was whatever the search proposes."""
+    global _searching, _listening
+    import jax
+
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(_count_compile)
+    _searching += 1
+    try:
+        yield
+    finally:
+        _searching -= 1
+
 
 
 def default_l2_domain(coordinate_ids, l2_range=DEFAULT_L2_RANGE) -> SearchDomain:
@@ -344,10 +374,11 @@ def tune_game_model(
     # (warm prior, reference ShrinkSearchRange / prior JSON defaults)
     priors = list(prior_observations or [])
     prior_params = fn.vectorize(base_config)
-    if np.all(prior_params > 0):
-        priors.append((prior_params, fn(prior_params)))
-    search.find(fn, n=n_iterations, priors=priors or None,
-                evaluate_batch=fn.evaluate_batch)
+    with _counting_compiles():
+        if np.all(prior_params > 0):
+            priors.append((prior_params, fn(prior_params)))
+        search.find(fn, n=n_iterations, priors=priors or None,
+                    evaluate_batch=fn.evaluate_batch)
 
     results = list(fn.results[start:])
     best = estimator.best(results)

@@ -17,6 +17,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import numpy as np
 from scipy.stats import qmc
 
+from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.tune.acquisition import expected_improvement
 from photon_ml_tpu.tune.gp import GaussianProcess
 
@@ -78,7 +79,14 @@ class RandomSearch:
     hands each round's candidates to ``evaluate_batch`` so backends that
     can amortize a multi-candidate fit (FusedSweep.run_grid: one vmapped
     program sharing the design-matrix streams) pay far less than
-    batch_size sequential retrains."""
+    batch_size sequential retrains.
+
+    Every round's proposal (here a Sobol draw; the GP fit and the expected
+    improvement of its candidates in ``GaussianProcessSearch``) is the span
+    ``tune.propose`` (``mode``, ``observations``, ``candidates``): the host
+    work that stands between one trial and the next."""
+
+    mode = "random"
 
     def __init__(self, domain: SearchDomain, minimize: bool = True, seed: int = 0,
                  batch_size: int = 1):
@@ -88,7 +96,6 @@ class RandomSearch:
         self.batch_size = max(1, int(batch_size))
         self._sobol = qmc.Sobol(domain.d, scramble=True, seed=seed)
         self.observations: List[Observation] = []
-        self.gp_seconds = 0.0  # candidate-proposal time (GP fit + EI)
 
     def _record(self, params: np.ndarray, raw_value: float) -> None:
         v = raw_value if self.minimize else -raw_value
@@ -101,6 +108,10 @@ class RandomSearch:
     def next_candidate(self) -> np.ndarray:
         return self.next_candidates(1)[0]
 
+    def _pool(self, q: int) -> int:
+        """Candidates the next proposal of ``q`` is chosen among."""
+        return q
+
     def find(self, evaluate: EvalFn, n: int,
              priors: Optional[Sequence[Tuple[np.ndarray, float]]] = None,
              evaluate_batch=None) -> Tuple[np.ndarray, float]:
@@ -109,15 +120,15 @@ class RandomSearch:
         (reference findWithPriors:61-93).  ``evaluate_batch``: optional
         callable(list of params) -> list of values used for rounds of more
         than one candidate (see batch_size)."""
-        import time
-
         for p, v in priors or []:
             self._record(np.asarray(p, float), v)
         done = 0
         while done < n:
-            t0 = time.perf_counter()
-            cands = self.next_candidates(min(self.batch_size, n - done))
-            self.gp_seconds += time.perf_counter() - t0
+            q = min(self.batch_size, n - done)
+            with obs_span("tune.propose", mode=self.mode,
+                          observations=len(self.observations),
+                          candidates=self._pool(q)):
+                cands = self.next_candidates(q)
             if evaluate_batch is not None and len(cands) > 1:
                 values = evaluate_batch(cands)
             else:
@@ -144,6 +155,12 @@ class GaussianProcessSearch(RandomSearch):
         super().__init__(domain, minimize, seed, batch_size)
         self.n_candidates = n_candidates  # reference draws 250
         self.n_initial = n_initial
+
+    mode = "bayesian"
+
+    def _pool(self, q: int) -> int:
+        return (q if len(self.observations) < self.n_initial
+                else self.n_candidates)
 
     def next_candidates(self, q: int) -> List[np.ndarray]:
         n_obs = len(self.observations)

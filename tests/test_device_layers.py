@@ -570,6 +570,42 @@ def test_traced_dry_run_of_the_heavy_tailed_cell(tmp_path):
     assert d["reference_dtype"] == "float32"
 
 
+def test_traced_dry_run_of_the_tuning_cell(tmp_path):
+    """``glmix_tune_ml20m.tune_jobs`` (ISSUE 32): a traced CPU dry run
+    reports the span metrics of a trial (the host's part of the suite, the
+    proposals, what the chip waits for), leaves out what needs a device
+    trace, holds four trials to the plain references and compiles nothing
+    in the window although every trial has other weights and the validated
+    program's table is recorded."""
+    cell = "glmix_tune_ml20m.tune_jobs"
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
+         "--seed", "3200000019", "--seconds", "2", "--trace", "1",
+         "--dry-run"],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        timeout=900,
+        env={**os.environ, "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla")})
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"], line["checks"]
+    assert line["checks"]["no_compile_in_window"]
+    assert line["checks"]["one_program"]
+    catalog = harness.Catalog()
+    listed = catalog.json("workloads", cell)["per_layer"]
+    want = {n for n in listed
+            if catalog.json("layer_metrics", n)["source"] != "device_trace"}
+    assert set(line["metrics"]) == want
+    assert {"xtune_evaluate_ms_per_trial", "xtune_propose_ms_per_trial",
+            "xtune_host_ms_per_trial", "solve_classes"} <= want
+    assert {"xtune_validate_busy_share", "device_idle_share",
+            "fused_glm_busy_share"} <= set(listed) - want
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    assert all(math.isfinite(v) and v > 0 for v in m.values())
+    # the chip waits for the proposal and for the host's part of a trial
+    assert m["xtune_host_ms_per_trial"] > m["xtune_propose_ms_per_trial"]
+    assert m["xtune_host_ms_per_trial"] > m["xtune_evaluate_ms_per_trial"]
+
+
 # -- (viii) a cache another tree filled ---------------------------------------
 
 @pytest.fixture

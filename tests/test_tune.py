@@ -459,9 +459,8 @@ def test_batched_grid_tuning_matches_sequential(rng):
             np.testing.assert_allclose(
                 np.asarray(rb.model["per-user"].w_stack),
                 np.asarray(rs.model["per-user"].w_stack), atol=2e-3)
-        assert fn_bat.fit_seconds > 0 and fn_bat.eval_seconds > 0
 
-    # end-to-end batched tuning: same fit count, search records gp time
+    # end-to-end batched tuning: same fit count
     config = GameConfig(
         task=TaskType.LOGISTIC_REGRESSION, num_outer_iterations=2,
         coordinates={
@@ -479,8 +478,8 @@ def test_batched_grid_tuning_matches_sequential(rng):
 
 
 def test_warmup_precompiles_grid_sizes(rng):
-    """warmup(grid_sizes=(q,)) must leave no recorded fits and zeroed phase
-    counters while having exercised both the single-fit and q-grid fused
+    """warmup(grid_sizes=(q,)) must leave no recorded fits
+    while having exercised both the single-fit and q-grid fused
     programs (the bench's batched gp_tune relies on this so no XLA compile
     lands inside its measured window)."""
     from photon_ml_tpu.core.regularization import Regularization
@@ -516,7 +515,6 @@ def test_warmup_precompiles_grid_sizes(rng):
     fn = GameEstimatorEvaluationFunction(est, config, tr, va, seed=0)
     fn.warmup(grid_sizes=(2,))
     assert fn.results == []
-    assert fn.fit_seconds == 0.0 and fn.eval_seconds == 0.0
     # the warmed function then drives a batched search normally
     best, search, tuned = tune_game_model(est, config, tr, va,
                                           n_iterations=4, mode="bayesian",
