@@ -287,6 +287,16 @@ def _storage_np_dtype(storage_dtype: Optional[str]):
     return np.dtype(storage_dtype)
 
 
+def _way_back_says(em, way_back) -> dict:
+    """What ``coord.rescore_layout`` / ``coord.external_layout`` record of
+    an entity-major layout's way back to sample order: ``back`` =
+    ``identity`` / ``unpad`` / ``gather`` and, un-padded, the ``stages`` and
+    ``slots`` of the compaction."""
+    if em.back != "unpad":
+        return dict(back=em.back)
+    return dict(back="unpad", stages=way_back.stages, slots=way_back.slots)
+
+
 @contextlib.contextmanager
 def _upload_span(coordinate_id: str, mesh: Optional[Mesh]):
     """``coord.upload``: a coordinate's arrays on their way to the device;
@@ -1105,8 +1115,9 @@ class RandomEffectCoordinate(Coordinate):
             # where the rows of an entity fill chunks, else transposed [d, n].
             # Sample-order layouts score by per-SAMPLE slots (``_slot_ids``:
             # the entity of each sample); the entity-major one by per-CHUNK
-            # slots (the entity of each chunk), its design and ``pos`` in the
-            # place of ``x_full`` and ``slots``, not beside them.
+            # slots (the entity of each chunk), its design and ``way_back``
+            # (to sample order: nothing, an un-pad or a position gather) in
+            # the place of ``x_full`` and ``slots``, not beside them.
             from photon_ml_tpu.parallel.bucketing import (EM_ROW,
                                                           entity_major_layout)
             self._em = None
@@ -1125,11 +1136,14 @@ class RandomEffectCoordinate(Coordinate):
                     # under a mesh the chunks are the sample order only
                     # where they are so shard for shard
                     self._em.pos = np.arange(self._n, dtype=np.int32)
+                way_back = None
                 if self._em is not None:
                     self._slot_ids = self._em.entities
+                    way_back = self._em.way_back(
+                        lane_multiple, self.carry_samples // lane_multiple)
                     layout_span.set(layout="entity_major", chunk=self._em.chunk,
                                     lanes=self._em.lanes, fill=self._em.fill,
-                                    identity=self._em.pos is None)
+                                    **_way_back_says(self._em, way_back))
                 else:
                     self._x_full_is_t = narrow
                     self._slot_ids = np.asarray(entity_ids, np.int64)
@@ -1157,13 +1171,21 @@ class RandomEffectCoordinate(Coordinate):
                         x_val=put(np.asarray(shard_data.values, dtype)))
                 elif self._em is not None:
                     # a streamed (device) shard is fetched: it is narrow
+                    # a gathered padding sample's position is the layout's
+                    # last, an un-padded one comes back 0 by ``live``
+                    if self._em.back == "gather":
+                        way_back = put(
+                            way_back, length=self.carry_samples,
+                            fill=self._em.lanes * self._em.chunk - 1)
+                    else:
+                        way_back = jax.tree.map(
+                            lambda a: put(a, a.ndim - 1,
+                                          length=a.shape[-1]), way_back)
                     self._full = dict(
                         lane_slot=slots,
                         x_em=entity_major_design_over(self._em,
                                                       np.asarray(x), mesh),
-                        pos=None if self._em.pos is None else put(
-                            self._em.pos, length=self.carry_samples,
-                            fill=self._em.lanes * self._em.chunk - 1))
+                        way_back=way_back)
                 else:
                     self._full = dict(slots=slots, x_full=put(
                         x.T, 1) if self._x_full_is_t else put(x))
@@ -1179,8 +1201,7 @@ class RandomEffectCoordinate(Coordinate):
                     lane_slot=slots,
                     x_em=entity_major_design(self._em,
                                              device_put_counted(x.T)),
-                    pos=None if self._em.pos is None
-                    else jnp.asarray(self._em.pos))
+                    way_back=jax.tree.map(jnp.asarray, way_back))
             else:
                 self._full = dict(slots=slots, x_full=device_put_counted(
                     x.T if self._x_full_is_t else x))
@@ -1958,10 +1979,10 @@ class RandomEffectCoordinate(Coordinate):
         if self._em is not None:
             if self.mesh is not None:
                 return score_entity_major(w_stack, data["lane_slot"],
-                                          data["x_em"], data["pos"],
+                                          data["x_em"], data["way_back"],
                                           self.mesh)
             return score_samples_em(w_stack, data["lane_slot"], data["x_em"],
-                                    data["pos"])
+                                    data["way_back"])
         if self._sparse:
             score, design = score_samples_sparse, (data["x_idx"],
                                                    data["x_val"])
@@ -2156,7 +2177,9 @@ class RandomEffectCoordinate(Coordinate):
         ``use_transposed_scoring``'s line row-major ``[n, d]``; over it the
         samples go on the lanes, entity-major where ``entity_major_chunk``
         finds a chunk length for the held-out rows of each entity, else
-        transposed ``[d, n]``.  Span ``coord.external_layout`` says which."""
+        transposed ``[d, n]``.  Span ``coord.external_layout`` says which,
+        and of the entity-major one how its scores come back to sample
+        order (``back``: ``EntityMajorLayout.back``)."""
         from photon_ml_tpu.parallel.bucketing import (entity_major_design,
                                                       entity_major_layout,
                                                       entity_runs,
@@ -2185,15 +2208,15 @@ class RandomEffectCoordinate(Coordinate):
                 sp.set(layout="transposed")
                 return {"slots": jnp.asarray(_slots_from(self._slot_of, ids)),
                         "x_t": x_t}
-            pos = em.pos
-            if pos is None and em.lanes * em.chunk != n:
-                pos = np.arange(n, dtype=np.int32)  # cut the tail's zeros
+            if em.pos is None and em.lanes * em.chunk != n:
+                em.pos = np.arange(n, dtype=np.int32)  # cut the tail's zeros
+            way_back = em.way_back()
             sp.set(layout="entity_major", chunk=em.chunk, lanes=em.lanes,
-                   fill=em.fill, identity=pos is None)
+                   fill=em.fill, **_way_back_says(em, way_back))
             return {"lane_slot": jnp.asarray(em.lane_slots(
                         _slots_from(self._slot_of, em.entities))),
                     "x_em": entity_major_design(em, x_t),
-                    "pos": None if pos is None else jnp.asarray(pos)}
+                    "way_back": jax.tree.map(jnp.asarray, way_back)}
 
     def trace_score_external(self, published: Array, vdata) -> Array:
         """== RandomEffectModel.score on the published stack, in whichever
@@ -2207,7 +2230,7 @@ class RandomEffectCoordinate(Coordinate):
 
         if "x_em" in vdata:
             return score_samples_em(published, vdata["lane_slot"],
-                                    vdata["x_em"], vdata["pos"])
+                                    vdata["x_em"], vdata["way_back"])
         if "x_t" in vdata:
             return score_samples_t(published, vdata["slots"], vdata["x_t"])
         if "x" in vdata:

@@ -22,7 +22,8 @@ handful of dense [E, S, d] batched programs on the MXU.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+import functools
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -634,6 +635,19 @@ NARROW_SCORE_DIM_MAX = 32  # the narrow layouts only ever help below this width
 #     per row and column.  The chip showed what that costs (PERF.md section 6,
 #     PR 23): the time is INDICES, 7 to 29 ns each, not bytes: d x 8.39M of
 #     them were 86.6% of glmix_chip's busy time and 49.7% of glmix3_wide's.
+# The entity-major scores come BACK to sample order one of three ways, read
+# off the layout the bucketer produced (``EntityMajorLayout.back``,
+# ``to_sample_order``; no flag):
+#   - ``identity``: the chunks ARE the sample order (rows grouped by entity,
+#     counts that fill whole chunks: glmix_chip): nothing.
+#   - ``unpad``: rows grouped by entity, counts that do not fill chunks
+#     (MovieLens' per-user rows, training and held-out).  ``pos`` rises and
+#     only skips each entity's tail padding, so an order-preserving
+#     compaction does it by data movement, no index a sample (``Unpad``,
+#     ``unpad``): 10.6 ms for 13.0M samples out of 16.0M slots on a v5e
+#     where ``acc[pos]`` took 97.5 (PERF.md section 6, PR 33).
+#   - ``gather``: rows that lie anywhere (per-item; shuffled ids):
+#     ``acc[pos]``, one index a sample, 6.6 to 8.6 ns each.
 NARROW_SCORE_PAD_BYTES_MIN = 1 << 30
 
 
@@ -759,13 +773,59 @@ class EntityMajorLayout:
     ``chunk_entity`` [k, R] int32: index into ``entities`` of each chunk's
     entity, -1 for nobody's.  ``pos`` [n] int32: flat position of sample i,
     or None when the layout IS the sample order (``pos == arange(n)``: no
-    padding but behind the last sample)."""
+    padding but behind the last sample).  ``grouped``: the rows arrived
+    grouped by entity (``entity_runs``' ``order is None``), so ``pos``
+    rises and only skips padding."""
 
     chunk: int
     entities: np.ndarray
     chunk_entity: np.ndarray
     pos: Optional[np.ndarray]
     num_samples: int
+    grouped: bool
+
+    @property
+    def back(self) -> str:
+        """The way back to sample order (the note above
+        ``use_transposed_scoring``): ``identity``, ``unpad`` or ``gather``."""
+        if self.pos is None:
+            return "identity"
+        return "unpad" if self.grouped else "gather"
+
+    def way_back(self, parts: int = 1, part_samples: Optional[int] = None
+                 ) -> "WayBack":
+        """What ``to_sample_order`` takes, its leaves on the host: None,
+        ``pos``, or the ``unpad`` way back.  Of that: one part brings the
+        whole stream to the ``num_samples`` samples.  Under a mesh part
+        ``c`` of ``parts`` brings back samples ``[c m, (c + 1) m)``, ``m =
+        part_samples`` (those from ``num_samples`` on are padding and come
+        back 0): ``pos`` rises, so they lie in ONE range of the stream,
+        which the part cuts out at its own row ``start`` (every part the
+        length of the longest) and compacts by its own stages.  Where no
+        part has a stage (``pos`` only cuts the tail) there is nothing to
+        pull and ``pull`` is empty."""
+        if self.back != "unpad":
+            return self.pos
+        total = self.lanes * self.chunk
+        if parts == 1:
+            pull, stages = pull_stages(self.pos, total)
+            return Unpad(pull if stages else pull[:0], None, None,
+                         self.num_samples, stages, total)
+        n, m = self.num_samples, part_samples
+        own = [self.pos[min(c * m, n): min((c + 1) * m, n)]
+               for c in range(parts)]
+        span = max(m, max(int(p[-1]) + 1 - int(p[0]) // EM_ROW * EM_ROW
+                          for p in own if len(p)))
+        span = -(-span // EM_ROW) * EM_ROW
+        start = np.asarray([min(int(p[0]) // EM_ROW if len(p) else 0,
+                                (total - span) // EM_ROW) for p in own],
+                           np.int32)
+        pulls, stages = zip(*(pull_stages(p - s * EM_ROW, span)
+                              for p, s in zip(own, start)))
+        pull = np.concatenate(pulls)
+        return Unpad(pull if max(stages) else pull[:0], start,
+                     np.asarray(list(map(len, own)), np.int32), m,
+                     max(stages), span)
 
     @property
     def lanes(self) -> int:
@@ -828,7 +888,107 @@ def entity_major_layout(runs: EntityRuns, row_multiple: int = 1
     return EntityMajorLayout(chunk=c, entities=entities,
                              chunk_entity=np.ascontiguousarray(
                                  ce.reshape(rows, k).T),
-                             pos=pos, num_samples=n)
+                             pos=pos, num_samples=n, grouped=order is None)
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["pull", "start", "live"],
+                   meta_fields=["num_samples", "stages", "slots"])
+@dataclasses.dataclass(frozen=True)
+class Unpad:
+    """The ``unpad`` way back: an order-preserving compaction of a stream of
+    slots whose live elements (the samples, in sample order) stand among
+    padding, as ``stages`` static shifts (``pull_stages``, ``unpad``).
+
+    ``pull`` int32 ``[slots]``: bit ``b`` at slot ``q`` says stage ``b``
+    pulls slot ``q + 2^b`` into ``q`` (one word for all stages: on a v5e a
+    uint8 plane a byte of stages costs its unpacking a second pass a stage,
+    19.4 ms for 10.6; PERF.md section 6, PR 33); empty where there is no
+    stage.  ``num_samples``: the samples that come back.  ``slots``: the
+    length of the stream compacted.  Under a mesh
+    (``EntityMajorLayout.way_back``) that stream is a chip's own range of
+    the whole one, and ``pull`` holds the chips' side by side, sharded over
+    them with ``start`` and ``live`` ``[chips]`` int32: the ROW of
+    ``EM_ROW`` slots at which a chip's range starts, and how many of its
+    ``num_samples`` samples are real (the rest come back exactly 0)."""
+
+    pull: Array
+    start: Optional[Array]
+    live: Optional[Array]
+    num_samples: int
+    stages: int
+    slots: int
+
+
+# None (identity), ``Unpad``, or ``pos`` [n] int32 (gather)
+WayBack = Union[None, Unpad, Array, np.ndarray]
+
+
+def pull_stages(pos: np.ndarray, slots: int) -> Tuple[np.ndarray, int]:
+    """``(Unpad.pull, stages)`` for live elements at the rising positions
+    ``pos`` [n] of a stream of ``slots``, bound for positions ``0 .. n - 1``.
+
+    Element ``i`` has ``shift = pos[i] - i`` to go left; the shifts never
+    fall along the stream and two live elements' differ by less than their
+    distance.  Stage ``b`` (least significant bit first) moves every element
+    whose shift has bit ``b`` left by ``2^b``: it lands on a slot whose own
+    element has left or is padding, never on a live one (Hacker's Delight's
+    ``compress``, on words).  After stage ``b`` an element stands at ``pos -
+    shift % 2^(b + 1)``; a RUN of elements with one shift (an entity's rows)
+    moves as one interval, so a stage's pulls are painted run by run."""
+    n = len(pos)
+    shift = np.asarray(pos, np.int32) - np.arange(n, dtype=np.int32)
+    heads = np.flatnonzero(np.r_[True, shift[1:] != shift[:-1]][:n])
+    run_shift = shift[heads].astype(np.int64)
+    if np.any(np.diff(run_shift) < 0) or (n and run_shift[0] < 0):
+        raise ValueError("un-pad: positions that do not rise with the samples")
+    run_pos = np.asarray(pos)[heads].astype(np.int64)
+    run_len = np.diff(np.r_[heads, n])
+    stages = int(run_shift[-1]).bit_length() if n else 0
+    pull = np.zeros(slots, "<i4")
+    planes = pull.view(np.uint8).reshape(slots, 4)  # byte g: stages 8g..8g+7
+    for b in range(stages):
+        moved = np.flatnonzero((run_shift >> b) & 1)
+        lo = run_pos[moved] - (run_shift[moved] & ((2 << b) - 1))
+        edges = np.empty(2 * len(moved) + 2, np.int64)
+        edges[0], edges[-1] = 0, slots
+        edges[1:-1:2], edges[2:-1:2] = lo, lo + run_len[moved]
+        bit = np.zeros(len(edges) - 1, np.uint8)
+        bit[1::2] = 1 << (b % 8)
+        planes[:, b // 8] |= np.repeat(bit, np.diff(edges))
+    return pull, stages
+
+
+def unpad(acc: Array, back: Unpad) -> Array:
+    """The samples out of the flat entity-major stream ``acc``, in sample
+    order, bitwise ``acc[pos]``: copies, no arithmetic touches a score and
+    no index a sample.  A stage is one pass over the slots (two reads of
+    the stream, one of the pulls, one write: 0.48 ms over 16.0M slots on a
+    v5e; PERF.md section 6, PR 33).  With ``back.start`` (a chip's, under
+    ``shard_map``) ``acc`` is the WHOLE stream and the chip first cuts its
+    own range out of it."""
+    if back.start is not None:
+        acc = jax.lax.dynamic_slice_in_dim(
+            acc.reshape(-1, EM_ROW), back.start[0], back.slots // EM_ROW
+        ).reshape(-1)
+    for b in range(back.stages):
+        acc = jnp.where((back.pull >> b) & 1 == 1,
+                        jnp.roll(acc, -(1 << b)), acc)
+    out = acc[:back.num_samples]
+    if back.live is not None:
+        out = jnp.where(jnp.arange(back.num_samples) < back.live[0], out, 0)
+    return out
+
+
+def to_sample_order(acc: Array, way_back: WayBack) -> Array:
+    """Flat entity-major scores back in sample order, by the way the
+    layout says (``EntityMajorLayout.back``): as they are, un-padded, or
+    gathered at one index a sample."""
+    if way_back is None:
+        return acc
+    if isinstance(way_back, Unpad):
+        return unpad(acc, way_back)
+    return acc[way_back]
 
 
 # photonlint: disable=sharding-annotation -- set-up, on one device: the
@@ -880,18 +1040,20 @@ def entity_major_design(layout: EntityMajorLayout, x_t: Array) -> Array:
 
 
 def score_samples_em(w_stack: Array, lane_slot: Array, x_em: Array,
-                     pos: Optional[Array] = None) -> Array:
+                     way_back: WayBack = None) -> Array:
     """``score_samples`` for an ENTITY-MAJOR design (``EntityMajorLayout``):
     ``x_em`` [d, R, EM_ROW], ``lane_slot`` [k, R] the stacked-model row of
-    each chunk (-1: no model, its samples score exactly 0), ``pos`` the flat
-    position of each sample or None where the layout is the sample order
-    (the result is then [R * EM_ROW]: the samples, then the tail's zeros).
+    each chunk (-1: no model, its samples score exactly 0), ``way_back`` the
+    layout's way back to sample order (``to_sample_order``): None where the
+    layout is the sample order (the result is then [R * EM_ROW]: the
+    samples, then the tail's zeros).
 
     The same d products a sample as ``score_samples_t``, summed in the same
     order in the same promoted dtype; but a coefficient is gathered once per
     CHUNK (k x R = n / C indices a column, not n) and spread along the chunk
-    in registers, and where the samples do not arrive entity-major ONE
-    n-sized gather puts the finished scores back into sample order.
+    in registers; the finished scores go back into sample order by an
+    un-pad where the rows arrive grouped by entity, by ONE n-sized gather
+    where they lie anywhere.
     """
     k, _ = lane_slot.shape
     has = lane_slot >= 0
@@ -911,7 +1073,7 @@ def score_samples_em(w_stack: Array, lane_slot: Array, x_em: Array,
     for j in range(x_em.shape[0]):  # d is static and small by contract
         acc = acc + x_em[j] * along_chunks(w_t[j][safe])
     acc = jnp.where(along_chunks(has), acc, 0.0).reshape(-1)
-    return acc if pos is None else acc[pos]
+    return to_sample_order(acc, way_back)
 
 
 def score_samples_sparse(w_stack: Array, slots: Array, indices: Array,

@@ -35,6 +35,15 @@ reference's shuffle by entity (RandomEffectCoordinate.scala:104-231) as
 three exchanges of ``[n]`` vectors and tables.  No design array crosses a
 chip.  Each runs under ``device_scope("exchange", <kind>)``, and
 ``exchange_bytes`` says what a chip sends for each.
+
+How the scores come back, by what the layout observed
+(``EntityMajorLayout.back``): ``identity`` (the chunks are the sample order
+shard for shard): nothing crosses, no ``scores`` exchange.  ``unpad`` (rows
+grouped by entity): ONE all-gather, then each chip cuts the one contiguous
+range that holds its samples out of the whole vector and compacts it, data
+movement with no index a sample.  ``gather`` (rows that lie anywhere): ONE
+all-gather, then each chip gathers its samples' positions, one index each,
+out of the whole vector.
 """
 
 from __future__ import annotations
@@ -316,31 +325,39 @@ def stack_lanes(lane_ws: Sequence[jax.Array], slot_idx: Sequence[jax.Array],
 
 
 def score_entity_major(w_stack: jax.Array, lane_slot: jax.Array,
-                       x_em: jax.Array, pos: Optional[jax.Array],
-                       mesh: Mesh) -> jax.Array:
+                       x_em: jax.Array, way_back, mesh: Mesh) -> jax.Array:
     """``bucketing.score_samples_em`` with the chunk rows on their chips,
     and exchange ``scores``: each chip scores its own rows of chunks from
     the replicated table, ONE all-gather makes the entity-major scores
-    whole on every chip, and each chip gathers its own samples' positions
-    out of them.  ``pos`` None: the chunks ARE the sample order, shard for
+    whole on every chip, and each chip takes its own samples out of them by
+    the layout's way back (``bucketing.to_sample_order``; ``way_back`` with
+    its sample axis over the chips): un-padded out of the ONE range of the
+    whole vector in which the chip's samples lie where the rows arrive
+    grouped by entity (``EntityMajorLayout.way_back``: a ``dynamic_slice`` at
+    the chip's own start, then copies), else gathered at one index a
+    sample.  ``way_back`` None: the chunks ARE the sample order, shard for
     shard, and nothing crosses."""
-    from photon_ml_tpu.parallel.bucketing import score_samples_em
+    from photon_ml_tpu.parallel.bucketing import (score_samples_em,
+                                                  to_sample_order)
 
     axes = tuple(mesh.axis_names)
 
-    def local(w, slots, x, p=None):
+    def local(w, slots, x, back=None):
         acc = score_samples_em(w, slots, x)
-        if p is None:
+        if back is None:
             return acc
         with device_scope("exchange", "scores"):
-            return jax.lax.all_gather(acc, axes, tiled=True)[p]
+            return to_sample_order(
+                jax.lax.all_gather(acc, axes, tiled=True), back)
 
     specs = (P(), over_chips(mesh, 2, 1), over_chips(mesh, 3, 1))
-    if pos is None:
+    if way_back is None:
         return on_chips(local, mesh, specs, over_chips(mesh))(
             w_stack, lane_slot, x_em)
-    return on_chips(local, mesh, specs + (over_chips(mesh),),
-                    over_chips(mesh))(w_stack, lane_slot, x_em, pos)
+    by_sample = jax.tree.map(lambda a: over_chips(mesh, a.ndim, a.ndim - 1),
+                             way_back)
+    return on_chips(local, mesh, specs + (by_sample,), over_chips(mesh))(
+        w_stack, lane_slot, x_em, way_back)
 
 
 def score_in_sample_order(score: Callable, w_stack: jax.Array, mesh: Mesh,

@@ -7,6 +7,7 @@ layouts through the grouping core and checks determinism, permutation
 stability, cap/rescale accounting, and dense/sparse bucketer agreement.
 """
 
+import dataclasses
 import os
 import sys
 
@@ -260,6 +261,150 @@ def test_entity_major_chunk_rule(counts, chunk):
     ids = np.repeat(np.arange(len(counts), dtype=np.int64), counts)
     layout = bucketing.entity_major_layout(bucketing.entity_runs(ids))
     assert (layout is None) == (chunk is None)
+
+
+# -- the way back to sample order (ISSUE 33) ----------------------------------
+
+def _heavy_tail(rng):
+    return np.maximum(1, rng.lognormal(3.6, 1.3, 400).astype(np.int64))
+
+
+# name -> (rows of each entity, the chunk the rule finds, the un-pad's
+# stages: the bits of the padding in front of the last entity; None: not
+# pinned).  Rows of 0.8 C to C an entity force the chunk C.
+_GROUPED = {
+    "ragged": (lambda rng: rng.integers(40, 300, size=200), None, None),
+    "heavy_tail": (_heavy_tail, None, None),
+    "an_entity_of_one_row": (
+        lambda rng: np.r_[rng.integers(40, 90, size=30), 1,
+                          rng.integers(40, 90, size=30)], None, None),
+    "one_row_each": (lambda rng: np.r_[np.ones(300, np.int64), 9000], 8,
+                     None),
+    "n_not_a_multiple_of_128": (lambda rng: np.r_[np.full(37, 50), 13], None,
+                                None),
+    "padding_127": (lambda rng: np.full(128, 7), 8, 7),
+    "padding_128": (lambda rng: np.full(129, 7), 8, 8),
+    "padding_32767": (lambda rng: np.full(32768, 7), 8, 15),
+    "padding_32768": (lambda rng: np.full(32769, 7), 8, 16),
+    **{f"chunk_{c}": (lambda rng, c=c: rng.integers(c - c // 5, c + 1,
+                                                    size=150), c, None)
+       for c in (8, 16, 32, 64, 128)},
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_GROUPED))
+def test_unpad_is_bitwise_the_position_gather(case, dtype):
+    """Rows grouped by entity whose counts do not fill chunks: the scores
+    come back to sample order by the un-pad, BITWISE ``acc[pos]``, with no
+    gather in the lowered program."""
+    import jax
+    import jax.numpy as jnp
+
+    make, chunk, stages = _GROUPED[case]
+    rng = np.random.default_rng(33)
+    counts = np.asarray(make(rng), np.int64)
+    ids = np.repeat(np.arange(len(counts), dtype=np.int64) * 3 - 5, counts)
+    layout = bucketing.entity_major_layout(bucketing.entity_runs(ids))
+    assert layout.grouped and layout.back == "unpad"
+    assert chunk is None or layout.chunk == chunk
+    back = layout.way_back()
+    assert isinstance(back, bucketing.Unpad)
+    assert back.num_samples == len(ids)
+    assert back.slots == layout.lanes * layout.chunk
+    assert stages is None or back.stages == stages
+    assert back.pull.dtype == np.int32 and back.pull.shape == (back.slots,)
+    assert int(back.pull.max(initial=0)).bit_length() <= back.stages
+
+    acc = jnp.asarray(rng.normal(size=back.slots), dtype)
+    fn = jax.jit(bucketing.to_sample_order)
+    back = jax.tree.map(jnp.asarray, back)
+    got = np.asarray(fn(acc, back))
+    want = np.asarray(acc[jnp.asarray(layout.pos)])
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    assert "gather" not in fn.lower(acc, back).as_text()
+
+
+@pytest.mark.parametrize("counts", [np.full(40, 64), np.full(7, 128),
+                                    np.full(100, 16), np.r_[np.full(5, 256),
+                                                            100]],
+                         ids=["64", "128", "16", "last_one_short"])
+def test_counts_that_fill_chunks_need_no_way_back(counts):
+    """The chunks ARE the sample order: identity, the helper does not
+    engage and the scores are handed on as they are."""
+    ids = np.repeat(np.arange(len(counts), dtype=np.int64), counts)
+    layout = bucketing.entity_major_layout(bucketing.entity_runs(ids))
+    assert layout.grouped and layout.pos is None
+    assert layout.back == "identity" and layout.way_back() is None
+    acc = np.arange(layout.lanes * layout.chunk, dtype=np.float32)
+    assert bucketing.to_sample_order(acc, layout.way_back()) is acc
+
+
+@pytest.mark.parametrize("parts", [1, 4])
+@pytest.mark.parametrize("counts", [np.r_[np.full(5, 256), 100],
+                                    np.full(37, 64)],
+                         ids=["last_one_short", "tail_of_the_last_row"])
+def test_cutting_the_tail_pulls_nothing(counts, parts):
+    """Chunks that are the sample order but for the zeros behind the last
+    sample (``pos == arange(n)``, as ``external_data`` and a mesh whose
+    shards are not the chunks' say it): an un-pad of no stage, which reads
+    no ``pull`` and uploads none; a part only cuts its own range."""
+    import jax
+    import jax.numpy as jnp
+
+    ids = np.repeat(np.arange(len(counts), dtype=np.int64), counts)
+    n = len(ids)
+    layout = bucketing.entity_major_layout(bucketing.entity_runs(ids), parts)
+    slots = layout.lanes * layout.chunk
+    assert layout.pos is None and slots > n
+    layout.pos = np.arange(n, dtype=np.int32)
+    back = layout.way_back(parts, slots // parts)
+    assert layout.back == "unpad" and back.stages == 0
+    assert back.pull.shape == (0,)
+    assert back.num_samples == (n if parts == 1 else slots // parts)
+    acc = jnp.arange(1, slots + 1, dtype=jnp.float32)
+    want = np.where(np.arange(slots) < n, np.asarray(acc), 0)
+    fn = jax.jit(bucketing.to_sample_order)
+    got = []
+    for c in range(parts):
+        part = back if parts == 1 else dataclasses.replace(
+            back, start=back.start[c:c + 1], live=back.live[c:c + 1])
+        part = jax.tree.map(jnp.asarray, part)
+        got.append(np.asarray(fn(acc, part)))
+        text = fn.lower(acc, part).as_text()
+        assert "gather" not in text and "concatenate" not in text  # no roll
+    np.testing.assert_array_equal(np.concatenate(got),
+                                  want[:parts * back.num_samples])
+
+
+@pytest.mark.parametrize("order", ["shuffled", "descending", "interleaved"])
+def test_rows_not_grouped_take_the_position_gather(order):
+    """Rows that lie anywhere keep ``acc[pos]``: ``way_back`` is ``pos``."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(34)
+    counts = rng.integers(40, 300, size=60)
+    ids = np.repeat(np.arange(len(counts), dtype=np.int64), counts)
+    ids = {"shuffled": rng.permutation,
+           "descending": lambda v: v[::-1].copy(),
+           "interleaved": lambda v: np.r_[v[::2], v[1::2]]}[order](ids)
+    layout = bucketing.entity_major_layout(bucketing.entity_runs(ids))
+    assert not layout.grouped and layout.back == "gather"
+    assert layout.way_back() is layout.pos
+    acc = jnp.asarray(rng.normal(size=layout.lanes * layout.chunk),
+                      jnp.float32)
+    fn = jax.jit(bucketing.to_sample_order)
+    pos = jnp.asarray(layout.pos)
+    np.testing.assert_array_equal(np.asarray(fn(acc, pos)),
+                                  np.asarray(acc)[layout.pos])
+    assert "gather" in fn.lower(acc, pos).as_text()
+
+
+def test_pull_stages_refuses_positions_that_do_not_rise():
+    with pytest.raises(ValueError, match="do not rise"):
+        bucketing.pull_stages(np.asarray([0, 5, 3, 9], np.int32), 16)
 
 
 @settings(max_examples=40, deadline=None)

@@ -298,6 +298,18 @@ def test_glmix_ml25m_exchanges_compile_for_four_chips(topo, compiled_text):
         assert "photon.exchange." in table[name] or (
             kind.startswith("collective-permute")
             and "photon.update.fixed" in table[name]), (name, table[name])
+    # the way back to sample order inside the ``scores`` exchange (ISSUE
+    # 33): per-user rows arrive grouped by user and are un-padded out of a
+    # dynamic slice of the whole vector, no gather; a movie's rows lie
+    # anywhere and keep one gathered index a sample
+    assert [c._em.back for c in list(coords.values())[1:]] == ["unpad",
+                                                               "gather"]
+    for cid, gathered in (("per_user", False), ("per_item", True)):
+        scoped = [line for line in text.splitlines()
+                  if f"photon.update.{cid}/" in line
+                  and "photon.exchange.scores" in line]
+        assert any(" gather(" in line for line in scoped) == gathered, cid
+        assert any("dynamic-slice(" in line for line in scoped) != gathered
     n_pad = sweep._base.shape[0]
     assert n_pad % (4 * 1024) == 0 and n_pad - 9217 < 4 * 1024
     # what a device must hold is a quarter of the arguments, not all

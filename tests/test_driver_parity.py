@@ -117,8 +117,8 @@ def test_fused_sweep_matches_host_descent(rng, monkeypatch, loss, side, layout):
     re = coords["per-user"]
     assert len(spans) == 1 and spans[0]["layout"] == layout.removesuffix("_pos")
     if spans[0]["layout"] == "entity_major":
-        assert spans[0]["identity"] == (layout == "entity_major")
-        assert (re._full["pos"] is None) == (layout == "entity_major")
+        assert (spans[0]["back"] == "identity") == (layout == "entity_major")
+        assert (re._full["way_back"] is None) == (layout == "entity_major")
     assert re._use_soa == (side == "soa")
     assert max(b.capacity for b in re.buckets.buckets) == SIDES[side][1]
 

@@ -499,6 +499,53 @@ def test_lanes_by_run_under_the_mesh(on_mesh):
     assert len(crossing) == 2 and all("all-gather" in c for c in crossing)
 
 
+def test_scores_come_back_by_unpad_under_the_mesh(on_mesh):
+    """ISSUE 33: per-user rows arrive grouped by user, so each chip cuts
+    the one range of the all-gathered entity-major scores that holds its
+    samples and un-pads it: bitwise the one-device ``acc[pos]``, the padded
+    sample axis exactly 0, no gather of one index a sample in the exchange;
+    a movie's rows lie anywhere and keep the position gather."""
+    data, coords, _, text, *_ = on_mesh
+    n = len(data["y"])
+    alone = build(data, "entity_major", None, dtype=np.float32)
+    rng = np.random.default_rng(33)
+    for cid, back in (("per-user", "unpad"), ("per-item", "gather")):
+        coord, one = coords[cid], alone[cid]
+        assert coord._em.back == one._em.back == back
+        assert coord.carry_samples > n  # the sample axis is padded
+        way = coord._full["way_back"]
+        assert isinstance(way, bucketing.Unpad) == (back == "unpad")
+        if back == "unpad":
+            assert isinstance(one._full["way_back"], bucketing.Unpad)
+            assert way.num_samples == coord.carry_samples // CHIPS
+            assert way.start.shape == way.live.shape == (CHIPS,)
+            assert int(np.asarray(way.live).sum()) == n
+            assert way.pull.sharding.shard_shape(way.pull.shape) == (
+                way.slots,)
+            assert way.slots < coord._em.lanes * coord._em.chunk
+        w = jnp.asarray(rng.normal(size=(len(coord._slot_of), coord.dim)),
+                        jnp.float32)
+        got = np.asarray(coord._score_samples_full(w, coord._full))
+        want = np.asarray(bucketing.score_samples_em(
+            w, one._full["lane_slot"], one._full["x_em"],
+            jnp.asarray(one._em.pos)))  # the position gather, one device
+        assert got.shape == (coord.carry_samples,) and want.shape == (n,)
+        np.testing.assert_array_equal(got[:n].view(np.uint32),
+                                      want.view(np.uint32))
+        assert (got[n:] == 0).all() and np.abs(want).min() > 0
+        np.testing.assert_array_equal(
+            np.asarray(one._score_samples_full(w, one._full)), want)
+        update = "photon.update." + cid.replace("-", "_")
+        scoped = [line for line in text.splitlines()
+                  if update + "/" in line and "photon.exchange.scores" in line]
+        gathers = [line for line in scoped if re.search(r" gather\(", line)]
+        assert bool(gathers) == (back == "gather"), gathers
+        assert any("dynamic-slice(" in line for line in scoped) == (
+            back == "unpad")
+        assert any("all-gather" in line and COLLECTIVE.match(line)
+                   for line in scoped)  # the exchange's one collective
+
+
 # -- (c) tracing ---------------------------------------------------------------
 
 def test_exchange_scopes_are_in_the_op_table(on_mesh):

@@ -375,7 +375,7 @@ def test_entity_major_rescore_matches_host_descent(rng, monkeypatch,
     assert user._em is not None and user._em.chunk == 32
     assert (user._em.pos is None) == (not shuffled)
     # in the place of the sample-order design and slots, not beside them
-    assert sorted(user._full) == ["lane_slot", "pos", "x_em"]
+    assert sorted(user._full) == ["lane_slot", "way_back", "x_em"]
     assert sorted(row_major["user"]._full) == ["slots", "x_full"]
 
     fused, fused_scores = FusedSweep(coords, num_iterations=2).run()
@@ -462,17 +462,24 @@ def test_entity_major_carry_through_scores(rng, monkeypatch):
     assert (got[uids != rare] == 0).all()
 
 
-@pytest.mark.parametrize("line, per_user, layout", [
-    (1, 32, dict(layout="entity_major", chunk=32, lanes=12, fill=1.0,
-                 identity=True)),
-    (1, 10, dict(layout="transposed")),  # 10 rows a user: no chunk
-    (None, 32, dict(layout="row_major")),
-])
+@pytest.mark.parametrize("line, per_user, shuffled, layout", [
+    (1, 32, False, dict(layout="entity_major", chunk=32, lanes=12, fill=1.0,
+                        back="identity")),
+    # 30 rows a user in chunks of 32: grouped rows, two slots of padding
+    # behind each user, 22 in front of the last: five stages
+    (1, 30, False, dict(layout="entity_major", chunk=32, lanes=12,
+                        fill=0.9375, back="unpad", stages=5, slots=384)),
+    (1, 32, True, dict(layout="entity_major", chunk=32, lanes=12, fill=1.0,
+                       back="gather")),
+    (1, 10, False, dict(layout="transposed")),  # 10 rows a user: no chunk
+    (None, 32, False, dict(layout="row_major")),
+], ids=["identity", "unpad", "gather", "transposed", "row_major"])
 def test_rescore_layout_span_says_what_engaged(rng, monkeypatch, line,
-                                               per_user, layout):
+                                               per_user, shuffled, layout):
     """``coord.rescore_layout`` (inside ``coord.bucket``): which of the
     three dense layouts the coordinate took, and the entity-major one's
-    chunk, lanes, fill and whether it is the sample order."""
+    chunk, lanes, fill, and the way BACK to the sample order (``back``;
+    un-padded, the compaction's stages and slots)."""
     from photon_ml_tpu import obs
     from photon_ml_tpu.game import GameData, RandomEffectConfig
     from photon_ml_tpu.game.coordinate import build_coordinate
@@ -483,9 +490,11 @@ def test_rescore_layout_span_says_what_engaged(rng, monkeypatch, line,
     if line is not None:
         monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", line)
     n = 12 * per_user
+    uids = np.repeat(np.arange(12), per_user)
     data = GameData(y=(rng.random(n) < 0.5).astype(float),
                     features={"u": rng.normal(size=(n, 3))},
-                    id_tags={"userId": np.repeat(np.arange(12), per_user)})
+                    id_tags={"userId": rng.permutation(uids) if shuffled
+                             else uids})
     prev = set_tracer(Tracer(capacity=256, enabled=True))
     try:
         coord = build_coordinate(
@@ -501,6 +510,10 @@ def test_rescore_layout_span_says_what_engaged(rng, monkeypatch, line,
                                   if r["name"] == "coord.bucket"}
     assert (coord._em is not None) == (layout["layout"] == "entity_major")
     assert coord._x_full_is_t == (layout["layout"] == "transposed")
+    if coord._em is not None:
+        assert coord._em.back == layout["back"]
+        assert isinstance(coord._full["way_back"], bucketing.Unpad) == (
+            layout["back"] == "unpad")
 
 
 def test_scoring_unknown_entity_is_zero(rng):

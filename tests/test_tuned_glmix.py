@@ -199,6 +199,13 @@ def test_validated_trial_equals_the_plain_reference(rng, monkeypatch, layout):
     chosen = {r["attrs"]["coordinate"]: r["attrs"]["layout"] for r in spans
               if r["name"] == "coord.external_layout"}
     assert chosen == {"per-user": layout, "per-item": layout}
+    # ragged held-out rows grouped by user come back by the un-pad, an
+    # item's rows lie anywhere and keep the position gather
+    back = {r["attrs"]["coordinate"]: r["attrs"].get("back") for r in spans
+            if r["name"] == "coord.external_layout"}
+    assert back == ({"per-user": "unpad", "per-item": "gather"}
+                    if layout == "entity_major"
+                    else {"per-user": None, "per-item": None})
     effects = [(held.features[shard], *_table(model[cid]), held.id_tags[tag])
                for cid, shard, tag in (("per-user", "u", "userId"),
                                        ("per-item", "i", "itemId"))]
@@ -223,6 +230,49 @@ def test_validated_trial_equals_the_plain_reference(rng, monkeypatch, layout):
     assert {r["name"] for r in spans} >= {
         "descent.fused_validated", "validate.evaluate", "validate.export",
         "validate.plan"}
+
+
+@pytest.mark.parametrize("back, per_user, shuffled, more", [
+    ("identity", 16, False, {}),
+    ("unpad", 14, False, dict(stages=4, slots=128)),  # 2 x 7 slots in front
+    ("gather", 16, True, {}),
+])
+def test_external_layout_span_says_the_way_back(rng, monkeypatch, back,
+                                                per_user, shuffled, more):
+    """``coord.external_layout`` of an entity-major held-out design says how
+    its scores come back to sample order, and ``trace_score_external``
+    scores every row as the plain gather-and-dot does."""
+    monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 0)
+    train, _ = _sets(rng)
+    _, coords = _coordinates(train)
+    coord = coords["per-user"]
+    uid = np.repeat(np.arange(8) * 2 + 7, per_user)  # user 7: never trained
+    if shuffled:
+        uid = rng.permutation(uid)
+    n = len(uid)
+    held = GameData(y=np.zeros(n), features={
+        "g": rng.normal(size=(n, 6)), "u": rng.normal(size=(n, 3)),
+        "i": rng.normal(size=(n, 3))},
+        id_tags={"userId": uid, "itemId": np.zeros(n, np.int64)})
+    set_tracer(Tracer(enabled=True))
+    try:
+        vdata = coord.external_data(held)
+        (span,) = [r["attrs"] for r in obs.get_tracer().records()
+                   if r["name"] == "coord.external_layout"]
+    finally:
+        set_tracer(Tracer())
+    assert span == dict(coordinate="per-user", rows=n, layout="entity_major",
+                        chunk=16, lanes=8, fill=per_user / 16, back=back,
+                        **more)
+    assert isinstance(vdata["way_back"], bucketing.Unpad) == (back == "unpad")
+    w = jnp.asarray(rng.normal(size=(len(coord._slot_of), 3)), coord._dtype)
+    got = np.asarray(coord.trace_score_external(w, vdata))
+    slots = np.asarray([coord._slot_of.get(int(u), -1) for u in uid])
+    want = np.where(slots >= 0, np.einsum(
+        "nd,nd->n", held.features["u"], np.asarray(w)[slots]), 0.0)
+    assert got.shape == (n,)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert (got[uid == 7] == 0).all()
 
 
 def test_validated_program_records_its_table_and_scopes(rng):
