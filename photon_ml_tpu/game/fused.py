@@ -55,6 +55,26 @@ def _take_iteration(pubs, t):
     return tuple(p[t] for p in pubs)
 
 
+# span attribute -> field of an executable's ``memory_analysis()``
+_MEMORY_FIELDS = {"argument_bytes": "argument_size_in_bytes",
+                  "output_bytes": "output_size_in_bytes",
+                  "alias_bytes": "alias_size_in_bytes",
+                  "temp_bytes": "temp_size_in_bytes",
+                  "code_bytes": "generated_code_size_in_bytes"}
+
+
+def _memory_account(compiled) -> Dict[str, int]:
+    """What ``compiled`` says it needs on ONE device, by ``_MEMORY_FIELDS``'
+    names; ``{}`` on a backend that keeps no such count (absent, not 0)."""
+    try:
+        stats = compiled.memory_analysis()
+    except (NotImplementedError, RuntimeError):  # a backend without one
+        return {}
+    return {name: int(getattr(stats, field))
+            for name, field in _MEMORY_FIELDS.items()
+            if getattr(stats, field, None) is not None}
+
+
 class FusedSweep:
     """jit(scan)-compiled block coordinate descent over GAME coordinates.
 
@@ -332,11 +352,20 @@ class FusedSweep:
         second compile or load.  Its persistent-cache key holds the
         metadata (the scopes are this tree's, whoever filled the cache):
         the first traced run in a cache compiles the program once more,
-        later ones load it.  Returns the executable's text."""
-        with obs_span("descent.device_table", program=name):
+        later ones load it.  The span carries the executable's own account:
+        ``instructions`` (the table's length), ``hlo_bytes`` (its text's)
+        and, where the backend gives a ``memory_analysis()``, a device's
+        ``argument_bytes``, ``output_bytes``, ``alias_bytes``,
+        ``temp_bytes`` and ``code_bytes``.  Returns the executable's
+        text."""
+        with obs_span("descent.device_table", program=name) as sp:
             with metadata_keyed_compile_cache():
-                text = program.lower(*args).compile().as_text()
-            get_tracer().record_device_table(name, hlo_op_table(text))
+                compiled = program.lower(*args).compile()
+            text = compiled.as_text()
+            table = hlo_op_table(text)
+            get_tracer().record_device_table(name, table)
+            sp.set(instructions=len(table), hlo_bytes=len(text),
+                   **_memory_account(compiled))
         return text
 
     def run(self, initial: Optional[GameModel] = None,

@@ -16,8 +16,11 @@ shared by training AND serving:
     fixed-bin latency histograms, label support — with Prometheus text
     exposition and JSON snapshots (``serving.ServingMetrics`` is a facade
     over it);
-  - ``probe``: ``JaxRuntimeProbe`` counting XLA compiles per call site and
-    host<->device transfer bytes at the chunked-upload path;
+  - ``probe``: ``JaxRuntimeProbe`` counting host<->device transfer bytes at
+    the chunked-upload path and XLA compiles: the serving engine's per call
+    site, always; while tracing is on every program JAX builds, by name,
+    as ``jax.trace`` / ``jax.lower`` / ``jax.compile`` spans (cache hit or
+    miss) off ``jax.monitoring``;
   - ``watch``: the fleet-global plane (photonwatch) — metrics federation
     (``DeltaExporter``/``FleetView``) and multi-window SLO burn-rate
     alerting.
@@ -38,7 +41,9 @@ from photon_ml_tpu.obs.trace import (Tracer, enabled, get_tracer,  # noqa: F401
 
 
 def enable_tracing(capacity: int = None) -> Tracer:
-    """Turn the default tracer on (optionally resized); returns it."""
+    """Turn the default tracer on (optionally resized); returns it.  From
+    here on the probe listens to JAX (``JaxRuntimeProbe.listen``)."""
+    get_probe().listen()
     t = get_tracer()
     if capacity is not None and capacity != t.capacity:
         t = Tracer(capacity=capacity, enabled=True)

@@ -114,3 +114,26 @@ def rng():
     import numpy as np
 
     return np.random.default_rng(20260729)
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """JAX's persistent compile cache in a fresh directory, every program
+    cached; the process's settings restored afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    flags = {"jax_compilation_cache_dir": str(tmp_path / "xla"),
+             "jax_persistent_cache_min_compile_time_secs": 0.0,
+             "jax_persistent_cache_min_entry_size_bytes": -1,
+             "jax_enable_compilation_cache": True}
+    before = {k: getattr(jax.config, k) for k in flags}
+    for k, v in flags.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()

@@ -145,6 +145,8 @@ def new_sweep(cells, config):
 
 @contextlib.contextmanager
 def tracing(enabled=True):
+    if enabled:  # as obs.enable_tracing does: the probe listens to JAX
+        obs.get_probe().listen()
     prev = set_tracer(Tracer(capacity=4096, enabled=enabled))
     try:
         yield obs.get_tracer()
@@ -182,6 +184,30 @@ def test_traced_sweep_records_its_table(cells, config):
     names = [r["name"] for r in records]
     assert names.count("descent.device_table") == 1
     assert names.count("descent.dispatch") == 2
+    # the executable's own account on the span, and what JAX did to build
+    # the main program under it (ISSUE 34): one span a phase, by name
+    span = next(r for r in records if r["name"] == "descent.device_table")
+    assert span["attrs"]["program"] == "jit_program"
+    assert span["attrs"]["instructions"] == len(table)
+    assert span["attrs"]["hlo_bytes"] > 1000
+    args, _ = sweep._program_args(None, None, 0, None)
+    stats = sweep._program.lower(*args).compile().memory_analysis()
+    if stats is None:  # a backend with no count: absent, not zero
+        assert "temp_bytes" not in span["attrs"]
+    else:
+        assert span["attrs"]["temp_bytes"] == stats.temp_size_in_bytes
+        assert span["attrs"]["argument_bytes"] == stats.argument_size_in_bytes
+    built = {r["name"]: r for r in records
+             if r["parent"] == span["id"]
+             and r["attrs"].get("program") in ("program", "jit(program)")}
+    assert set(built) == {"jax.trace", "jax.lower", "jax.compile"}
+    assert built["jax.compile"]["attrs"]["cache"] in ("hit", "miss", "off")
+    assert all(span["ts_ns"] <= r["ts_ns"] and r["ts_ns"] + r["dur_ns"]
+               <= span["ts_ns"] + span["dur_ns"] for r in built.values())
+    # the dispatches that follow build nothing: jit's own caches serve them
+    dispatched = {r["id"] for r in records if r["name"] == "descent.dispatch"}
+    assert not [r for r in records if r["parent"] in dispatched
+                and r["name"] in ("jax.lower", "jax.compile")]
 
 
 # -- (ii-b) the entity-major rescore: one gather a chunk, one a sample ----------
@@ -607,28 +633,6 @@ def test_traced_dry_run_of_the_tuning_cell(tmp_path):
 
 
 # -- (viii) a cache another tree filled ---------------------------------------
-
-@pytest.fixture
-def persistent_cache(tmp_path):
-    """JAX's persistent compile cache in a fresh directory, every program
-    cached; the process's settings restored afterwards."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    flags = {"jax_compilation_cache_dir": str(tmp_path / "xla"),
-             "jax_persistent_cache_min_compile_time_secs": 0.0,
-             "jax_persistent_cache_min_entry_size_bytes": -1,
-             "jax_enable_compilation_cache": True}
-    before = {k: getattr(jax.config, k) for k in flags}
-    for k, v in flags.items():
-        jax.config.update(k, v)
-    compilation_cache.reset_cache()
-    try:
-        yield
-    finally:
-        for k, v in before.items():
-            jax.config.update(k, v)
-        compilation_cache.reset_cache()
-
 
 def test_table_is_this_trees_in_a_cache_another_tree_filled(
         cells, persistent_cache, monkeypatch):
