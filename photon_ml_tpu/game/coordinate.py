@@ -43,7 +43,7 @@ from photon_ml_tpu.obs.trace import device_scope
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.opt.solve import line_search_kind, make_solver
 from photon_ml_tpu.opt.types import SolverResult
-from photon_ml_tpu.parallel.bucketing import (bucket_by_entity,
+from photon_ml_tpu.parallel.bucketing import (bucket_by_entity, lane_windows,
                                               offsets_into_lanes,
                                               stacked_coefficients)
 from photon_ml_tpu.parallel.mesh import (SAMPLE_TILE, exchange_bytes,
@@ -64,6 +64,14 @@ Array = jax.Array
 # histogram bound overrides).  100µs .. ~7min, factor 2.
 set_family_bounds("solve_bucket_seconds",
                   [1e-4 * (2.0 ** i) for i in range(23)])
+
+
+def _share_lanes(rows: np.ndarray, shares: int, lo: int,
+                 hi: Optional[int]) -> np.ndarray:
+    """Lanes ``lo .. hi - 1`` of each of the ``shares`` equal shares of a
+    class's ``rows`` [lanes, capacity], share after share."""
+    return rows.reshape(shares, -1, rows.shape[1])[:, lo:hi].reshape(
+        -1, rows.shape[1])
 
 
 def _slots_from(slot_of: Dict[int, int], entity_ids: np.ndarray) -> np.ndarray:
@@ -1079,6 +1087,17 @@ class RandomEffectCoordinate(Coordinate):
             # hold a row; passive rows are scored and never trained on
             classes = self.buckets.buckets
             by_run = [b.run_lanes * lane_multiple for b in classes]
+            by_window = [b.window_lanes * lane_multiple for b in classes]
+            # the window lanes' starts and picks (bucketing.lane_windows),
+            # painted here on the host, uploaded with the lanes' rows
+            windows_of = [
+                lane_windows(_share_lanes(b.rows, lane_multiple, b.run_lanes,
+                                          b.run_lanes + b.window_lanes))
+                if b.window_lanes else None for b in classes]
+            slots = sum(b.num_lanes * b.capacity for b in classes)
+            run_slots = sum(r * b.capacity for r, b in zip(by_run, classes))
+            window_slots = sum(w * b.capacity
+                               for w, b in zip(by_window, classes))
             bucket_span.set(
                 line_search=self.line_search,
                 classes=len(classes),
@@ -1086,14 +1105,20 @@ class RandomEffectCoordinate(Coordinate):
                 lanes=[b.num_lanes for b in classes],
                 lanes_per_device=[b.num_lanes // lane_multiple
                                   for b in classes],
-                slots=sum(b.num_lanes * b.capacity for b in classes),
-                # lanes addressed by the start of their run of samples
-                # (bucketing._class_lanes), their slots, and the slots that
-                # keep one gathered index each
+                slots=slots,
+                # lanes addressed by the start of their run of samples, and
+                # by the start of the window their rows lie in, its width
+                # and the passes of the pick (bucketing._class_lanes,
+                # lane_windows); their slots, and the slots that keep one
+                # gathered index each
                 run_lanes=by_run,
-                run_slots=sum(r * b.capacity for r, b in zip(by_run, classes)),
-                index_slots=sum((b.num_lanes - r) * b.capacity
-                                for r, b in zip(by_run, classes)),
+                window_lanes=by_window,
+                window=[w.window if w else 0 for w in windows_of],
+                pick_stages=[w.stages if w else 0
+                             for w in windows_of],
+                run_slots=run_slots,
+                window_slots=window_slots,
+                index_slots=slots - run_slots - window_slots,
                 active_rows=sum(int(b.counts.sum()) for b in classes),
                 capped_entities=self.buckets.capped_entities,
                 passive_rows=self.buckets.passive_rows)
@@ -1222,25 +1247,31 @@ class RandomEffectCoordinate(Coordinate):
                 return bx.astype(sd)
             return np.asarray(bx).astype(sd)
 
-        def _lane_rows(b):
+        def _lane_rows(b, windows):
             """What ``offsets_into_lanes`` addresses a class's lanes by:
             the row of every slot, or where the bucketer found run lanes
-            (the first ``b.run_lanes`` of each device's share) their starts
-            and the rows of the lanes behind them."""
+            (the first ``b.run_lanes`` of each device's share) their starts,
+            where it found window lanes (the ``b.window_lanes`` behind
+            those) their ``LaneWindows``, and the rows of the lanes behind
+            them."""
             rows = np.where(b.rows < 0, 0, b.rows)
-            if not b.run_lanes:
+            head = b.run_lanes + b.window_lanes
+            if not head:
                 return dict(rows=put(rows))
-            rows = rows.reshape(lane_multiple, -1, b.capacity)
-            return dict(
-                run_start=put(rows[:, :b.run_lanes, 0].reshape(-1)),
-                rows=put(rows[:, b.run_lanes:].reshape(-1, b.capacity)))
+            by = dict(rows=put(_share_lanes(rows, lane_multiple, head, None)))
+            if b.run_lanes:
+                by["run_start"] = put(_share_lanes(
+                    rows, lane_multiple, 0, b.run_lanes)[:, 0])
+            if windows is not None:
+                by["windows"] = jax.tree.map(put, windows)
+            return by
 
         with _upload_span(coordinate_id, mesh) as placed:
             self._dev = [
                 dict(x=put(_narrow(b.x)),
                      y=put(b.y), w=put(b.weight),
-                     valid=put(b.rows >= 0), **_lane_rows(b))
-                for b in solve_buckets
+                     valid=put(b.rows >= 0), **_lane_rows(b, windows))
+                for b, windows in zip(solve_buckets, windows_of)
             ]
             placed((self._dev, self._slot_idx_dev))
         # INDEX_MAP/sparse + normalization: project the coordinate context
@@ -1319,12 +1350,14 @@ class RandomEffectCoordinate(Coordinate):
     def _offsets_into_lanes(self, offsets: Array, devs):
         """``gather(bi)``: the residual offsets of bucket ``bi``'s lanes,
         ``where(valid, offsets[rows], 0)``, its run lanes addressed by
-        their start (``bucketing.offsets_into_lanes``).  Under a mesh the
+        their start and its window lanes by their window's
+        (``bucketing.offsets_into_lanes``).  Under a mesh the
         sample-sharded offsets meet entity-sharded lanes: exchange
         ``offsets``, ONE all-gather of the ``[n]`` vector an update and
         each chip's own lane gathers, every class's at once
         (``lanes_of``)."""
-        classes = [{k: dev[k] for k in ("rows", "valid", "run_start")
+        classes = [{k: dev[k]
+                    for k in ("rows", "valid", "run_start", "windows")
                     if k in dev} for dev in devs]
         if self.mesh is None:
             def gather(bi):

@@ -58,8 +58,9 @@ class Bucket:
     per entity), entity_lanes [E] int64 (original entity id per lane, -1 for
     padding lanes).  ``run_lanes``: in each of the ``lane_multiple`` equal
     shares of the lanes, the first ``run_lanes`` hold rows that are one
-    consecutive run of samples (``_class_lanes``; 0: the order the entities
-    came in, padding lanes last).
+    consecutive run of samples, and the ``window_lanes`` behind them rows
+    that lie inside one short window of samples (``_class_lanes``; both 0:
+    the order the entities came in, padding lanes last).
     """
 
     x: np.ndarray
@@ -70,6 +71,7 @@ class Bucket:
     counts: np.ndarray
     entity_lanes: np.ndarray
     run_lanes: int = 0
+    window_lanes: int = 0
 
     @property
     def num_lanes(self) -> int:
@@ -207,44 +209,58 @@ def _passive(kept_rows: List[np.ndarray], rescale: List[float]) -> dict:
 
 def _class_lanes(idxs: np.ndarray, kept_rows: List[np.ndarray], cap: int,
                  lane_multiple: int, row_ids: Optional[np.ndarray] = None
-                 ) -> Tuple[np.ndarray, int]:
-    """``(lanes [n_lanes], run_lanes)``: the entity (an index into
-    ``kept_rows``; -1: a padding lane) of each lane of one capacity class,
-    the lane count padded to ``lane_multiple``, and how many lanes at the
-    head of each of the ``lane_multiple`` equal shares are RUN lanes.
+                 ) -> Tuple[np.ndarray, int, int]:
+    """``(lanes [n_lanes], run_lanes, window_lanes)``: the entity (an index
+    into ``kept_rows``; -1: a padding lane) of each lane of one capacity
+    class, the lane count padded to ``lane_multiple``, and how many lanes at
+    the head of each of the ``lane_multiple`` equal shares are RUN lanes and
+    how many behind those WINDOW lanes.
 
-    A run lane's rows, as ``Bucket.rows`` stores them, are one consecutive
-    run of samples ``start .. start + k - 1`` (an uncapped entity of data
-    that arrives grouped by entity), so ``offsets_into_lanes`` addresses it
-    by its start.  The rule reads the rows and nothing else: a reservoir, an
-    entity whose rows lie anywhere and every lane of a class under
-    ``RUN_CAPACITY_MIN`` keep one index a slot.  Every share holds the SAME
-    number of run lanes (under a mesh a share is a chip's, and the program
-    is one for all chips): they are dealt out in turn, and those that do
-    not fill a round stay index lanes.  With no run lane the order is the
-    entities' own, padding lanes last."""
-    def is_run(rows):  # ``_group_rows``' rows: ascending and distinct
-        if int(rows[-1]) - int(rows[0]) != len(rows) - 1:
-            return False
-        return row_ids is None or bool(np.all(np.diff(row_ids[rows]) == 1))
+    One rule over a lane's rows as ``Bucket.rows`` stores them, ascending
+    and distinct, ``span = last - first + 1``.  ``span == len(rows)``: one
+    consecutive run of samples ``start .. start + k - 1`` (an uncapped
+    entity of data that arrives grouped by entity), a run lane, which
+    ``offsets_into_lanes`` addresses by its start.  ``len(rows) < span <=
+    WINDOW_SPAN_MAX x cap``: the rows lie inside one short window of
+    samples (a reservoir out of an entity's consecutive rows), a window
+    lane, addressed by the window's start, its kept slots picked out of it
+    on the chip.  The rule reads the rows and nothing else: rows that lie
+    anywhere, a reservoir out of many times its capacity and every lane of a
+    class under ``RUN_CAPACITY_MIN`` keep one index a slot.  Every share
+    holds the SAME number of run lanes and of window lanes (under a mesh a
+    share is a chip's, and the program is one for all chips): each kind is
+    dealt out in turn, and those that do not fill a round stay index lanes.
+    With neither the order is the entities' own, padding lanes last."""
+    def kind(rows):  # 1: a run, 2: a window, 0: one index a slot
+        stored = rows if row_ids is None else row_ids[rows]
+        if int(rows[-1]) - int(rows[0]) == len(rows) - 1 and (
+                row_ids is None or bool(np.all(np.diff(stored) == 1))):
+            return 1
+        if row_ids is not None and not np.all(stored[1:] > stored[:-1]):
+            return 0
+        span = int(stored[-1]) - int(stored[0]) + 1
+        return 2 if len(rows) < span <= WINDOW_SPAN_MAX * cap else 0
 
     n_lanes = -(-len(idxs) // lane_multiple) * lane_multiple
-    lanes = np.full(n_lanes, -1, np.int64)
-    run = (np.fromiter((is_run(kept_rows[ei]) for ei in idxs), bool,
-                       len(idxs)) if cap >= RUN_CAPACITY_MIN else np.zeros(0, bool))
-    per_share = int(run.sum()) // lane_multiple
-    if per_share == 0:
-        lanes[:len(idxs)] = idxs
-        return lanes, 0
     share = n_lanes // lane_multiple
-    dealt = np.flatnonzero(run)[:per_share * lane_multiple]
-    lanes.reshape(lane_multiple, share)[:, :per_share] = idxs[dealt].reshape(
-        per_share, lane_multiple).T
-    rest = np.delete(idxs, dealt)
+    lanes = np.full(n_lanes, -1, np.int64)
+    kinds = (np.fromiter((kind(kept_rows[ei]) for ei in idxs), np.int8,
+                         len(idxs)) if cap >= RUN_CAPACITY_MIN
+             else np.zeros(len(idxs), np.int8))
+    rest, heads = np.ones(len(idxs), bool), []
+    for k in (1, 2):
+        found = np.flatnonzero(kinds == k)
+        per_share = len(found) // lane_multiple
+        dealt = found[:per_share * lane_multiple]
+        at = sum(heads)
+        lanes.reshape(lane_multiple, share)[:, at:at + per_share] = idxs[
+            dealt].reshape(per_share, lane_multiple).T
+        rest[dealt] = False
+        heads.append(per_share)
     tails = (np.arange(lane_multiple)[:, None] * share
-             + np.arange(per_share, share)[None, :]).ravel()
-    lanes[tails[:len(rest)]] = rest
-    return lanes, per_share
+             + np.arange(sum(heads), share)[None, :]).ravel()
+    lanes[tails[:int(rest.sum())]] = idxs[rest]
+    return (lanes, *heads)
 
 
 def _pack_lane_meta(cap, lanes, kept_rows, kept_entities, rescale,
@@ -356,8 +372,8 @@ def bucket_by_entity(
     buckets: List[Bucket] = []
     lane_of: Dict[int, Tuple[int, int]] = {}
     for cap in sorted(set(caps.tolist())):
-        lanes, run_lanes = _class_lanes(np.nonzero(caps == cap)[0], kept_rows,
-                                        cap, lane_multiple, row_ids)
+        lanes, run_lanes, window_lanes = _class_lanes(
+            np.nonzero(caps == cap)[0], kept_rows, cap, lane_multiple, row_ids)
         by, boff, bw, brows, bcounts, blanes = _pack_lane_meta(
             cap, lanes, kept_rows, kept_entities, rescale,
             y, offset, weight, dtype, lane_of, len(buckets), row_ids=row_ids)
@@ -376,7 +392,7 @@ def bucket_by_entity(
                     bx[lane, :len(rows)] = x[rows]
         buckets.append(Bucket(x=bx, y=by, offset=boff, weight=bw, rows=brows,
                               counts=bcounts, entity_lanes=blanes,
-                              run_lanes=run_lanes))
+                              run_lanes=run_lanes, window_lanes=window_lanes))
 
     return EntityBuckets(buckets=buckets, lane_of=lane_of, dim=d,
                          num_entities=len(kept_entities),
@@ -467,8 +483,8 @@ def bucket_by_entity_sparse(
     projections: List[object] = []
     lane_of: Dict[int, Tuple[int, int]] = {}
     for cap in sorted(set(caps.tolist())):
-        lanes, run_lanes = _class_lanes(np.nonzero(caps == cap)[0], kept_rows,
-                                        cap, lane_multiple, row_ids)
+        lanes, run_lanes, window_lanes = _class_lanes(
+            np.nonzero(caps == cap)[0], kept_rows, cap, lane_multiple, row_ids)
         compacted = {lane: _compact_lane(kept_rows[ei])
                      for lane, ei in enumerate(lanes) if ei >= 0}
         d_proj = _pow2_at_least(max((len(o) for o, _ in compacted.values()),
@@ -484,7 +500,7 @@ def bucket_by_entity_sparse(
             bidx[lane, :len(obs)] = obs
         buckets.append(Bucket(x=bx, y=by, offset=boff, weight=bw, rows=brows,
                               counts=bcounts, entity_lanes=blanes,
-                              run_lanes=run_lanes))
+                              run_lanes=run_lanes, window_lanes=window_lanes))
         projections.append(BucketProjection(indices=bidx, d_full=dim))
 
     ents = EntityBuckets(buckets=buckets, lane_of=lane_of, dim=dim,
@@ -696,6 +712,36 @@ EM_PAD_MAX = 1.3  # padded rows over rows; per-item at C = 128 pads to 1.25
 # at 2, twice as fast from 4.  At glmix_ml20m's per-user classes (32 to
 # 1,024) 2.25 ms an update for 110.4.
 RUN_CAPACITY_MIN = 4
+# The span over the capacity up to which a lane whose kept rows are NOT one
+# run (a reservoir out of an entity's consecutive rows) is addressed by the
+# start of its WINDOW and picked out of it (``_windows_at``), and the bytes
+# of a block of its lanes.  On a v5e (PR 35, scratch, about 2M slots a
+# class out of 13.0M offsets, every form bitwise one index a slot), ms a
+# class: one index a slot / window lanes as landed at a span of 2x, 4x, 8x
+# the capacity, and (blocks unrolled) 16x:
+#   capacity    4 (524,288 lanes): 17.6 / 9.6, -, -, 7.8
+#   capacity   16 (131,072 lanes): 15.7 / -, 3.7, 4.6, 4.0
+#   capacity   64 (32,768 lanes):  15.1 / -, -, 3.5, 3.7
+#   capacity  256 (8,192 lanes):   15.0 / -, -, 2.4, 5.9
+#   capacity 1024 (2,048 lanes):   15.0 / 0.53 (unrolled), 1.2, 2.5, 6.0
+# A window lane wins 1.8 to 12 times over at every span and capacity
+# measured; what stops at 8x is the HOST: a class of 2M slots paints 16.8M
+# words in 1.7 to 2.0 s at 8x and 33.5M in 5.5 to 6.2 s at 16x
+# (``lane_windows``, the chip's host), and uploads as many.  glmix_chip's
+# class (131,072 lanes, 32 kept of 64), ms a call: 30.4 one index a slot;
+# 8.9 with the windows gathered in one piece (``_runs_at`` alone 7.8: its
+# [lanes, 256] view is 134 MB and each of the seven rolls a pass over HBM,
+# 59 ns a lane where PR 31's 24,656 lanes cost 14.7); in blocks of 4 / 8 /
+# 16 / 32 / 64 MiB of that view 1.7 / 2.0 / 1.8 / 2.3 / 6.0 unrolled and 2.9
+# / 3.5 / 4.4 / 4.4 as ONE loop, which landed at 8: at 16 blocks unrolled
+# the program grows from 527 to 1,712 instructions and a warm set-up by 0.8
+# s (the loop: 0.03), and from 2,048 lanes of 8,192 slots up the loop is
+# the faster (2.5 ms for 4.9).  The stages on the flat stream of all
+# windows (PR 33's form) 4.0 to 4.9 blocked, 9.9 not; a compare-select-
+# reduce over the window 8.7, transposed 7.9 (one piece): the [lanes, W]
+# view and the stages win here.
+WINDOW_SPAN_MAX = 8
+WINDOW_BLOCK_BYTES = 8 << 20
 
 
 def _runs_at(offsets: Array, run_start: Array, capacity: int) -> Array:
@@ -712,7 +758,7 @@ def _runs_at(offsets: Array, run_start: Array, capacity: int) -> Array:
     whole = -(-n // EM_ROW) * EM_ROW
     table = (offsets if whole == n else jnp.pad(offsets, (0, whole - n))
              ).reshape(-1, EM_ROW)
-    take = max(capacity // EM_ROW, 1) + 1
+    take = (capacity + EM_ROW - 2) // EM_ROW + 1
     first = (run_start // EM_ROW)[:, None]
     picked = table[jnp.minimum(first + jnp.arange(take, dtype=first.dtype),
                                table.shape[0] - 1)]   # [lanes, take, EM_ROW]
@@ -724,22 +770,124 @@ def _runs_at(offsets: Array, run_start: Array, capacity: int) -> Array:
     return picked[:, :capacity]
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["start", "pull"], meta_fields=["stages"])
+@dataclasses.dataclass(frozen=True)
+class LaneWindows:
+    """A class's WINDOW lanes (``_class_lanes``), as ``offsets_into_lanes``
+    addresses them: ``start`` int32 ``[lanes]`` the sample each lane's window
+    starts at; ``pull`` int32 ``[lanes, W]`` (``W`` the class's widest
+    window, at least its capacity) the pick of every lane's kept slots out
+    of its window into its first places, in their stored order, as
+    ``pull_stages``' order-preserving compaction a lane: bit ``b`` at slot
+    ``q`` says stage ``b`` pulls the lane's slot ``q + 2^b`` into ``q``
+    (``_pulled`` along the lane); ``stages`` its passes (static), the bits
+    of the most rows any lane skips in front of a kept one."""
+
+    start: Array
+    pull: Array
+    stages: int
+
+    @property
+    def window(self) -> int:
+        return self.pull.shape[1]
+
+
+def lane_windows(rows: np.ndarray) -> LaneWindows:
+    """``LaneWindows`` (its leaves on the host) of window lanes' stored
+    ``rows`` [lanes, capacity]: each lane's ascending, -1 behind them.
+
+    A kept row has ``skipped`` rows of its window in front of it that are
+    not kept, and so many places to go left: never fewer than the kept row
+    before it, so the lane's elements keep their order and their distance
+    through ``pull_stages``' stages (stage ``b`` moves those whose count
+    has bit ``b`` by ``2^b``), and no lane's leave its window.  The stages
+    are painted by running them here over the counts, a lane a row: half
+    the rows of a reservoir start a run of their own, and ``pull_stages``
+    run by run takes ten times as long."""
+    lanes, capacity = rows.shape
+    kept = rows >= 0
+    within = np.where(kept, rows - rows[:, :1], -1)
+    window = max(int(within.max()) + 1, capacity)
+    skipped = np.where(kept, within - np.arange(capacity, dtype=rows.dtype),
+                       0)
+    if np.any(skipped[:, 1:] < skipped[:, :-1] * kept[:, 1:]):
+        raise ValueError("window lanes: rows that do not rise along a lane")
+    stages = int(skipped.max()).bit_length()
+    word = np.int8 if stages < 8 else np.int16 if stages < 16 else np.int32
+    # the stream as the chip sees it: at a slot the count its element has
+    # still to go (0: an element at rest, or nothing)
+    at = np.zeros((lanes, window + 1), word)
+    np.put_along_axis(at, np.where(kept, within, window),
+                      skipped.astype(word), axis=1)
+    at = np.ascontiguousarray(at[:, :window])
+    pull = np.zeros((lanes, window), word)
+    for b in range(stages):
+        moves = (at >> b) & 1
+        pull[:, :window - (1 << b)] |= moves[:, 1 << b:] << b
+        moved = np.where(moves == 1, at, 0)
+        at -= moved
+        at[:, :window - (1 << b)] |= moved[:, 1 << b:]
+    return LaneWindows(rows[:, 0].astype(np.int32), pull.astype(np.int32),
+                       stages)
+
+
+def _windows_at(offsets: Array, windows: LaneWindows, capacity: int) -> Array:
+    """The window lanes' kept rows out of ``offsets`` [lanes, capacity]:
+    every window read by rows of 128 (``_runs_at``), its kept slots pulled
+    to its head (``_pulled`` along the lane: no index a slot, and a slot
+    never pulls from beyond its window), the first ``capacity`` places
+    kept.  Copies only.  In BLOCKS of lanes whose wide view (the gathered
+    rows of 128, before the cut to the window) holds
+    ``WINDOW_BLOCK_BYTES``: the seven rolls and the stages of a block then
+    run without a pass over HBM each; the whole blocks are one loop (one
+    body in the program however many lanes), what is left behind them a
+    block of its own."""
+    lanes, window = windows.pull.shape
+    take = (window + EM_ROW - 2) // EM_ROW + 1
+    block = max(8, WINDOW_BLOCK_BYTES // (
+        take * EM_ROW * offsets.dtype.itemsize) // 8 * 8)
+
+    def pick(start, pull):
+        return _pulled(_runs_at(offsets, start, window), pull,
+                       windows.stages, axis=1)[:, :capacity]
+
+    whole = lanes // block * block
+    picked = []
+    if whole:
+        picked.append(jax.lax.map(
+            lambda by: pick(*by),
+            (windows.start[:whole].reshape(-1, block),
+             windows.pull[:whole].reshape(-1, block, window))
+        ).reshape(whole, capacity))
+    if lanes > whole:
+        picked.append(pick(windows.start[whole:], windows.pull[whole:]))
+    return picked[0] if len(picked) == 1 else jnp.concatenate(picked)
+
+
 def offsets_into_lanes(offsets: Array, rows: Array, valid: Array,
-                       run_start: Optional[Array] = None) -> Array:
+                       run_start: Optional[Array] = None,
+                       windows: Optional[LaneWindows] = None) -> Array:
     """One capacity class's residual offsets, ``where(valid, offsets[rows
     of each slot], 0)`` [lanes, capacity], from the ``[n]`` vector.  The
     class's first ``len(run_start)`` lanes are RUN lanes (``_class_lanes``)
-    addressed by the sample their run starts at; ``rows`` [index lanes,
-    capacity] holds the rows of the lanes behind them, one index a slot;
-    ``valid`` masks all of them.  Copies either way: the lanes are bitwise
-    what one index a slot gives.  ``run_start`` None: a class with no run
-    lane, ``rows`` its every lane's."""
-    if run_start is None:
-        return jnp.where(valid, offsets[rows], 0.0)
-    picked = _runs_at(offsets, run_start, valid.shape[1])
-    if rows.shape[0]:
-        picked = jnp.concatenate([picked, offsets[rows]])
-    return jnp.where(valid, picked, 0.0)
+    addressed by the sample their run starts at, the ``len(windows.start)``
+    behind them WINDOW lanes addressed by their window's start and picked
+    out of it; ``rows`` [index lanes, capacity] holds the rows of the lanes
+    behind those, one index a slot; ``valid`` masks all of them.  Copies
+    every way: the lanes are bitwise what one index a slot gives.
+    ``run_start`` / ``windows`` None: a class with no such lane."""
+    capacity = valid.shape[1]
+    picked = []
+    if run_start is not None:
+        picked.append(_runs_at(offsets, run_start, capacity))
+    if windows is not None:
+        picked.append(_windows_at(offsets, windows, capacity))
+    if rows.shape[0] or not picked:
+        picked.append(offsets[rows])
+    return jnp.where(
+        valid, picked[0] if len(picked) == 1 else jnp.concatenate(picked),
+        0.0)
 
 
 def entity_major_chunk(counts: np.ndarray) -> Optional[int]:
@@ -959,6 +1107,17 @@ def pull_stages(pos: np.ndarray, slots: int) -> Tuple[np.ndarray, int]:
     return pull, stages
 
 
+def _pulled(acc: Array, pull: Array, stages: int,
+            axis: Optional[int] = None) -> Array:
+    """``pull_stages``' stages run over the stream ``acc`` (flat, or each
+    row of it along ``axis``): stage ``b`` one pass ``where(pull bit b,
+    roll(acc, -2^b), acc)``."""
+    for b in range(stages):
+        acc = jnp.where((pull >> b) & 1 == 1,
+                        jnp.roll(acc, -(1 << b), axis=axis), acc)
+    return acc
+
+
 def unpad(acc: Array, back: Unpad) -> Array:
     """The samples out of the flat entity-major stream ``acc``, in sample
     order, bitwise ``acc[pos]``: copies, no arithmetic touches a score and
@@ -971,10 +1130,7 @@ def unpad(acc: Array, back: Unpad) -> Array:
         acc = jax.lax.dynamic_slice_in_dim(
             acc.reshape(-1, EM_ROW), back.start[0], back.slots // EM_ROW
         ).reshape(-1)
-    for b in range(back.stages):
-        acc = jnp.where((back.pull >> b) & 1 == 1,
-                        jnp.roll(acc, -(1 << b)), acc)
-    out = acc[:back.num_samples]
+    out = _pulled(acc, back.pull, back.stages)[:back.num_samples]
     if back.live is not None:
         out = jnp.where(jnp.arange(back.num_samples) < back.live[0], out, 0)
     return out

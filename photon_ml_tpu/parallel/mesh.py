@@ -282,8 +282,8 @@ def lanes_of(offsets: jax.Array, classes: Sequence[Dict[str, jax.Array]],
     """Exchange ``offsets``: ``bucketing.offsets_into_lanes`` for each
     capacity class, the sample-sharded ``[n]`` offsets meeting
     entity-sharded lanes (``classes``: that function's ``rows``, ``valid``
-    and, where the class has run lanes, ``run_start`` of each, their lanes
-    over every chip).  ONE all-gather makes the vector whole on every chip,
+    and, where the class has run or window lanes, ``run_start`` and
+    ``windows`` of each, their lanes over every chip).  ONE all-gather makes the vector whole on every chip,
     then each chip gathers its own lanes' rows out of it, class by class.
     One program a chip for both halves:
     handed from one ``shard_map`` to the next, the TPU compiler turned the

@@ -407,6 +407,69 @@ def test_pull_stages_refuses_positions_that_do_not_rise():
         bucketing.pull_stages(np.asarray([0, 5, 3, 9], np.int32), 16)
 
 
+def _window_class(rng, n, capacity, runs, windows, anywhere):
+    """Stored rows [lanes, capacity] (-1 behind a lane's rows) of a class
+    that mixes run, window and index lanes, in that order."""
+    limit = bucketing.WINDOW_SPAN_MAX * capacity
+    rows = np.full((runs + windows + anywhere, capacity), -1, np.int64)
+    for lane in range(runs):
+        k = int(rng.integers(1, capacity + 1))
+        start = int(rng.integers(0, n - k + 1))
+        rows[lane, :k] = start + np.arange(k)
+    for lane in range(runs, runs + windows):
+        k = int(rng.integers(2, capacity + 1))
+        # spans from one skipped row to the rule's limit, both met
+        span = int(rng.choice([k + 1, limit, rng.integers(k + 1, limit + 1)]))
+        # a window that ends at the vector's last sample; the class's
+        # widest then makes every narrower one near it reach PAST the end
+        start = n - span if rng.random() < 0.4 else int(
+            rng.integers(0, n - span + 1))
+        inner = rng.choice(np.arange(1, span - 1), k - 2, replace=False)
+        rows[lane, :k] = start + np.sort(np.r_[0, inner, span - 1])
+    for lane in range(runs + windows, len(rows)):
+        k = int(rng.integers(1, capacity + 1))
+        rows[lane, :k] = np.sort(rng.choice(n, k, replace=False))
+    return rows
+
+
+@pytest.mark.parametrize("capacity", [4, 8, 32, 128, 1024])
+@settings(max_examples=6, deadline=None)
+@given(seed=st.integers(0, 2**31 - 1), runs=st.integers(0, 3),
+       windows=st.integers(1, 6), anywhere=st.integers(0, 3),
+       tail=st.integers(1, bucketing.EM_ROW - 1), bf16=st.booleans())
+def test_window_lanes_are_bitwise_one_index_a_slot(capacity, seed, runs,
+                                                   windows, anywhere, tail,
+                                                   bf16):
+    """ISSUE 35: a class that mixes run, window and index lanes comes out
+    of ``offsets_into_lanes`` BITWISE ``where(valid, offsets[rows], 0)``:
+    windows that start anywhere (no multiple of 128), that reach past the
+    vector's end, of every span the rule admits, ``n`` no multiple of 128."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    limit = bucketing.WINDOW_SPAN_MAX * capacity
+    n = (limit // bucketing.EM_ROW + int(rng.integers(1, 4))
+         ) * bucketing.EM_ROW + tail
+    rows = _window_class(rng, n, capacity, runs, windows, anywhere)
+    valid = rows >= 0
+    offsets = jnp.asarray(rng.standard_normal(n),
+                          jnp.bfloat16 if bf16 else jnp.float32)
+    want = np.asarray(jnp.where(valid, offsets[np.maximum(rows, 0)], 0.0))
+    by = bucketing.lane_windows(rows[runs:runs + windows])
+    assert by.window >= capacity and by.stages >= 1
+    assert by.window == max(capacity, int(
+        (rows[runs:runs + windows].max(axis=1)
+         - rows[runs:runs + windows, 0]).max()) + 1)
+    got = jax.jit(bucketing.offsets_into_lanes)(
+        offsets, jnp.asarray(np.maximum(rows[runs + windows:], 0), jnp.int32),
+        jnp.asarray(valid),
+        jnp.asarray(rows[:runs, 0], jnp.int32) if runs else None,
+        jax.tree.map(jnp.asarray, by))
+    assert got.dtype == offsets.dtype and got.shape == want.shape
+    assert np.array_equal(np.asarray(got), want)
+
+
 @settings(max_examples=40, deadline=None)
 @given(ids=_ids, cap=st.integers(1, 8), seed=st.integers(0, 2**31 - 1),
        sort=st.booleans())

@@ -136,10 +136,11 @@ def test_glmix_chip_kernels_and_gathers_fall_under_their_layers(compiled_text):
         "photon.update.fixed/photon.fixed_solve"}
     assert layers_of(table, "soa_newton_step") == {
         "photon.update.per_user/photon.entity_solve.b0"}
-    # 128 users x 32 active rows gathered out of the 6,144 offsets; 6,144
+    # 128 users' windows (32 active rows kept of 48: ISSUE 35) gathered out
+    # of the 6,144 offsets as two rows of 128 each, no index a slot; 6,144
     # rows of w_stack gathered by slot (the row layout, at this size)
     assert gather_fusions(text, table) == {
-        "photon.update.per_user/photon.entity_gather": ["f32[4096]"],
+        "photon.update.per_user/photon.entity_gather": ["f32[256,128]"],
         "photon.update.per_user/photon.rescore": ["f32[6144,4]"]}
 
 

@@ -37,6 +37,7 @@ from photon_ml_tpu.game.fused import FusedSweep  # noqa: E402
 from photon_ml_tpu.obs import trace as obs_trace  # noqa: E402
 from photon_ml_tpu.obs.trace import (Tracer, device_scope,  # noqa: E402
                                      hlo_op_table, set_tracer)
+from photon_ml_tpu.parallel import bucketing  # noqa: E402
 
 CONFIGS = ["glmix_chip", "glmix3_wide"]
 # what each configuration exercises of the vocabulary (PERF.md section 3)
@@ -367,7 +368,9 @@ def test_coordinate_spans_carry_coordinate_and_bytes():
 def test_bucket_span_counts_the_lanes_addressed_by_run(config, by_run):
     """ISSUE 31's counter that says how often the mechanism engages:
     ``coord.bucket`` carries ``run_lanes`` (a class), ``run_slots`` and
-    ``index_slots`` (summed; a class's are its lanes x its capacity)."""
+    ``index_slots`` (summed; a class's are its lanes x its capacity); and
+    ISSUE 35's: ``window_lanes``, ``window`` and ``pick_stages`` (a class),
+    ``window_slots`` (summed), a slot counted under one of the three."""
     catalog = harness.Catalog()
     cfg = harness.sized(catalog.json("configs", config), True)
     data = catalog.module("recipes", cfg["recipe"]).make_training(cfg, 6)
@@ -382,9 +385,29 @@ def test_bucket_span_counts_the_lanes_addressed_by_run(config, by_run):
         assert len(a["run_lanes"]) == a["classes"]
         assert a["run_slots"] == sum(
             r * c for r, c in zip(a["run_lanes"], a["capacities"]))
-        assert a["run_slots"] + a["index_slots"] == a["slots"]
         assert (a["run_slots"] > 0) == engaged, (cid, a)
         assert any("run_start" in dev for dev in coords[cid]._dev) == engaged
+        for key in ("window_lanes", "window", "pick_stages"):
+            assert len(a[key]) == a["classes"]
+        assert a["window_slots"] == sum(
+            w * c for w, c in zip(a["window_lanes"], a["capacities"]))
+        assert (a["run_slots"] + a["window_slots"] + a["index_slots"]
+                == a["slots"])
+        for lanes, w, stages, c in zip(a["window_lanes"], a["window"],
+                                       a["pick_stages"], a["capacities"]):
+            assert (lanes > 0) == (w > 0) == (stages > 0)
+            assert w == 0 or c <= w <= bucketing.WINDOW_SPAN_MAX * c
+        assert ["windows" in dev for dev in coords[cid]._dev] == [
+            lanes > 0 for lanes in a["window_lanes"]]
+    if config == "glmix_chip":  # every lane a reservoir of half a user's rows
+        a = spans["per-user"]
+        assert a["window_lanes"] == a["lanes"] and a["index_slots"] == 0
+        assert a["window"] == [cfg["rows_per_user"]]
+        assert a["window_slots"] == a["slots"]
+        assert a["pick_stages"] == [
+            (cfg["rows_per_user"] - a["capacities"][0]).bit_length()]
+    if config == "glmix3_wide":  # shuffled ids, no cap: nothing qualifies
+        assert all(sum(a["window_lanes"]) == 0 for a in spans.values())
     if config == "glmix_ml20m":  # the cell's share: most of the slots
         a = spans["per-user"]
         by_class = dict(zip(a["capacities"], a["run_lanes"]))

@@ -183,18 +183,25 @@ def test_solver_rule_follows_the_worst_bucket(rng, d, rows, loss, extra, soa):
 ROW_GATHER = "slice_sizes = array<i64: 1, %d>" % bucketing.EM_ROW
 
 
-@pytest.mark.parametrize("rows_lie, cap, run_classes", [
-    ("by_user", None, 3),    # the control: classes of 64 to 256, all runs
-    ("by_user", 128, 2),     # 150 rows capped to a reservoir beside runs
-    ("anywhere", None, 0),   # the ids shuffled: no lane is a run
-    ("by_user", 32, 0),      # every user over the cap: reservoirs only
-    ("short", None, 0),      # runs, in classes under RUN_CAPACITY_MIN
+@pytest.mark.parametrize("rows_lie, cap, run_classes, window_lanes", [
+    ("by_user", None, 3, 0),   # the control: classes of 64 to 256, all runs
+    ("by_user", 128, 2, 1),    # 150 rows capped to a reservoir beside runs
+    ("anywhere", None, 0, 4),  # the ids shuffled: rows that lie anywhere,
+                               # which at 682 samples is inside 8 x 128
+    ("by_user", 32, 0, 8),     # every user over the cap: reservoirs only,
+                               # each inside a window of 8 x 32 samples
+    ("by_user", 8, 0, 4),      # reservoirs out of 40 to 64 rows, and four
+                               # out of over 8 x their capacity
+    ("short", None, 0, 0),     # runs, in classes under RUN_CAPACITY_MIN
 ])
-def test_run_rule_reads_the_rows(rng, rows_lie, cap, run_classes):
+def test_run_rule_reads_the_rows(rng, rows_lie, cap, run_classes,
+                                 window_lanes):
     """``bucketing._class_lanes``: a lane is addressed by its start where
-    its rows are one run of samples and its class holds at least
-    ``RUN_CAPACITY_MIN`` rows; every other lane, and with it the program
-    of a coordinate that has none, keeps one index a slot."""
+    its rows are one run of samples, by its window's start where they lie
+    inside ``WINDOW_SPAN_MAX`` x its capacity samples (ISSUE 35), and its
+    class holds at least ``RUN_CAPACITY_MIN`` rows; every other lane, and
+    with it the program of a coordinate that has none, keeps one index a
+    slot."""
     counts = ([1, 2, 1, 2] * 4 if rows_lie == "short"
               else [40, 50, 60, 64, 100, 128, 150, 90])
     uid = np.repeat(np.arange(len(counts)), counts)
@@ -219,9 +226,29 @@ def test_run_rule_reads_the_rows(rng, rows_lie, cap, run_classes):
     assert ["run_start" in dev for dev in coord._dev] == [
         b.run_lanes > 0 for b in classes]
     assert span["run_lanes"] == [b.run_lanes for b in classes]
-    assert span["run_slots"] + span["index_slots"] == span["slots"]
     assert span["run_slots"] == sum(b.run_lanes * b.capacity
                                     for b in classes)
+    # ISSUE 35: the lanes addressed by their window's start, a class's
+    # widest window, the passes of its pick; a slot is counted once
+    assert span["window_lanes"] == [b.window_lanes for b in classes]
+    assert sum(span["window_lanes"]) == window_lanes
+    assert ["windows" in dev for dev in coord._dev] == [
+        b.window_lanes > 0 for b in classes]
+    for b, dev, w, stages in zip(classes, coord._dev, span["window"],
+                                 span["pick_stages"]):
+        if not b.window_lanes:
+            assert (w, stages) == (0, 0)
+            continue
+        head = slice(b.run_lanes, b.run_lanes + b.window_lanes)
+        spans = b.rows[head].max(axis=1) - b.rows[head, 0] + 1
+        assert w == max(b.capacity, spans.max())
+        assert spans.max() <= bucketing.WINDOW_SPAN_MAX * b.capacity
+        assert stages == int((spans - b.counts[head]).max()).bit_length()
+        assert dev["windows"].pull.shape == (b.window_lanes, w)
+    assert span["window_slots"] == sum(b.window_lanes * b.capacity
+                                       for b in classes)
+    assert (span["run_slots"] + span["window_slots"] + span["index_slots"]
+            == span["slots"])
     if cap == 128:  # the capped user's lane stands behind the runs
         b = classes[-1]
         assert (b.capacity, b.num_lanes, b.run_lanes) == (128, 4, 3)
@@ -229,7 +256,7 @@ def test_run_rule_reads_the_rows(rng, rows_lie, cap, run_classes):
     sweep = FusedSweep({"user": coord}, num_iterations=1)
     args, _ = sweep._program_args(None, None, 0, None)
     text = sweep._program.lower(*args).as_text()
-    assert (ROW_GATHER in text) == (run_classes > 0)
+    assert (ROW_GATHER in text) == (run_classes + window_lanes > 0)
     # either way the lanes are what one index a slot gives
     offsets = rng.normal(size=n)
     gather = coord._offsets_into_lanes(np.asarray(offsets), coord._dev)

@@ -461,8 +461,10 @@ def test_kernels_run_a_shard_in_place_inside_shard_map(mesh, monkeypatch):
 def test_lanes_by_run_under_the_mesh(on_mesh):
     """ISSUE 31: per-user lanes whose rows are one run of samples are
     addressed by their start on every chip, the same number of them in
-    every chip's share; the lanes are the one-device lanes, entity by
-    entity, and the offsets still cross in ONE all-gather an update."""
+    every chip's share; ISSUE 35: so are the capped users' lanes by the
+    start of the window their reservoir lies in, each chip holding its own
+    lanes' picks; the lanes are the one-device lanes, entity by entity, and
+    the offsets still cross in ONE all-gather an update."""
     data, coords, _, text, *_ = on_mesh
     n = len(data["y"])
     alone = build(data, "entity_major", None, dtype=np.float32)
@@ -474,13 +476,25 @@ def test_lanes_by_run_under_the_mesh(on_mesh):
         # rows by user: runs; a movie's rows lie anywhere
         assert any(has_runs) == (cid == "per-user")
         assert ["run_start" in dev for dev in coord._dev] == has_runs
+        has_windows = [b.window_lanes > 0 for b in classes]
+        # the capped users' reservoirs lie inside windows; a movie's do not
+        assert any(has_windows) == (cid == "per-user")
+        assert ["windows" in dev for dev in coord._dev] == has_windows
         for b, dev in zip(classes, coord._dev):
             if b.run_lanes:
                 assert dev["run_start"].shape == (CHIPS * b.run_lanes,)
                 assert dev["run_start"].sharding.shard_shape(
                     dev["run_start"].shape) == (b.run_lanes,)
-                assert dev["rows"].shape == (
-                    b.num_lanes - CHIPS * b.run_lanes, b.capacity)
+            if b.window_lanes:
+                by = dev["windows"]
+                assert by.start.shape == (CHIPS * b.window_lanes,)
+                assert by.pull.shape == (CHIPS * b.window_lanes, by.window)
+                for a in (by.start, by.pull):  # a chip holds its own lanes'
+                    assert a.sharding.shard_shape(a.shape) == (
+                        b.window_lanes,) + a.shape[1:]
+            assert dev["rows"].shape == (
+                b.num_lanes - CHIPS * (b.run_lanes + b.window_lanes),
+                b.capacity)
             assert dev["valid"].shape == (b.num_lanes, b.capacity)
         gather = coord._offsets_into_lanes(
             samples_on_device(offsets, coord.mesh, np.float32), coord._dev)
@@ -589,8 +603,12 @@ def test_spans_say_what_is_sharded_and_what_crosses(on_mesh):
         # ISSUE 31: the lanes addressed by their run's start, a multiple
         # of the chips in every class; none of a movie's
         assert all(r % CHIPS == 0 for r in a["run_lanes"])
-        assert a["run_slots"] + a["index_slots"] == a["slots"]
         assert (a["run_slots"] > 0) == (a["coordinate"] == "per-user")
+        # ISSUE 35: and those addressed by their window's start
+        assert all(w % CHIPS == 0 for w in a["window_lanes"])
+        assert (a["window_slots"] > 0) == (a["coordinate"] == "per-user")
+        assert (a["run_slots"] + a["window_slots"] + a["index_slots"]
+                == a["slots"])
     (ex,) = spans_named(records, "descent.exchange")
     assert ex["coordinates"] == list(coords) and ex["devices"] == CHIPS
     assert ex["collectives"] == sweep._collectives != {}

@@ -168,3 +168,151 @@ def test_rows_that_lie_anywhere_give_no_run_lane():
     assert sum(b.run_lanes for b in eb.buckets) > 0
     for b in eb.buckets:
         assert lane_runs(b)[:b.run_lanes].all()
+
+
+# -- ISSUE 35: window lanes ---------------------------------------------------
+
+def window_rows(rng, start, k, span):
+    """``k`` ascending rows from ``start``, the first and the last of a
+    window of ``span`` samples among them."""
+    inner = rng.choice(np.arange(1, span - 1), k - 2, replace=False)
+    return (start + np.sort(np.r_[0, inner, span - 1])).astype(np.int64)
+
+
+def kinds_class(rng, capacity, runs, windows, far, n=1 << 20):
+    """``kept_rows`` of ``runs`` run, ``windows`` window and ``far`` index
+    entities of one capacity class, shuffled, and each one's kind."""
+    limit = bucketing.WINDOW_SPAN_MAX * capacity
+    kept, kind = [], []
+    for which, count in (("run", runs), ("window", windows), ("far", far)):
+        for i in range(count):
+            k = int(rng.integers(capacity // 2 + 1, capacity + 1))
+            start = int(rng.integers(0, n - 2 * limit))
+            if which == "run":
+                rows = start + np.arange(k)
+            else:
+                # the rule's edge on both sides: a span of exactly the
+                # limit is a window, one more is not
+                span = (limit + 1 + (i % 2) * limit if which == "far" else
+                        (limit, k + 1)[i] if i < 2 else
+                        int(rng.integers(k + 1, limit + 1)))
+                rows = window_rows(rng, start, k, span)
+            kept.append(rows.astype(np.int64))
+            kind.append(which)
+    order = rng.permutation(len(kept))
+    return [kept[i] for i in order], [kind[i] for i in order]
+
+
+@pytest.mark.parametrize("lane_multiple", [1, 4])
+@pytest.mark.parametrize("capacity", [2, 4, 16, 128])
+def test_class_lanes_deals_runs_then_windows_then_the_rest(capacity,
+                                                           lane_multiple):
+    """``_class_lanes`` reads a lane's kind off its rows and gives every
+    share the same number of run lanes, then of window lanes; what does
+    not fill a round of the deal, and every lane of a class under
+    ``RUN_CAPACITY_MIN``, keeps one index a slot."""
+    rng = np.random.default_rng(capacity)
+    kept, kind = kinds_class(rng, capacity, runs=7, windows=10, far=5)
+    idxs = np.arange(len(kept))
+    lanes, run_lanes, window_lanes = bucketing._class_lanes(
+        idxs, kept, capacity, lane_multiple)
+    assert len(lanes) == -(-len(kept) // lane_multiple) * lane_multiple
+    assert sorted(lanes[lanes >= 0]) == list(idxs)
+    if capacity < bucketing.RUN_CAPACITY_MIN:
+        assert (run_lanes, window_lanes) == (0, 0)
+        assert np.array_equal(lanes[:len(kept)], idxs)  # the parent's order
+        return
+    assert run_lanes == 7 // lane_multiple
+    assert window_lanes == 10 // lane_multiple
+    by_share = lanes.reshape(lane_multiple, -1)
+    of = np.vectorize(lambda e: kind[e] if e >= 0 else "padding")
+    assert (of(by_share[:, :run_lanes]) == "run").all()
+    head = run_lanes + window_lanes
+    assert (of(by_share[:, run_lanes:head]) == "window").all()
+    rest = of(by_share[:, head:]).ravel()
+    assert (rest == "run").sum() == 7 % lane_multiple
+    assert (rest == "window").sum() == 10 % lane_multiple
+    assert (rest == "far").sum() == 5
+    # the rest in the entities' own order, padding lanes last in the class
+    tail = by_share[:, head:].ravel()
+    assert np.array_equal(tail[tail >= 0], np.sort(tail[tail >= 0]))
+
+
+def parents_runs_at(offsets, run_start, capacity):
+    """PR 31's ``_runs_at`` as it landed, word for word."""
+    n = offsets.shape[0]
+    whole = -(-n // EM_ROW) * EM_ROW
+    table = (offsets if whole == n else jnp.pad(offsets, (0, whole - n))
+             ).reshape(-1, EM_ROW)
+    take = max(capacity // EM_ROW, 1) + 1
+    first = (run_start // EM_ROW)[:, None]
+    picked = table[jnp.minimum(first + jnp.arange(take, dtype=first.dtype),
+                               table.shape[0] - 1)]
+    picked = picked.reshape(picked.shape[0], -1)
+    shift = (run_start % EM_ROW)[:, None]
+    for bit in range(EM_ROW.bit_length() - 1):
+        picked = jnp.where((shift >> bit) & 1 == 1,
+                           jnp.roll(picked, -(1 << bit), axis=1), picked)
+    return picked[:, :capacity]
+
+
+def parents_offsets_into_lanes(offsets, rows, valid, run_start=None):
+    """PR 31's ``offsets_into_lanes``, word for word."""
+    if run_start is None:
+        return jnp.where(valid, offsets[rows], 0.0)
+    picked = parents_runs_at(offsets, run_start, valid.shape[1])
+    if rows.shape[0]:
+        picked = jnp.concatenate([picked, offsets[rows]])
+    return jnp.where(valid, picked, 0.0)
+
+
+@pytest.mark.parametrize("run_lanes, index_lanes", [(0, 5), (9, 0), (9, 5)])
+@pytest.mark.parametrize("capacity", [4, 64, 128, 1024])
+def test_a_class_with_no_window_lane_traces_as_it_did(capacity, run_lanes,
+                                                      index_lanes):
+    """No window lane: the jaxpr of the class's gather is the parent's,
+    with run lanes (``run_start`` only) and without (None)."""
+    n = 5000
+    offsets = jnp.zeros(n, jnp.float32)
+    rows = jnp.zeros((index_lanes, capacity), jnp.int32)
+    valid = jnp.ones((run_lanes + index_lanes, capacity), bool)
+    start = jnp.zeros(run_lanes, jnp.int32) if run_lanes else None
+    ours = jax.make_jaxpr(offsets_into_lanes)(offsets, rows, valid, start)
+    parents = jax.make_jaxpr(parents_offsets_into_lanes)(
+        offsets, rows, valid, start)
+    assert str(ours) == str(parents)
+    if run_lanes:
+        windowed = jax.make_jaxpr(offsets_into_lanes)(
+            offsets, rows, jnp.ones((len(valid) + 1, capacity), bool), start,
+            jax.tree.map(jnp.asarray, bucketing.lane_windows(
+                np.arange(2 * capacity)[None, ::2])))
+        assert str(windowed) != str(ours)
+
+
+@pytest.mark.parametrize("capacity, lanes", [(8, 50), (64, 21), (256, 9)])
+def test_window_lanes_in_blocks_are_the_window_lanes(monkeypatch, capacity,
+                                                     lanes):
+    """``_windows_at`` reads its lanes block by block (a block's wide view
+    ``WINDOW_BLOCK_BYTES`` on the chip): the lanes do not depend on where
+    the blocks are cut, a last block that is not whole included."""
+    rng = np.random.default_rng(capacity)
+    n = 3 * bucketing.WINDOW_SPAN_MAX * capacity + 77
+    rows = np.full((lanes, capacity), -1, np.int64)
+    for lane in range(lanes):
+        k = int(rng.integers(2, capacity + 1))
+        span = int(rng.integers(k + 1, bucketing.WINDOW_SPAN_MAX * capacity + 1))
+        rows[lane, :k] = window_rows(
+            rng, int(rng.integers(0, n - span + 1)), k, span)
+    valid = rows >= 0
+    offsets = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    want = np.asarray(jnp.where(valid, offsets[np.maximum(rows, 0)], 0.0))
+    by = jax.tree.map(jnp.asarray, bucketing.lane_windows(rows))
+    none = jnp.zeros((0, capacity), jnp.int32)
+    whole = offsets_into_lanes(offsets, none, jnp.asarray(valid), None, by)
+    monkeypatch.setattr(bucketing, "WINDOW_BLOCK_BYTES", 1)  # 8 lanes a block
+    blocks = jax.make_jaxpr(offsets_into_lanes)(
+        offsets, none, jnp.asarray(valid), None, by)
+    assert str(blocks).count("concatenate") > 0
+    cut = offsets_into_lanes(offsets, none, jnp.asarray(valid), None, by)
+    assert np.array_equal(np.asarray(whole), want)
+    assert np.array_equal(np.asarray(cut), want)
