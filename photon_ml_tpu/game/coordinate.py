@@ -43,8 +43,10 @@ from photon_ml_tpu.obs.trace import device_scope
 from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.opt.solve import line_search_kind, make_solver
 from photon_ml_tpu.opt.types import SolverResult
-from photon_ml_tpu.parallel.bucketing import (bucket_by_entity, lane_windows,
+from photon_ml_tpu.parallel.bucketing import (block_pairs, block_slots,
+                                              bucket_by_entity, lane_windows,
                                               offsets_into_lanes,
+                                              sample_blocks,
                                               stacked_coefficients)
 from photon_ml_tpu.parallel.mesh import (SAMPLE_TILE, exchange_bytes,
                                          lanes_of, on_chips, over_chips,
@@ -933,7 +935,8 @@ class RandomEffectCoordinate(Coordinate):
         # coord.bucket: host grouping + the Python packing loops, the
         # full-sample layout (coord.rescore_layout) inside it;
         # coord.upload: the design's way onto the device
-        narrow, runs = False, None  # a dense shard over the footprint line
+        # a dense shard, a sparse shard's pairs over the footprint line
+        narrow, pairs_t, runs = False, False, None
         with obs_span("coord.bucket", coordinate=coordinate_id) as bucket_span:
             if self._sparse:
                 # Row-sparse RE feature bag (the reference's per-entity sparse
@@ -943,11 +946,20 @@ class RandomEffectCoordinate(Coordinate):
                 # bucket tensors never exist (bucket_by_entity_sparse).
                 # (projected_dim without RANDOM is rejected at CONFIG time —
                 # RandomEffectConfig.__post_init__ — so no guard here)
-                from photon_ml_tpu.parallel.bucketing import bucket_by_entity_sparse
+                from photon_ml_tpu.parallel.bucketing import (
+                    bucket_by_entity_sparse, entity_runs,
+                    use_transposed_scoring)
                 from photon_ml_tpu.parallel.projection import ProjectedBuckets
 
                 ratio = (config.features_to_samples_ratio
                          if config.projector == ProjectorType.INDEX_MAP else None)
+                # over the padded-footprint line the pairs, [n, k] of a
+                # small k, keep the samples on the lanes as a narrow dense
+                # design does, in blocks of samples
+                # (bucketing.score_samples_sparse_blocks); under a mesh
+                # they stay [n, k], each chip its rows
+                pairs_t = mesh is None and use_transposed_scoring(
+                    *shard_data.indices.shape, np.dtype(dtype).itemsize)
                 self.buckets, projections = bucket_by_entity_sparse(
                     entity_ids, shard_data.indices, shard_data.values, self.dim,
                     np.asarray(data.y, dtype),
@@ -959,6 +971,7 @@ class RandomEffectCoordinate(Coordinate):
                     features_to_samples_ratio=ratio,
                     intercept_index=config.intercept_index,
                     existing_model_keys=existing_model_keys,
+                    runs=entity_runs(entity_ids),
                 )
                 self._proj = ProjectedBuckets(base=self.buckets,
                                               buckets=self.buckets.buckets,
@@ -1122,6 +1135,21 @@ class RandomEffectCoordinate(Coordinate):
                 active_rows=sum(int(b.counts.sum()) for b in classes),
                 capped_entities=self.buckets.capped_entities,
                 passive_rows=self.buckets.passive_rows)
+            if self._proj is not None and "index" in self._proj_kinds:
+                # a compact coordinate: the width every class solves at, the
+                # columns its lanes keep and the columns that width holds
+                maps = self._proj.projections
+                bucket_span.set(
+                    projector=config.projector.name, d_full=self.dim,
+                    d_proj=[p.d_proj for p in maps],
+                    kept_columns=sum(int(np.count_nonzero(p.indices >= 0))
+                                     for p in maps),
+                    compact_columns=sum(p.indices.size for p in maps))
+                if self._sparse:
+                    bucket_span.set(
+                        row_width=int(shard_data.indices.shape[1]),
+                        observed_columns=self.buckets.observed_columns,
+                        filtered_entities=self.buckets.filtered_entities)
             # slot order for the stacked model = sorted entity id (stacked_coefficients)
             self._sorted_ids = sorted(self.buckets.lane_of)
             self._slot_of = {eid: i for i, eid in enumerate(self._sorted_ids)}
@@ -1172,10 +1200,25 @@ class RandomEffectCoordinate(Coordinate):
                 else:
                     self._x_full_is_t = narrow
                     self._slot_ids = np.asarray(entity_ids, np.int64)
-                    layout_span.set(layout="sparse" if self._sparse
-                                    else "transposed" if narrow
-                                    else "row_major")
-                slots = self._put_slots(self._slots_under(self._slot_of))
+                    if not self._sparse:
+                        layout_span.set(layout="transposed" if narrow
+                                        else "row_major")
+                slots = self._slots_under(self._slot_of)
+                # a sparse shard's pairs by blocks of samples: how many
+                # blocks, and how much of the table one reads, by the slots
+                self._pair_blocks, self._table_rows = 0, None
+                if pairs_t:
+                    self._pair_blocks = sample_blocks(
+                        slots, self.dim * np.dtype(dtype).itemsize)
+                    by_block, self._table_rows = self._slots_in_blocks(slots)
+                else:
+                    slots = self._put_slots(slots)
+                if self._sparse:
+                    layout_span.set(
+                        layout="sparse", blocks=self._pair_blocks,
+                        table_rows=self._table_rows,
+                        row_width=int(shard_data.indices.shape[1]),
+                        nonzeros=int(np.count_nonzero(shard_data.values)))
         from photon_ml_tpu.parallel.bucketing import (entity_major_design,
                                                       entity_major_design_over)
         with _upload_span(coordinate_id, mesh) as placed:
@@ -1215,12 +1258,19 @@ class RandomEffectCoordinate(Coordinate):
                     self._full = dict(slots=slots, x_full=put(
                         x.T, 1) if self._x_full_is_t else put(x))
             elif self._sparse:
-                # full-sample scoring stays sparse: [n, k] gather arrays, never
-                # an [n, d_full] densified design (score_samples_sparse)
+                # full-sample scoring stays sparse: the pairs [n, k] or, by
+                # blocks of samples, [blocks, k, r]; never an [n, d_full]
+                # densified design (score_samples_sparse[_blocks])
+                def pairs(a, as_type):
+                    a = np.asarray(a, as_type)
+                    return device_put_counted(
+                        block_pairs(a, self._pair_blocks)
+                        if self._pair_blocks else a)
+
                 self._full = dict(
-                    slots=slots,
-                    x_idx=device_put_counted(shard_data.indices, np.int32),
-                    x_val=device_put_counted(shard_data.values, dtype))
+                    by_block if self._pair_blocks else dict(slots=slots),
+                    x_idx=pairs(shard_data.indices, np.int32),
+                    x_val=pairs(shard_data.values, dtype))
             elif self._em is not None:
                 self._full = dict(
                     lane_slot=slots,
@@ -1346,6 +1396,15 @@ class RandomEffectCoordinate(Coordinate):
         if slots.ndim == 2:  # the layout's R is a multiple of the devices
             return put_over_chips(slots, self.mesh, 1, slots.shape[1])
         return put_over_chips(slots, self.mesh, fill=-1)
+
+    def _slots_in_blocks(self, slots: np.ndarray) -> Tuple[dict, int]:
+        """A per-sample slot vector as a sparse shard's blocked pairs are
+        scored by it (``bucketing.score_samples_sparse_blocks``): the
+        entries of ``sweep_data`` it makes, and how many rows of the table
+        one block reads (static: the program's shape)."""
+        by_block, first, table_rows = block_slots(slots, self._pair_blocks)
+        return (dict(slots=jnp.asarray(by_block), first=jnp.asarray(first)),
+                table_rows)
 
     def _offsets_into_lanes(self, offsets: Array, devs):
         """``gather(bi)``: the residual offsets of bucket ``bi``'s lanes,
@@ -1977,13 +2036,18 @@ class RandomEffectCoordinate(Coordinate):
         and ``slot_of``: this coordinate's own map, or a foreign one (a
         model trained elsewhere: an entity may be absent from our training
         buckets yet present in the model)."""
-        data = self._full
+        data, table_rows = self._full, self._table_rows
         if only is not None or slot_of != self._slot_of:
-            key = "slots" if self._em is None else "lane_slot"
-            data = dict(data, **{key: self._put_slots(
-                self._slots_under(slot_of, only))})
+            slots = self._slots_under(slot_of, only)
+            if self._pair_blocks:  # what a block reads, under THIS map
+                by_block, table_rows = self._slots_in_blocks(slots)
+                data = dict(data, **by_block)
+            else:
+                key = "slots" if self._em is None else "lane_slot"
+                data = dict(data, **{key: self._put_slots(slots)})
         w = jnp.asarray(np.asarray(w_stack, self._dtype))
-        return np.asarray(self._score_samples_full(w, data))[: self._n]
+        return np.asarray(self._score_samples_full(
+            w, data, table_rows))[: self._n]
 
     def carry_through_scores(self, init: Optional[RandomEffectModel]
                              ) -> Optional[np.ndarray]:
@@ -2000,14 +2064,16 @@ class RandomEffectCoordinate(Coordinate):
     def score(self, model: RandomEffectModel) -> np.ndarray:
         return self._score_full(model.w_stack, model.slot_of)
 
-    def _score_samples_full(self, w_stack: Array, data) -> Array:
+    def _score_samples_full(self, w_stack: Array, data,
+                            table_rows: Optional[int] = None) -> Array:
         """Every sample's score in whichever layout the full-sample design
-        has (``data``: what ``sweep_data`` passes of it): sparse,
-        entity-major, [d, n] or [n, d] (parallel/bucketing.py)."""
-        from photon_ml_tpu.parallel.bucketing import (score_samples,
-                                                      score_samples_em,
-                                                      score_samples_sparse,
-                                                      score_samples_t)
+        has (``data``: what ``sweep_data`` passes of it): sparse (by rows or
+        by blocks of samples, of which one reads ``table_rows`` of the
+        table: this coordinate's own where not given), entity-major,
+        [d, n] or [n, d] (parallel/bucketing.py)."""
+        from photon_ml_tpu.parallel.bucketing import (
+            score_samples, score_samples_em, score_samples_sparse,
+            score_samples_sparse_blocks, score_samples_t)
 
         if self._em is not None:
             if self.mesh is not None:
@@ -2016,6 +2082,10 @@ class RandomEffectCoordinate(Coordinate):
                                           self.mesh)
             return score_samples_em(w_stack, data["lane_slot"], data["x_em"],
                                     data["way_back"])
+        if self._pair_blocks:
+            return score_samples_sparse_blocks(
+                w_stack, data["slots"], data["first"], data["x_idx"],
+                data["x_val"], table_rows or self._table_rows)
         if self._sparse:
             score, design = score_samples_sparse, (data["x_idx"],
                                                    data["x_val"])
@@ -2125,9 +2195,11 @@ class RandomEffectCoordinate(Coordinate):
             if data is None:
                 data = self.sweep_data()
             proj = data["proj"]
-            state = tuple(self._traced_back_project(bi, proj[bi], lanes,
-                                                    fill=data.get("box_fill"))
-                          for bi, lanes in enumerate(state))
+            with device_scope("backproject"):
+                state = tuple(
+                    self._traced_back_project(bi, proj[bi], lanes,
+                                              fill=data.get("box_fill"))
+                    for bi, lanes in enumerate(state))
         if self.mesh is not None:
             return stack_lanes(state, self._slot_idx_dev,
                                len(self._sorted_ids), self.mesh)

@@ -21,6 +21,7 @@ handful of dense [E, S, d] batched programs on the MXU.
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -108,6 +109,10 @@ class EntityBuckets:
     compact: bool = False
     capped_entities: int = 0  # entities over the active cap
     passive_rows: int = 0     # their rows outside the reservoir: scored only
+    # compact buckets: the columns the entities observed in their active
+    # rows, summed, and the entities a features-to-samples bound cut
+    observed_columns: int = 0
+    filtered_entities: int = 0
 
     def entity_ids(self) -> np.ndarray:
         return np.asarray(sorted(self.lane_of), np.int64)
@@ -400,6 +405,116 @@ def bucket_by_entity(
                          **_passive(kept_rows, rescale))
 
 
+# Active rows whose pairs one block of ``bucket_by_entity_sparse`` holds on
+# the host at a time, the (lane, column) cells up to which a block's pairs
+# are grouped by counting rather than by sorting, and the blocks in flight.
+_COMPACT_BLOCK_ROWS = 1 << 20
+_COMPACT_COUNTED_CELLS = 1 << 26
+_COMPACT_WORKERS = 4
+
+
+def _merged_pairs(iv: np.ndarray, vv: np.ndarray):
+    """``(indices, values, seen)`` [rows, k] of row-sparse pairs with each
+    row's duplicate columns merged into one pair (their values accumulate,
+    as ``SparseBatch`` margins do); ``seen`` marks the pairs that stand for
+    a nonzero entry.  A row whose nonzero pairs rise strictly by column, up
+    to a tail of zero-valued padding, has no duplicate and is left as it
+    is; only the others are sorted, in place (the caller's arrays are its
+    own copies of the rows)."""
+    seen = vv != 0
+    if iv.shape[1] < 2:
+        return iv, vv, seen
+    tail = np.logical_and.accumulate(~seen[:, ::-1], axis=1)[:, ::-1]
+    mixed = np.flatnonzero(~np.all((iv[:, 1:] > iv[:, :-1]) | tail[:, 1:],
+                                   axis=1))
+    if mixed.size:
+        order = np.argsort(iv[mixed], axis=1, kind="stable")
+        si = np.take_along_axis(iv[mixed], order, 1)
+        sv = np.take_along_axis(vv[mixed], order, 1)
+        sn = sv != 0
+        for j in range(si.shape[1] - 1, 0, -1):
+            same = si[:, j] == si[:, j - 1]
+            sv[:, j - 1] += np.where(same, sv[:, j], 0)
+            sn[:, j - 1] |= same & sn[:, j]
+            sv[same, j] = 0
+            sn[same, j] = False
+        iv[mixed], vv[mixed], seen[mixed] = si, sv, sn
+    return iv, vv, seen
+
+
+def _distinct(key: np.ndarray, size: int):
+    """``np.unique(key, return_inverse=True)`` for keys in ``[0, size)``:
+    by counting where the key space is small, else by sorting."""
+    if size > _COMPACT_COUNTED_CELLS:
+        return np.unique(key, return_inverse=True)
+    hit = np.bincount(key, minlength=size) > 0
+    return np.flatnonzero(hit), (np.cumsum(hit) - 1)[key]
+
+
+def _compact_lanes(rows_of: List[np.ndarray], indices: np.ndarray,
+                   values: np.ndarray, y: np.ndarray, weight: np.ndarray,
+                   dim: int, ratio: Optional[float],
+                   intercept_index: Optional[int]):
+    """The compact bases of a block of lanes, all lanes at once and at the
+    cost of their nonzero pairs: ``rows_of[l]`` the active rows of lane
+    ``l``.  Returns ``(lane, column, position)`` of every column a lane
+    KEEPS (its observed columns, ascending; under ``ratio`` the
+    ``max(1, ceil(ratio x rows))`` of them that rank highest by |Pearson
+    correlation| with the label over the lane's active rows, ties to the
+    lower column, ``intercept_index`` always: ``pearson_top_k``'s rule, from
+    weighted sums in float64), ``(lane, slot, position, value)`` of every
+    entry of the lanes' compact design blocks, and ``(columns observed,
+    lanes the bound cut)``."""
+    m, k = len(rows_of), indices.shape[1]
+    lens = np.fromiter(map(len, rows_of), np.int64, m)
+    rows = np.concatenate(rows_of) if m else np.empty(0, np.int64)
+    lane = np.repeat(np.arange(m), lens)
+    slot = np.arange(len(rows)) - np.repeat(np.cumsum(lens) - lens, lens)
+    iv, vv, seen = _merged_pairs(indices[rows], values[rows])
+    at = np.flatnonzero(seen.ravel())           # the entries, row by row
+    r, val = at // k, vv.ravel()[at]
+    cells, cell_of = _distinct((lane * dim)[r] + iv.ravel()[at], m * dim)
+    cell_lane, cell_col = cells // dim, cells % dim
+    observed = np.bincount(cell_lane, minlength=m)
+    first = np.cumsum(observed) - observed      # a lane's first cell
+    keep = np.ones(len(cells), bool)
+    cut = 0
+    if ratio is not None:
+        keep_n = np.maximum(1, np.ceil(ratio * lens)).astype(np.int64)
+        cut = int(np.count_nonzero(observed > keep_n))
+        if cut:
+            w = weight[rows].astype(np.float64)
+            wy = w * y[rows]
+            total = np.maximum(np.bincount(lane, w, m), 1e-12)[cell_lane]
+            my = np.bincount(lane, wy, m)[cell_lane] / total
+            vy = np.bincount(lane, wy * y[rows], m)[cell_lane] / total \
+                - my * my
+            u = len(cells)
+            wx = w[r] * val
+            mx = np.bincount(cell_of, wx, u) / total
+            vx = np.bincount(cell_of, wx * val, u) / total - mx * mx
+            cov = np.bincount(cell_of, wy[r] * val, u) / total - mx * my
+            denom = np.sqrt(np.maximum(vx * vy, 0.0))
+            with np.errstate(invalid="ignore", divide="ignore"):
+                score = np.where(denom > 0, np.abs(cov) / denom, 0.0)
+            score[vx <= 1e-12 * np.maximum(1.0, mx * mx)] = 0.0
+            if intercept_index is not None:
+                score[cell_col == intercept_index] = np.inf
+            # cells lie by (lane, column); a stable sort by (lane, -score)
+            # ranks a lane's columns with ties to the lower one
+            order = np.lexsort((-score, cell_lane))
+            rank = np.empty(u, np.int64)
+            rank[order] = np.arange(u) - first[cell_lane[order]]
+            keep = rank < keep_n[cell_lane]
+    before = np.concatenate([[0], np.cumsum(keep)])
+    position = before[:-1] - before[first][cell_lane]
+    held = np.flatnonzero(keep[cell_of])
+    r = r[held]
+    return ((cell_lane[keep], cell_col[keep], position[keep]),
+            (lane[r], slot[r], position[cell_of[held]], val[held]),
+            (len(cells), cut))
+
+
 def bucket_by_entity_sparse(
     entity_ids: np.ndarray,
     indices: np.ndarray,
@@ -418,6 +533,7 @@ def bucket_by_entity_sparse(
     existing_model_keys: Optional[frozenset] = None,
     row_ids: Optional[np.ndarray] = None,
     num_samples: Optional[int] = None,
+    runs: Optional[EntityRuns] = None,
 ):
     """Compact per-entity buckets built DIRECTLY from row-sparse features.
 
@@ -437,19 +553,21 @@ def bucket_by_entity_sparse(
     row ACCUMULATE, matching core/batch.SparseBatch margins).
     ``features_to_samples_ratio``/``intercept_index``: per-entity top-k
     |Pearson| feature filter exactly as build_observed_indices applies it to
-    dense buckets (LocalDataset.scala:185-247).
+    dense buckets (LocalDataset.scala:185-247).  A class's lanes are
+    compacted together (``_compact_lanes``), in blocks of lanes: no loop an
+    entity over the pairs.
+    ``runs``: ``entity_runs(entity_ids)`` where the caller has it already.
 
     Returns ``(EntityBuckets, projections)`` — compact buckets plus one
     BucketProjection per bucket mapping compact columns back to the full
     vocabulary (``EntityBuckets.dim`` stays the FULL dimension).
     """
     from photon_ml_tpu.parallel.projection import (BucketProjection,
-                                                   _pow2_at_least,
-                                                   pearson_top_k)
+                                                   _pow2_at_least)
 
     n = len(entity_ids)
     entity_ids = np.asarray(entity_ids, np.int64)
-    indices = np.asarray(indices, np.int64)
+    indices = np.asarray(indices)
     values = np.asarray(values, dtype)
     y = np.asarray(y, dtype)
     offset = np.zeros(n, dtype) if offset is None else np.asarray(offset, dtype)
@@ -459,45 +577,53 @@ def bucket_by_entity_sparse(
         row_ids = np.asarray(row_ids, np.int64)
     kept_rows, kept_entities, rescale = _group_rows(
         entity_ids, active_cap, min_active_samples, seed,
-        existing_model_keys=existing_model_keys, row_ids=row_ids)
-
-    def _compact_lane(rows: np.ndarray):
-        """(observed columns, compact dense block [len(rows), n_obs])."""
-        iv, vv = indices[rows], values[rows]
-        nz_r, nz_c = np.nonzero(vv != 0)
-        obs = np.unique(iv[nz_r, nz_c]) if nz_r.size else np.empty(0, np.int64)
-        x = np.zeros((len(rows), len(obs)), dtype)
-        if nz_r.size:
-            pos = np.searchsorted(obs, iv[nz_r, nz_c])
-            np.add.at(x, (nz_r, pos), vv[nz_r, nz_c])  # duplicates accumulate
-        if features_to_samples_ratio is not None and obs.size:
-            keep_n = max(1, int(np.ceil(features_to_samples_ratio * len(rows))))
-            if obs.size > keep_n:
-                top = pearson_top_k(x, y[rows], weight[rows], obs, keep_n,
-                                    intercept_index)
-                obs, x = obs[top], x[:, top]
-        return obs.astype(np.int32), x
+        existing_model_keys=existing_model_keys, row_ids=row_ids, runs=runs)
 
     caps = _capacity_classes(kept_rows)
     buckets: List[Bucket] = []
     projections: List[object] = []
     lane_of: Dict[int, Tuple[int, int]] = {}
+    classes, blocks = [], []
     for cap in sorted(set(caps.tolist())):
         lanes, run_lanes, window_lanes = _class_lanes(
             np.nonzero(caps == cap)[0], kept_rows, cap, lane_multiple, row_ids)
-        compacted = {lane: _compact_lane(kept_rows[ei])
-                     for lane, ei in enumerate(lanes) if ei >= 0}
-        d_proj = _pow2_at_least(max((len(o) for o, _ in compacted.values()),
-                                    default=1))
-        d_proj = min(d_proj, dim)
+        live = np.flatnonzero(lanes >= 0)
+        # lanes a block, about 1M active rows: the host's peak follows the
+        # blocks in flight and not the class
+        per_block = max(1, _COMPACT_BLOCK_ROWS // cap)
+        blocks += [(len(classes), live[at:at + per_block])
+                   for at in range(0, len(live), per_block)]
+        classes.append((cap, lanes, run_lanes, window_lanes))
+
+    def compact(block):
+        ci, live = block
+        return _compact_lanes(
+            [kept_rows[ei] for ei in classes[ci][1][live]], indices, values,
+            y, weight, dim, features_to_samples_ratio, intercept_index)
+
+    # the blocks are independent and numpy lets go of the interpreter in
+    # nearly all of their work
+    with concurrent.futures.ThreadPoolExecutor(_COMPACT_WORKERS) as pool:
+        compacted = list(pool.map(compact, blocks))
+    observed_columns = sum(seen[0] for _, _, seen in compacted)
+    filtered_entities = sum(seen[1] for _, _, seen in compacted)
+    for ci, (cap, lanes, run_lanes, window_lanes) in enumerate(classes):
+        mine = [(live, kept, entries) for (at, live), (kept, entries, _)
+                in zip(blocks, compacted) if at == ci]
+        # one width a class: the power of two over its widest lane
+        widest = max((int(pos.max()) + 1 for _, (_, _, pos), _ in mine
+                      if len(pos)), default=1)
+        d_proj = min(_pow2_at_least(widest), dim)
         by, boff, bw, brows, bcounts, blanes = _pack_lane_meta(
             cap, lanes, kept_rows, kept_entities, rescale,
             y, offset, weight, dtype, lane_of, len(buckets), row_ids=row_ids)
         bx = np.zeros((len(lanes), cap, d_proj), dtype)
         bidx = np.full((len(lanes), d_proj), -1, np.int32)
-        for lane, (obs, x) in compacted.items():
-            bx[lane, :len(x), :len(obs)] = x
-            bidx[lane, :len(obs)] = obs
+        slots = bx.reshape(-1, d_proj)
+        for live, (k_lane, k_col, k_pos), (e_lane, e_slot, e_pos, e_val) \
+                in mine:
+            bidx[live[k_lane], k_pos] = k_col
+            slots[live[e_lane] * cap + e_slot, e_pos] = e_val
         buckets.append(Bucket(x=bx, y=by, offset=boff, weight=bw, rows=brows,
                               counts=bcounts, entity_lanes=blanes,
                               run_lanes=run_lanes, window_lanes=window_lanes))
@@ -506,7 +632,9 @@ def bucket_by_entity_sparse(
     ents = EntityBuckets(buckets=buckets, lane_of=lane_of, dim=dim,
                          num_entities=len(kept_entities),
                          num_samples=n if num_samples is None else num_samples,
-                         compact=True, **_passive(kept_rows, rescale))
+                         compact=True, observed_columns=observed_columns,
+                         filtered_entities=filtered_entities,
+                         **_passive(kept_rows, rescale))
     return ents, projections
 
 
@@ -1246,6 +1374,97 @@ def score_samples_sparse(w_stack: Array, slots: Array, indices: Array,
     gathered = w_stack[safe[:, None], indices]  # [n, k]
     margins = jnp.sum(gathered * values, axis=-1)
     return jnp.where(slots >= 0, margins, 0.0)
+
+
+# The part of a coefficient table one gather of the sparse rescore may read.
+# On a v5e a gathered coefficient costs 7.2 to 7.6 ns out of a table of up
+# to 64 MB, 12.5 ns out of 128 MB and 15.8 ns out of 301 MB (PERF.md
+# section 6, PR 36).
+SPARSE_TABLE_BYTES_MAX = 1 << 26
+SPARSE_BLOCKS_MAX = 1 << 10
+
+
+def sample_blocks(slots: np.ndarray, row_bytes: int) -> int:
+    """Into how many equal blocks (a power of two) ``block_slots`` cuts the
+    sample axis for ``score_samples_sparse_blocks``: the fewest whose
+    samples' slots span at most ``SPARSE_TABLE_BYTES_MAX`` of a table of
+    ``row_bytes`` a slot.  Rows that arrive grouped by entity get there
+    (the slots rise with the samples); rows that lie anywhere never do, and
+    stay one block."""
+    blocks = 1
+    while blocks <= SPARSE_BLOCKS_MAX:
+        if block_slots(slots, blocks)[2] * row_bytes <= SPARSE_TABLE_BYTES_MAX:
+            return blocks
+        blocks *= 2
+    return 1
+
+
+def block_slots(slots: np.ndarray, blocks: int
+                ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """``(slots [blocks, r], first [blocks], table_rows)``: the per-sample
+    slot vector cut into equal blocks (the tail padded with -1), the lowest
+    slot of each block and the most slots one block spans."""
+    slots = np.asarray(slots, np.int32)
+    rows = max(1, -(-len(slots) // blocks))
+    cut = np.full(blocks * rows, -1, np.int32)
+    cut[:len(slots)] = slots
+    cut = cut.reshape(blocks, rows)
+    has = cut >= 0
+    first = np.where(has, cut, np.iinfo(np.int32).max).min(axis=1)
+    last = cut.max(axis=1)
+    first = np.where(last >= 0, first, 0).astype(np.int32)
+    return cut, first, int(np.max(np.where(last >= 0, last - first + 1, 1)))
+
+
+def block_pairs(a: np.ndarray, blocks: int) -> np.ndarray:
+    """A sparse shard's [n, k] array as ``score_samples_sparse_blocks``
+    reads it: [blocks, k, r], the samples on the lanes, the tail zero."""
+    n, k = a.shape
+    rows = max(1, -(-n // blocks))
+    padded = np.zeros((blocks * rows, k), a.dtype)
+    padded[:n] = a
+    return np.ascontiguousarray(
+        padded.reshape(blocks, rows, k).transpose(0, 2, 1))
+
+
+def score_samples_sparse_blocks(w_stack: Array, slots: Array, first: Array,
+                                indices: Array, values: Array,
+                                table_rows: int) -> Array:
+    """``score_samples_sparse`` for pairs stored by BLOCKS of samples with
+    the samples on the lanes: ``indices`` / ``values`` [blocks, k, r],
+    ``slots`` [blocks, r] (-1: no model, and the tail's padding), ``first``
+    [blocks] the lowest slot of each block, ``table_rows`` (static) the
+    most slots one block spans (``block_slots``).  Returns [blocks x r]:
+    the samples, then the tail's zeros.
+
+    The samples go on the lanes for ``score_samples_t``'s reason (an [n, k]
+    array of k <= 32 takes 128 / k times its bytes in HBM, and so does
+    every [n, k] gather out of it: ``use_transposed_scoring``).  A block
+    gathers out of ITS rows of the table laid flat, k gathers of r
+    coefficients at ``(slot - first) x d + column``: a gathered coefficient
+    costs by the size of what it is gathered out of (see
+    ``SPARSE_TABLE_BYTES_MAX``).  Any slots score right, whatever they
+    span; what they span decides the speed."""
+    entities, d = w_stack.shape
+    table_rows = min(int(table_rows), entities)
+    if table_rows * d >= 1 << 31:
+        raise ValueError(
+            f"{table_rows} rows of a coefficient table {d} wide cannot be "
+            "addressed flat by int32")
+
+    def one(block):
+        slot, lowest, idx, val = block
+        start = jnp.clip(lowest, 0, entities - table_rows)
+        table = jax.lax.dynamic_slice(w_stack, (start, jnp.zeros_like(start)),
+                                      (table_rows, d)).reshape(-1)
+        at = jnp.where(slot >= 0, slot - start, 0) * d
+        acc = jnp.zeros(slot.shape[0],
+                        jnp.promote_types(val.dtype, w_stack.dtype))
+        for j in range(idx.shape[0]):  # k is static and small by contract
+            acc = acc + val[j] * table[at + idx[j]]
+        return jnp.where(slot >= 0, acc, 0.0)
+
+    return jax.lax.map(one, (slots, first, indices, values)).reshape(-1)
 
 
 def gather_entity_coefficients(

@@ -655,6 +655,50 @@ def test_traced_dry_run_of_the_tuning_cell(tmp_path):
     assert m["xtune_host_ms_per_trial"] > m["xtune_evaluate_ms_per_trial"]
 
 
+def test_traced_dry_run_of_the_userbag_cell(tmp_path):
+    """``glmix_userbag_ml20m.train`` (ISSUE 36): a traced CPU dry run over a
+    per-user effect on the item's sparse bag ends ``correct`` against the
+    projected reference (every class reached, exact zeros off the kept
+    columns, every row of the capped users), compiles nothing in the
+    window, reports the span metrics of the compaction and leaves out what
+    needs a device trace."""
+    cell = "glmix_userbag_ml20m.train"
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
+         "--seed", "3600000019", "--seconds", "1", "--trace", "1",
+         "--dry-run"],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        timeout=600,
+        env={**os.environ, "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla")})
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"], line["checks"]
+    assert line["checks"]["no_compile_in_window"]
+    assert line["checks"]["zero_off_support"]
+    assert line["checks"]["every_class_reached"]
+    catalog = harness.Catalog()
+    listed = catalog.json("workloads", cell)["per_layer"]
+    want = {n for n in listed
+            if catalog.json("layer_metrics", n)["source"] != "device_trace"}
+    assert set(line["metrics"]) == want
+    assert {"xuserbag_compact_fill", "xuserbag_bucket_s", "solve_classes",
+            "solve_slot_fill"} <= want
+    assert {"xuserbag_rescore_busy_share", "xuserbag_rescore_hbm_share",
+            "xuserbag_backproject_busy_share", "device_idle_share",
+            "fused_glm_busy_share"} <= set(listed) - want
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    assert m["solve_classes"] == 14  # 1 to 64 rows: seven powers of two each
+    assert 0 < m["xuserbag_compact_fill"] <= 100
+    assert 0 < m["xuserbag_bucket_s"] < m["coord_build_s"]
+    bag = line["detail"]["bag_checks"]["per-user"]
+    assert bag["entities"] >= 64 and bag["classes_reached"] == bag["classes"]
+    assert bag["off_support"] == 0 and bag["p10"] < 3e-4 <= 1
+    assert bag["passive_entities"] == 8 and bag["passive_rows"] > 0
+    assert bag["score_err"] < 1e-5   # the sparse rescore alone: float32 rounding
+    assert bag["passive_err"] < 5e-2
+    assert line["detail"]["reference_dtype"] == "float32"
+
+
 # -- (viii) a cache another tree filled ---------------------------------------
 
 def test_table_is_this_trees_in_a_cache_another_tree_filled(

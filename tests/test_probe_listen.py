@@ -329,8 +329,11 @@ def test_tool_writes_all_five_cells_and_one_runs_traced(tmp_path):
     account = tmp_path / "account"
     path = layer_cells_all.write(str(tmp_path / "cells"), str(account))
     catalog = harness.Catalog(path)
-    cells = harness.Catalog().names("workloads")
-    assert sorted(layer_cells_all.APPENDED) == sorted(cells) and len(cells) == 5
+    cells = sorted(layer_cells_all.APPENDED)
+    # the five cells the benchmark had when the tool was written: a later
+    # cell waits for a ``benchmark`` PR to list it there (PERF.md section 7)
+    assert len(cells) == 5 and set(harness.Catalog().names("workloads")) - set(
+        cells) == {"glmix_userbag_ml20m.train"}
     for cell in cells:
         listed = catalog.json("workloads", cell + layer_cells_all.SUFFIX)[
             "per_layer"]
