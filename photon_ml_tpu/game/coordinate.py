@@ -44,9 +44,11 @@ from photon_ml_tpu.obs.trace import span as obs_span
 from photon_ml_tpu.opt.solve import line_search_kind, make_solver
 from photon_ml_tpu.opt.types import SolverResult
 from photon_ml_tpu.parallel.bucketing import (block_pairs, block_slots,
-                                              bucket_by_entity, lane_windows,
-                                              offsets_into_lanes,
-                                              sample_blocks,
+                                              bucket_by_entity,
+                                              entity_major_pairs, lane_windows,
+                                              offsets_into_lanes, pair_planes,
+                                              pair_words, pick_block,
+                                              sample_blocks, score_pairs_full,
                                               stacked_coefficients)
 from photon_ml_tpu.parallel.mesh import (SAMPLE_TILE, exchange_bytes,
                                          lanes_of, on_chips, over_chips,
@@ -960,6 +962,7 @@ class RandomEffectCoordinate(Coordinate):
                 # they stay [n, k], each chip its rows
                 pairs_t = mesh is None and use_transposed_scoring(
                     *shard_data.indices.shape, np.dtype(dtype).itemsize)
+                runs = entity_runs(entity_ids)
                 self.buckets, projections = bucket_by_entity_sparse(
                     entity_ids, shard_data.indices, shard_data.values, self.dim,
                     np.asarray(data.y, dtype),
@@ -971,7 +974,7 @@ class RandomEffectCoordinate(Coordinate):
                     features_to_samples_ratio=ratio,
                     intercept_index=config.intercept_index,
                     existing_model_keys=existing_model_keys,
-                    runs=entity_runs(entity_ids),
+                    runs=runs,
                 )
                 self._proj = ProjectedBuckets(base=self.buckets,
                                               buckets=self.buckets.buckets,
@@ -1175,6 +1178,11 @@ class RandomEffectCoordinate(Coordinate):
                                                           entity_major_layout)
             self._em = None
             self._x_full_is_t = False
+            # a sparse shard's pairs over the footprint line go entity-major
+            # too where every entity's published row is exactly zero off
+            # its compact columns: they are then scored from the compact
+            # lanes (bucketing.score_pairs_em), D = the widest d_proj
+            self._pick_columns = self._compact_pick_columns() if pairs_t else 0
             with obs_span("coord.rescore_layout",
                           coordinate=coordinate_id) as layout_span:
                 if narrow:
@@ -1183,6 +1191,10 @@ class RandomEffectCoordinate(Coordinate):
                     self._em = entity_major_layout(
                         runs, 1 if mesh is None else lane_multiple
                         * SAMPLE_TILE // EM_ROW)
+                elif self._pick_columns:
+                    self._em = entity_major_layout(runs)
+                    if self._em is None:
+                        self._pick_columns = 0
                 if (mesh is not None and self._em is not None
                         and self._em.pos is None and self._em.lanes
                         * self._em.chunk != self.carry_samples):
@@ -1207,7 +1219,7 @@ class RandomEffectCoordinate(Coordinate):
                 # a sparse shard's pairs by blocks of samples: how many
                 # blocks, and how much of the table one reads, by the slots
                 self._pair_blocks, self._table_rows = 0, None
-                if pairs_t:
+                if pairs_t and not self._pick_columns:
                     self._pair_blocks = sample_blocks(
                         slots, self.dim * np.dtype(dtype).itemsize)
                     by_block, self._table_rows = self._slots_in_blocks(slots)
@@ -1215,10 +1227,18 @@ class RandomEffectCoordinate(Coordinate):
                     slots = self._put_slots(slots)
                 if self._sparse:
                     layout_span.set(
-                        layout="sparse", blocks=self._pair_blocks,
+                        layout="sparse", pairs="entity_major"
+                        if self._pick_columns else "blocks"
+                        if self._pair_blocks else "rows",
+                        blocks=self._pair_blocks,
                         table_rows=self._table_rows,
                         row_width=int(shard_data.indices.shape[1]),
                         nonzeros=int(np.count_nonzero(shard_data.values)))
+                if self._pick_columns:
+                    chunk_row, words, kept_pairs = self._compact_pairs(
+                        runs, shard_data)
+                    layout_span.set(pick_columns=self._pick_columns,
+                                    kept_pairs=kept_pairs)
         from photon_ml_tpu.parallel.bucketing import (entity_major_design,
                                                       entity_major_design_over)
         with _upload_span(coordinate_id, mesh) as placed:
@@ -1257,6 +1277,23 @@ class RandomEffectCoordinate(Coordinate):
                 else:
                     self._full = dict(slots=slots, x_full=put(
                         x.T, 1) if self._x_full_is_t else put(x))
+            elif self._pick_columns:
+                # the pairs entity-major [blocks, k, block, EM_ROW], a word a
+                # pair (its column and its compact place) and its value,
+                # reordered on the device; each chunk's compact lane
+                # (score_pairs_em), and its slot for full-width tables
+                # (score_pairs_full)
+                values = pair_planes(shard_data.values, dtype)
+                block = pick_block(self._em, self._pick_columns,
+                                   np.dtype(dtype).itemsize)
+                self._full = dict(
+                    lane_slot=slots, chunk_row=jnp.asarray(chunk_row),
+                    x_word=entity_major_pairs(
+                        self._em, device_put_counted(words), block),
+                    x_val=entity_major_pairs(
+                        self._em, device_put_counted(values), block),
+                    way_back=jax.tree.map(jnp.asarray, way_back))
+                del words, values
             elif self._sparse:
                 # full-sample scoring stays sparse: the pairs [n, k] or, by
                 # blocks of samples, [blocks, k, r]; never an [n, d_full]
@@ -1405,6 +1442,42 @@ class RandomEffectCoordinate(Coordinate):
         by_block, first, table_rows = block_slots(slots, self._pair_blocks)
         return (dict(slots=jnp.asarray(by_block), first=jnp.asarray(first)),
                 table_rows)
+
+    def _compact_pick_columns(self) -> int:
+        """D, the widest class's ``d_proj``, where a sparse shard's pairs
+        can be scored from the compact lanes (``score_pairs_em``), else 0:
+        every class compacted by an index map and no box fill, so an
+        entity's published row is exactly zero off its compact columns, and
+        a column and a place fit one int32 word."""
+        from photon_ml_tpu.parallel.projection import BucketProjection
+
+        maps = self._proj.projections if self._proj is not None else []
+        if (not maps or self._box_fill is not None
+                or not all(isinstance(p, BucketProjection) for p in maps)):
+            return 0
+        width = max(p.d_proj for p in maps)
+        return width if self.dim << width.bit_length() <= 1 << 31 else 0
+
+    def _compact_pairs(self, runs, shard: SparseShard):
+        """``(chunk_row [k_em, R], words [k, n + 1], kept_pairs)`` of the
+        entity-major layout ``self._em``: each chunk's lane among every
+        class's compact lanes laid side by side (-1: no lane), and the
+        pairs' words (``bucketing.pair_words``)."""
+        width = self._pick_columns
+        classes = self.buckets.buckets
+        lane_ids = np.concatenate([np.asarray(b.entity_lanes, np.int64)
+                                   for b in classes])
+        lane_columns = np.concatenate([
+            np.pad(p.indices, ((0, 0), (0, width - p.d_proj)),
+                   constant_values=-1) for p in self._proj.projections])
+        valid = lane_ids >= 0
+        lane_entity = np.where(
+            valid, np.searchsorted(self._em.entities, lane_ids), -1)
+        row_of = np.full(len(self._em.entities), -1, np.int32)
+        row_of[lane_entity[valid]] = np.flatnonzero(valid)
+        words, kept = pair_words(shard.indices, runs, lane_entity,
+                                 lane_columns, shard.values)
+        return self._em.lane_slots(row_of), words, kept
 
     def _offsets_into_lanes(self, offsets: Array, devs):
         """``gather(bi)``: the residual offsets of bucket ``bi``'s lanes,
@@ -2046,6 +2119,16 @@ class RandomEffectCoordinate(Coordinate):
                 key = "slots" if self._em is None else "lane_slot"
                 data = dict(data, **{key: self._put_slots(slots)})
         w = jnp.asarray(np.asarray(w_stack, self._dtype))
+        if self._pick_columns:
+            # entity-major pairs: the stream comes to the host and back to
+            # sample order there (the way back on the device, op by op,
+            # held 1.3 GB more device memory at once on a v5e at
+            # glmix_userbag_ml20m's size)
+            stream = np.asarray(score_pairs_full(
+                w, data["lane_slot"], data["x_word"], data["x_val"],
+                self._pick_columns.bit_length()))
+            return (stream if self._em.pos is None
+                    else stream[self._em.pos])[: self._n]
         return np.asarray(self._score_samples_full(
             w, data, table_rows))[: self._n]
 
@@ -2067,10 +2150,11 @@ class RandomEffectCoordinate(Coordinate):
     def _score_samples_full(self, w_stack: Array, data,
                             table_rows: Optional[int] = None) -> Array:
         """Every sample's score in whichever layout the full-sample design
-        has (``data``: what ``sweep_data`` passes of it): sparse (by rows or
-        by blocks of samples, of which one reads ``table_rows`` of the
+        has (``data``: what ``sweep_data`` passes of it): sparse (by rows
+        or by blocks of samples, of which one reads ``table_rows`` of the
         table: this coordinate's own where not given), entity-major,
-        [d, n] or [n, d] (parallel/bucketing.py)."""
+        [d, n] or [n, d] (parallel/bucketing.py); entity-major pairs are
+        scored in ``_score_full`` and ``_score_compact``."""
         from photon_ml_tpu.parallel.bucketing import (
             score_samples, score_samples_em, score_samples_sparse,
             score_samples_sparse_blocks, score_samples_t)
@@ -2167,11 +2251,29 @@ class RandomEffectCoordinate(Coordinate):
             new_lanes.append(res.w)
         if iterations_out is not None:
             iterations_out.append(jnp.stack(iterations))
+        if self._pick_columns:
+            with device_scope("rescore"):
+                score = self._score_compact(new_lanes, data)
+            return tuple(new_lanes), score[: self.carry_samples]
         w_stack = self.trace_publish(tuple(new_lanes), data=data)
         with device_scope("rescore"):
             score = self._score_samples_full(
                 w_stack, data)[: self.carry_samples]
         return tuple(new_lanes), score
+
+    def _score_compact(self, state: Tuple[Array, ...], data) -> Array:
+        """Every sample's score from the lanes as publish back-projects
+        them (original space), each class's padded to D and laid side by
+        side (``bucketing.score_pairs_em``): no full-width table."""
+        from photon_ml_tpu.parallel.bucketing import score_pairs_em
+
+        width = self._pick_columns
+        lanes = jnp.concatenate([
+            jnp.pad(self._lanes_to_original(w, bi, data=data),
+                    ((0, 0), (0, width - w.shape[1])))
+            for bi, w in enumerate(state)])
+        return score_pairs_em(lanes, data["chunk_row"], data["x_word"],
+                              data["x_val"], data["way_back"])
 
     def trace_publish(self, state: Tuple[Array, ...], data=None) -> Array:
         with device_scope("publish"):

@@ -1467,6 +1467,210 @@ def score_samples_sparse_blocks(w_stack: Array, slots: Array, first: Array,
     return jax.lax.map(one, (slots, first, indices, values)).reshape(-1)
 
 
+# A compact coordinate's pairs ENTITY-MAJOR (``score_pairs_em``): a chunk
+# gathers its entity's whole compact row with one index, and each pair picks
+# its coefficient out of that row by its compact place, with no index.  On a
+# v5e at glmix_userbag_ml20m's size (65,536 entities, 9.49M samples in 11.5M
+# slots at C = 64, 16 pairs, D = 64; PERF.md section 6), ms a call
+# with the un-pad (6.0 of it): the sparse gathers by blocks 1,153; a tree of
+# selects on the place's bits 36.1; a compare-select-sum a pair 25.5; a
+# column's values summed over the pairs that pick it, times the column,
+# 23.3 (``_pair_sums``); blocks of 2 to 32 MiB alike, so a block's rows
+# spread along their chunks, ``[D, rows, EM_ROW]``, hold the window lanes'
+# ``WINDOW_BLOCK_BYTES``.  ``PAIR_ROWS``: the samples of a pass of the host's
+# lookup of the places (``pair_words``) and of its transposes.
+PAIR_ROWS = 1 << 14
+
+
+def pair_words(indices: np.ndarray, runs: EntityRuns,
+               lane_entity: np.ndarray, lane_columns: np.ndarray,
+               values: np.ndarray) -> Tuple[np.ndarray, int]:
+    """``(words [k, n + 1] int32, kept_pairs)``: every pair of a sparse
+    shard ``indices`` [n, k] as ``score_pairs_em`` reads it, one word a
+    pair, ``column << bits | q`` (``bits = D.bit_length()``), ``q`` the
+    column's place among its entity's compact columns, or ``D`` where the
+    entity did not keep the column (or has no lane); column ``n`` is
+    padding's word (column 0, ``q = D``).  ``kept_pairs``: the pairs of a
+    nonzero ``values`` whose column their entity kept.
+
+    ``runs``: the shard's ``entity_runs``; ``lane_entity`` [L] each compact
+    lane's entity as an index into ``runs``' entities (-1: a padding lane),
+    ``lane_columns`` [L, D] its columns (-1 behind them).  The places are
+    looked up through a ``[entities, d_full]`` map, the entities' rows
+    taken in their grouped order, ``PAIR_ROWS`` of them a pass (a pass's
+    arrays stay in the host's caches)."""
+    entities, counts, order = runs
+    n, k = indices.shape
+    d_full = int(indices.max(initial=0)) + 1
+    width = lane_columns.shape[1]
+    bits = width.bit_length()
+    if d_full << bits > 1 << 31:
+        raise ValueError(f"{d_full} columns and {width} places do not fit "
+                         "one int32 word a pair")
+    place = (np.int8 if width < 1 << 7 else np.int16 if width < 1 << 15
+             else np.int32)
+    places = np.full(len(entities) * d_full, width, place)
+    has = np.flatnonzero(lane_entity >= 0)
+    lane, q = np.nonzero(lane_columns[has] >= 0)
+    places[lane_entity[has][lane] * d_full
+           + lane_columns[has][lane, q]] = q
+    entity_of = np.repeat(np.arange(len(entities), dtype=np.int32), counts)
+    words = np.empty((k, n + 1), np.int32)
+    words[:, n] = width
+    kept = 0
+    for g0 in range(0, n, PAIR_ROWS):
+        g1 = min(n, g0 + PAIR_ROWS)
+        rows = slice(g0, g1) if order is None else order[g0:g1]
+        idx = indices[rows].astype(np.int32, copy=False)
+        at = np.take(places, (entity_of[g0:g1] * d_full)[:, None] + idx)
+        kept += int(np.count_nonzero((at < width) & (values[rows] != 0)))
+        words[:, rows] = ((idx << bits) | at).T
+    return words, kept
+
+
+def pair_planes(a: np.ndarray, dtype) -> np.ndarray:
+    """A sparse shard's ``[n, k]`` array as ``entity_major_pairs`` takes
+    it: ``[k, n + 1]`` of ``dtype``, a 0 behind the samples for padding,
+    transposed ``PAIR_ROWS`` samples a pass."""
+    n, k = a.shape
+    out = np.zeros((k, n + 1), dtype)
+    for r0 in range(0, n, PAIR_ROWS):
+        out[:, r0:min(n, r0 + PAIR_ROWS)] = a[r0:r0 + PAIR_ROWS].T
+    return out
+
+
+def pick_block(layout: EntityMajorLayout, width: int, itemsize: int) -> int:
+    """The rows of 128 samples in a block of ``score_pairs_em``: those
+    whose chunks' rows of ``width`` coefficients, spread along the chunks,
+    hold ``WINDOW_BLOCK_BYTES``, a multiple of 8 and no more than the
+    layout's rows need."""
+    rows = layout.lanes * layout.chunk // EM_ROW
+    return max(8, min(WINDOW_BLOCK_BYTES // (width * EM_ROW * itemsize),
+                      rows + 7) // 8 * 8)
+
+
+# photonlint: disable=sharding-annotation -- set-up, on one device: the
+# pairs of a shard that has no mesh, the output their only consumer's
+# argument
+@functools.partial(jax.jit, static_argnums=2)
+def _planes_at(planes: Array, src: Array, block: int) -> Array:
+    return jnp.moveaxis(jnp.take(planes, src, axis=1, mode="clip").reshape(
+        planes.shape[0], -1, block, EM_ROW), 1, 0)
+
+
+def entity_major_pairs(layout: EntityMajorLayout, planes: Array,
+                       block: int) -> Array:
+    """A sparse shard's ``[k, n + 1]`` pair planes (``pair_words``, or
+    ``pair_planes`` of its values), on the device in sample order, stored
+    by ``layout`` in BLOCKS of ``block`` rows of 128: ``[blocks, k, block,
+    EM_ROW]``, padding (and the rows behind the layout's last) taking
+    column ``n``.  Block-major, so that ``score_pairs_em``'s loop takes a
+    block as it comes (an operand sliced along another axis inside a loop
+    inside the fit's is copied whole first: 1.5 GB at glmix_userbag_ml20m's
+    size).  ONE gather of whole columns on the device, at set-up, as
+    ``entity_major_design``; returns when ``planes`` is no longer needed."""
+    src = layout.source_rows()
+    rows = len(src) // EM_ROW
+    src = np.pad(src, (0, (-(-rows // block) * block - rows) * EM_ROW),
+                 constant_values=layout.num_samples)
+    return jax.block_until_ready(_planes_at(planes, jnp.asarray(src), block))
+
+
+def _along_chunks(v: Array, k: int) -> Array:
+    """``[k, ..., R]`` a chunk -> ``[..., R, EM_ROW]`` a stored sample: the
+    value of each sample's chunk, spread along the chunk."""
+    chunk_of_lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1,) * (v.ndim - 1) + (EM_ROW,), v.ndim - 1) // (
+            EM_ROW // k)
+    out = v[0][..., None]
+    # photonlint: disable=tracer-safety -- k, the chunks of a row, is a
+    # static Python int
+    for i in range(1, k):
+        out = jnp.where(chunk_of_lane == i, v[i][..., None], out)
+    return out
+
+
+def _by_blocks(one, per_chunk: Array, fill, words: Array,
+               values: Array) -> Array:
+    """``one(chunks, words, values)`` [block, EM_ROW] over the blocks of
+    ``entity_major_pairs``' planes, ONE loop, ``per_chunk`` [k_em, R, ...]
+    cut into the same blocks (``fill`` behind its last row): the flat
+    entity-major stream of ``R x EM_ROW`` scores."""
+    blocks, _, block, _ = words.shape
+    k_em, r = per_chunk.shape[:2]
+    chunks = jnp.pad(per_chunk, ((0, 0), (0, blocks * block - r))
+                     + ((0, 0),) * (per_chunk.ndim - 2), constant_values=fill)
+    chunks = jnp.moveaxis(chunks.reshape((k_em, blocks, block)
+                                         + per_chunk.shape[2:]), 1, 0)
+    acc = jax.lax.map(lambda x: one(*x), (chunks, words, values))
+    return acc.reshape(-1)[: r * EM_ROW]
+
+
+def _pair_sums(rows: Array, q: Array, val: Array) -> Array:
+    """``sum_j val[j] rows[q[j]]`` [r, EM_ROW], ``rows`` [D, r, EM_ROW] the
+    chunks' rows spread along them, ``q`` / ``val`` [k, r, EM_ROW] the
+    pairs' places and values: each column's values summed over the pairs
+    that pick it (a compare and a select a pair and column, no index),
+    times the column.  A place of ``D`` or more adds exactly 0."""
+    places = jnp.arange(rows.shape[0], dtype=q.dtype)[:, None, None]
+    per = jnp.sum(jnp.where(q[:, None] == places, val[:, None], 0.0),
+                  axis=0)                              # [D, r, EM_ROW]
+    return jnp.sum(per * rows, axis=0)
+
+
+def score_pairs_em(lanes: Array, chunk_row: Array, words: Array,
+                   values: Array, way_back: WayBack = None) -> Array:
+    """``score_samples_sparse`` of a COMPACT coordinate, from its compact
+    lanes: ``lanes`` [L, D] every class's lanes side by side (each padded to
+    the widest ``d_proj``, D), ``chunk_row`` [k_em, R] the lane of each
+    chunk's entity (-1: no model, its samples score exactly 0), ``words``
+    / ``values`` [blocks, k, block, EM_ROW] the pairs stored entity-major
+    (``pair_words``, ``entity_major_pairs``), ``way_back`` the layout's
+    (``to_sample_order``).
+
+    Exact where an entity's published row is zero off its compact columns
+    (an index-map projection with no box fill): a chunk gathers its lane's
+    whole row with ONE index, ``k_em x R = n / C`` indices a call where the
+    sparse gathers issue one a pair, and the pairs pick out of it by their
+    places (``_pair_sums``); a pair whose column the entity did not keep
+    adds exactly 0, as the zero it stands for would."""
+    k_em, width = chunk_row.shape[0], lanes.shape[1]
+    has = chunk_row >= 0
+    rows = jnp.where(has[..., None], lanes[jnp.where(has, chunk_row, 0)],
+                     0.0)                              # [k_em, R, D]
+    mask = (1 << width.bit_length()) - 1
+    return to_sample_order(_by_blocks(
+        lambda chunks, q, val: _pair_sums(
+            _along_chunks(jnp.moveaxis(chunks, 2, 1), k_em), q & mask, val),
+        rows, 0.0, words, values), way_back)
+
+
+# photonlint: disable=sharding-annotation -- off the timed path, on one
+# device: a coordinate whose pairs are entity-major has no mesh
+@functools.partial(jax.jit, static_argnums=4)
+def score_pairs_full(w_stack: Array, lane_slot: Array, words: Array,
+                     values: Array, bits: int) -> Array:
+    """``score_samples_sparse`` of ANY table ``w_stack`` [E, d_full] from
+    ``score_pairs_em``'s planes, as the flat entity-major stream of ``R x
+    EM_ROW`` scores (the layout's ``pos`` brings it to sample order):
+    ``lane_slot`` [k_em, R] the table's row of each chunk (-1: none), each
+    pair's column its word's high bits (``bits``: ``pair_words``').  One
+    index a pair: off the timed path (a foreign model, carried
+    entities)."""
+    k_em = lane_slot.shape[0]
+
+    def one(slots, cols, val):
+        has = slots >= 0
+        slot = _along_chunks(jnp.where(has, slots, 0), k_em)
+        acc = jnp.zeros(slot.shape, jnp.promote_types(val.dtype,
+                                                      w_stack.dtype))
+        for j in range(cols.shape[0]):  # k is static and small by contract
+            acc = acc + val[j] * w_stack[slot, cols[j] >> bits]
+        return jnp.where(_along_chunks(has, k_em), acc, 0.0)
+
+    return _by_blocks(one, lane_slot, -1, words, values)
+
+
 def gather_entity_coefficients(
     coeffs: Sequence[Array], buckets: EntityBuckets
 ) -> Dict[int, np.ndarray]:

@@ -89,11 +89,12 @@ def problem(seed=0, users=36, items=12):
     return dict(y=y, uids=uids, iids=iids, idx=idx, val=val, xg=xg, xi=xi)
 
 
-def fit(p, ratio, values=None, dtype=np.float64):
+def fit(p, ratio, values=None, dtype=np.float64, **per_user):
     """The fused fit, the sparse per-user coordinate updated LAST, so that
     the offsets of its solves are the fit's own final scores of the two
     others.  Returns (its coordinate, its published table, every
-    coordinate's scores)."""
+    coordinate's scores).  ``per_user``: fields of that coordinate's
+    configuration in the place of the INDEX_MAP ones."""
     data = GameData(
         y=p["y"],
         features={"g": p["xg"], "i": p["xi"], "u": SparseShard(
@@ -109,8 +110,9 @@ def fit(p, ratio, values=None, dtype=np.float64):
             reg=reg),
         "per-user": RandomEffectConfig(
             random_effect_type="userId", feature_shard="u", solver=solver,
-            reg=reg, active_cap=CAP, projector=ProjectorType.INDEX_MAP,
-            features_to_samples_ratio=ratio, intercept_index=0)}
+            reg=reg, active_cap=CAP, **(per_user or dict(
+                projector=ProjectorType.INDEX_MAP,
+                features_to_samples_ratio=ratio, intercept_index=0)))}
     coords = {cid: build_coordinate(cid, data, conf,
                                     TaskType.LOGISTIC_REGRESSION, dtype=dtype)
               for cid, conf in confs.items()}
@@ -300,28 +302,207 @@ def test_the_blocks_follow_the_slots(monkeypatch):
     assert bucketing.sample_blocks(shuffled, 400) == 1
 
 
-def test_a_coordinate_over_the_footprint_line_stores_its_pairs_by_blocks(
+def own_scores(p, coord, table):
+    """Every row's score by the published table, row by row."""
+    slots = np.asarray([coord._slot_of.get(int(e), -1) for e in p["uids"]])
+    got = np.einsum("nk,nk->n", table[np.maximum(slots, 0)[:, None],
+                                      p["idx"]],
+                    p["val"].astype(np.float64))
+    return np.where(slots >= 0, got, 0.0)
+
+
+def turned(coord, table):
+    """The coordinate's model, and the same model under another slot map."""
+    import dataclasses
+
+    model = coord.export_model(table)
+    last = len(model.slot_of) - 1
+    return model, dataclasses.replace(
+        model, w_stack=model.w_stack[::-1].copy(),
+        slot_of={e: last - s for e, s in model.slot_of.items()})
+
+
+def test_a_coordinate_over_the_footprint_line_scores_its_pairs_entity_major(
         data, monkeypatch):
+    """Over the line a compact coordinate stores its pairs entity-major and
+    scores them from its compact lanes: the fit is the blocked gather's."""
+    from photon_ml_tpu.game.coordinate import RandomEffectCoordinate
+
     monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 1 << 16)
     monkeypatch.setattr(bucketing, "SPARSE_TABLE_BYTES_MAX", 12 * DIM * 8)
     coord, table, scores = fit(data, 0.25)
-    assert coord._pair_blocks == 4 and coord._table_rows <= 12
-    assert coord._full["x_idx"].shape == (4, WIDTH, -(-len(data["y"]) // 4))
-    # a model under another slot map scores by what ITS slots span
-    model = coord.export_model(table)
-    turned = {e: len(model.slot_of) - 1 - s for e, s in model.slot_of.items()}
-    import dataclasses
-    other = dataclasses.replace(model, w_stack=model.w_stack[::-1].copy(),
-                                slot_of=turned)
-    np.testing.assert_allclose(coord.score(other), coord.score(model),
-                               rtol=0, atol=1e-12)
-    monkeypatch.undo()
-    plain, table_0, scores_0 = fit(data, 0.25)
-    assert plain._pair_blocks == 0
+    em = coord._em
+    assert coord._pick_columns == 16 and coord._pair_blocks == 0
+    assert em is not None and em.back == "unpad"
+    rows = em.lanes * em.chunk // bucketing.EM_ROW
+    block = bucketing.pick_block(em, 16, 8)
+    assert block == -(-rows // 8) * 8       # the layout's rows, one block
+    assert coord._full["x_word"].shape == (1, WIDTH, block,
+                                           bucketing.EM_ROW)
+    assert coord._full["chunk_row"].shape == em.chunk_entity.shape
+    # the parent's path on the same data: the pairs by blocks of samples
+    monkeypatch.setattr(RandomEffectCoordinate, "_compact_pick_columns",
+                        lambda self: 0)
+    blocks, table_0, scores_0 = fit(data, 0.25)
+    assert blocks._pair_blocks == 4 and blocks._pick_columns == 0
     np.testing.assert_allclose(table, table_0, rtol=0, atol=1e-9)
-    np.testing.assert_allclose(scores[2], scores_0[2], rtol=0, atol=1e-8)
+    for got, want in zip(scores, scores_0):
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-8)
+    np.testing.assert_allclose(scores[2], own_scores(data, coord, table),
+                               rtol=0, atol=1e-12)
+    # a full-width table, under its own slot map or another, off the path
+    model, other = turned(coord, table)
     np.testing.assert_allclose(coord.score(model), scores_0[2], rtol=0,
                                atol=1e-8)
+    np.testing.assert_allclose(coord.score(other), coord.score(model),
+                               rtol=0, atol=1e-12)
+    np.testing.assert_allclose(blocks.score(other), coord.score(model),
+                               rtol=0, atol=1e-8)
+
+
+def _few_rows(p, most=4):
+    """The problem cut to at most ``most`` rows a user: no chunk length
+    pads within ``EM_PAD_MAX``."""
+    rank = np.arange(len(p["uids"])) - np.searchsorted(p["uids"], p["uids"])
+    keep = rank < most
+    return {key: v[keep] for key, v in p.items()}
+
+
+@pytest.mark.parametrize("case", ["random_projector", "box_fill",
+                                  "no_chunk"])
+def test_what_cannot_be_picked_keeps_the_blocks(data, monkeypatch, case):
+    """A RANDOM projection, a box whose fill publishes off the compact
+    columns, rows with no chunk length: the pairs by blocks of samples,
+    every row scored by the published table."""
+    monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 1 << 12)
+    p, per_user = data, {}
+    if case == "random_projector":
+        per_user = dict(projector=ProjectorType.RANDOM, projected_dim=8,
+                        intercept_index=0)
+    elif case == "box_fill":
+        per_user = dict(projector=ProjectorType.INDEX_MAP,
+                        features_to_samples_ratio=0.25, intercept_index=0,
+                        constraints=((5, 0.1, 1.0),))
+    else:
+        p = _few_rows(data)
+    coord, table, scores = fit(p, 0.25, **per_user)
+    assert coord._pick_columns == 0 and coord._em is None
+    assert coord._pair_blocks >= 1
+    if case == "box_fill":
+        assert np.all(table[:, 5] >= 0.1)   # published off the columns
+    np.testing.assert_allclose(scores[2], own_scores(p, coord, table),
+                               rtol=0, atol=1e-9)
+
+
+def compact_lanes(p, rng, widths=(1, 2, 4, 8, 16)):
+    """Compact lanes as a coordinate lays them side by side: classes of the
+    given widths, each entity keeping a random part of its columns, one
+    entity with no lane.  Returns (lane_entity, lane_columns, lanes, the
+    published table [users, DIM])."""
+    users = int(p["uids"].max()) + 1
+    width = max(widths)
+    lane_entity, lane_columns, lanes = [], [], []
+    table = np.zeros((users, DIM), np.float32)
+    for e in range(users):
+        if e == 7:
+            continue
+        seen = np.unique(p["idx"][p["uids"] == e])
+        d = widths[e % len(widths)]
+        kept = rng.choice(seen, min(d, len(seen)), replace=False)
+        cols = np.full(width, -1, np.int32)
+        cols[:len(kept)] = kept
+        w = np.zeros(width, np.float32)
+        w[:len(kept)] = rng.normal(size=len(kept))
+        table[e, kept] = w[:len(kept)]
+        lane_entity.append(e)
+        lane_columns.append(cols)
+        lanes.append(w)
+    order = rng.permutation(len(lanes))        # classes, not entities
+    return (np.asarray(lane_entity)[order], np.stack(lane_columns)[order],
+            np.stack(lanes)[order], table)
+
+
+@pytest.mark.parametrize("rows", ["grouped", "shuffled", "whole_chunks"])
+def test_pairs_entity_major_score_as_row_major_ones(data, rows):
+    """``score_pairs_em`` from compact lanes and ``score_pairs_full`` from
+    a full-width table, against ``score_samples_sparse``: pairs of columns
+    the entity did not keep, an entity with no model, padding pairs and
+    padding slots, every class width, users of one chunk and of many."""
+    rng = np.random.default_rng(11)
+    p = data
+    if rows == "whole_chunks":   # 64 rows a user: the chunks ARE the order
+        take = np.concatenate([np.resize(np.flatnonzero(data["uids"] == e),
+                                         64) for e in range(36)])
+        p = {key: v[take] for key, v in data.items()}
+    ids = p["uids"] * 3
+    if rows == "shuffled":
+        ids = rng.permutation(ids)
+    runs = bucketing.entity_runs(ids)
+    em = bucketing.entity_major_layout(runs)
+    assert em.back == {"grouped": "unpad", "shuffled": "gather",
+                       "whole_chunks": "identity"}[rows]
+    pp = dict(p, uids=ids // 3)
+    lane_entity, lane_columns, lanes, table = compact_lanes(pp, rng)
+    words, kept = bucketing.pair_words(pp["idx"], runs, lane_entity,
+                                       lane_columns, pp["val"])
+    want_kept = sum(int(np.count_nonzero(
+        np.isin(pp["idx"][pp["uids"] == e], c[c >= 0])
+        & (pp["val"][pp["uids"] == e] != 0)))
+        for e, c in zip(lane_entity, lane_columns))
+    assert kept == want_kept
+    n, k = pp["idx"].shape
+    values = bucketing.pair_planes(pp["val"], np.float32)
+    np.testing.assert_array_equal(values, np.c_[pp["val"].T, np.zeros(k)])
+    way_back = jax.tree.map(jnp.asarray, em.way_back())
+    row_of = np.full(len(em.entities), -1, np.int32)
+    row_of[lane_entity] = np.arange(len(lane_entity))
+    slots = np.where(np.isin(pp["uids"], lane_entity), pp["uids"], -1)
+    want = np.asarray(bucketing.score_samples_sparse(
+        jnp.asarray(table), jnp.asarray(slots), jnp.asarray(pp["idx"]),
+        jnp.asarray(pp["val"])))
+    # a full-width table under another slot map
+    order = rng.permutation(36)
+    slot_of = np.where(np.arange(36) == 7, -1, order).astype(np.int32)
+    foreign = np.zeros_like(table)
+    foreign[order] = table
+    # one block and the rows' tail, or blocks of 8 rows and a padded tail
+    for block in (bucketing.pick_block(em, 16, 4), 8):
+        x_word = bucketing.entity_major_pairs(em, jnp.asarray(words), block)
+        x_val = bucketing.entity_major_pairs(em, jnp.asarray(values), block)
+        assert x_word.shape[1:] == (k, block, bucketing.EM_ROW)
+        got = np.asarray(jax.jit(bucketing.score_pairs_em)(
+            jnp.asarray(lanes), jnp.asarray(em.lane_slots(row_of)),
+            x_word, x_val, way_back))[:n]
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+        assert np.all(got[slots < 0] == 0.0)
+        stream = np.asarray(bucketing.score_pairs_full(
+            jnp.asarray(foreign), jnp.asarray(em.lane_slots(slot_of)),
+            x_word, x_val, lane_columns.shape[1].bit_length()))
+        assert stream.shape == (em.lanes * em.chunk,)
+        full = stream if em.pos is None else stream[em.pos]
+        np.testing.assert_allclose(full[:n], want, rtol=1e-6, atol=1e-6)
+
+
+def test_pair_words_by_passes_are_each_pairs_place(data, monkeypatch):
+    """The places looked up ``PAIR_ROWS`` samples at a time are each
+    pair's place among its entity's compact columns, pair by pair."""
+    rng = np.random.default_rng(2)
+    runs = bucketing.entity_runs(data["uids"])
+    lane_entity, lane_columns = compact_lanes(data, rng)[:2]
+    args = (data["idx"], runs, lane_entity, lane_columns, data["val"])
+    whole = bucketing.pair_words(*args)
+    monkeypatch.setattr(bucketing, "PAIR_ROWS", 37)
+    parts = bucketing.pair_words(*args)
+    assert parts[1] == whole[1]
+    np.testing.assert_array_equal(parts[0], whole[0])
+    assert np.all(whole[0][:, -1] == 16)       # padding: column 0, q = D
+    assert np.all(whole[0][:, :-1] >> 5 == data["idx"].T)
+    lane_of = dict(zip(runs[0][lane_entity].tolist(), lane_columns))
+    for i, e in enumerate(data["uids"]):
+        cols = lane_of.get(int(e), np.empty(0, np.int32))
+        for j, c in enumerate(data["idx"][i]):
+            q = np.flatnonzero(cols == c)
+            assert whole[0][j, i] & 31 == (q[0] if q.size else 16)
 
 
 # -- the compaction, all lanes at once, against the loop an entity ----------
@@ -410,11 +591,59 @@ def test_the_spans_say_what_the_compaction_made(data):
     assert layout["layout"] == "sparse" and layout["row_width"] == WIDTH
     assert layout["nonzeros"] == int(np.count_nonzero(data["val"]))
     assert layout["blocks"] == 0 and layout["table_rows"] is None
+    assert layout["pairs"] == "rows"
     paths = set(tables["jit_program"].values())
     assert any("photon.update.per_user/photon.publish/photon.backproject"
                in p for p in paths)
     assert any("photon.update.per_user/photon.rescore" in p for p in paths)
     assert not any("backproject" in p and "per_item" in p for p in paths)
+
+
+def _layout_span(data, **per_user):
+    from photon_ml_tpu import obs
+
+    obs.enable_tracing(capacity=1 << 12)
+    try:
+        coord, _, _ = fit(data, 0.25, **per_user)
+        spans = {r["name"]: r["attrs"] for r in obs.get_tracer().records()
+                 if r["ph"] == "X" and r["attrs"].get("coordinate")
+                 == "per-user"}
+        paths = set(obs.get_tracer().device_tables()["jit_program"].values())
+    finally:
+        obs.disable_tracing()
+    return coord, spans["coord.rescore_layout"], paths
+
+
+def test_the_span_says_which_pairs_engaged(data, monkeypatch):
+    """``pairs = entity_major`` and its fields on the compact coordinate,
+    ``pairs = blocks`` on a RANDOM projection; the layout stays ``sparse``
+    and says its ``row_width``, as the benchmark's readers find it."""
+    monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 1 << 16)
+    coord, layout, paths = _layout_span(data)
+    em = coord._em
+    kept = 0
+    for e, (bi, lane) in coord.buckets.lane_of.items():
+        cols = coord._proj.projections[bi].indices[lane]
+        mine = data["uids"] == e
+        kept += int(np.count_nonzero(np.isin(data["idx"][mine], cols[cols >= 0])
+                                     & (data["val"][mine] != 0)))
+    assert layout == dict(
+        coordinate="per-user", layout="sparse", pairs="entity_major",
+        chunk=em.chunk, lanes=em.lanes, fill=em.fill, back="unpad",
+        stages=coord._full["way_back"].stages, slots=em.lanes * em.chunk,
+        blocks=0, table_rows=None, row_width=WIDTH,
+        nonzeros=int(np.count_nonzero(data["val"])), pick_columns=16,
+        kept_pairs=kept)
+    assert 0 < kept < layout["nonzeros"]
+    # the rescore reads no published table: no back-projection in the loop
+    assert any("photon.update.per_user/photon.rescore" in p for p in paths)
+    assert not any("photon.update.per_user/photon.publish" in p
+                   for p in paths)
+    _, layout, _ = _layout_span(data, projector=ProjectorType.RANDOM,
+                                projected_dim=8, intercept_index=0)
+    assert layout["layout"] == "sparse" and layout["pairs"] == "blocks"
+    assert layout["row_width"] == WIDTH and layout["blocks"] >= 1
+    assert "pick_columns" not in layout
 
 
 # -- the dense coordinates' programs stay what they were ----------------------
@@ -442,10 +671,30 @@ def parents_score_samples_full(self, w_stack, data):
     return score(w_stack, data["slots"], data["x_full"])
 
 
+def parents_trace_score_external(self, published, vdata):
+    """The parent's ``RandomEffectCoordinate.trace_score_external``."""
+    from photon_ml_tpu.parallel.bucketing import (score_samples,
+                                                  score_samples_em,
+                                                  score_samples_sparse,
+                                                  score_samples_t)
+
+    if "x_em" in vdata:
+        return score_samples_em(published, vdata["lane_slot"],
+                                vdata["x_em"], vdata["way_back"])
+    if "x_t" in vdata:
+        return score_samples_t(published, vdata["slots"], vdata["x_t"])
+    if "x" in vdata:
+        return score_samples(published, vdata["slots"], vdata["x"])
+    return score_samples_sparse(published, vdata["slots"],
+                                vdata["x_idx"], vdata["x_val"])
+
+
 @pytest.mark.parametrize("narrow", [False, True],
                          ids=["row_major", "entity_major"])
 def test_a_dense_random_effect_traces_as_it_did(data, narrow, monkeypatch):
-    """(g): the jaxpr of a dense random effect's update is the parent's."""
+    """(g): the jaxprs of a dense random effect's update and of its
+    held-out scoring (``trace_score_external`` over ``external_data``, the
+    validated program's) are the parent's."""
     if narrow:
         monkeypatch.setattr(bucketing, "NARROW_SCORE_PAD_BYTES_MIN", 1 << 16)
     game = GameData(y=data["y"], features={"i": data["xi"]},
@@ -463,10 +712,25 @@ def test_a_dense_random_effect_traces_as_it_did(data, narrow, monkeypatch):
     def update(state, offsets):
         return coord.trace_update(state, offsets, data=coord.sweep_data())
 
+    # the held-out path of a validated fit: the rows of every other user
+    held = np.flatnonzero(data["uids"] % 2 == 0)
+    vdata = coord.external_data(GameData(
+        y=data["y"][held], features={"i": data["xi"][held]},
+        id_tags={"userId": data["uids"][held]}))
+    published = coord.trace_publish(state)
+
+    def heldout(published, vdata):
+        return coord.trace_score_external(published, vdata)
+
     ours = jax.make_jaxpr(update)(state, offsets)
+    ours_held = jax.make_jaxpr(heldout)(published, vdata)
     monkeypatch.setattr(type(coord), "_trace_publish", parents_trace_publish)
     monkeypatch.setattr(type(coord), "_score_samples_full",
                         parents_score_samples_full)
+    monkeypatch.setattr(type(coord), "trace_score_external",
+                        parents_trace_score_external)
     parents = jax.make_jaxpr(update)(state, offsets)
     assert str(ours) == str(parents)
+    assert str(ours_held) == str(jax.make_jaxpr(heldout)(published, vdata))
+    assert ("x_em" in vdata) == narrow
     assert "backproject" not in str(ours)
