@@ -1206,8 +1206,11 @@ class RandomEffectCoordinate(Coordinate):
                     self._slot_ids = self._em.entities
                     way_back = self._em.way_back(
                         lane_multiple, self.carry_samples // lane_multiple)
+                    # coef_indices: what the scorer gathers a call, ONE
+                    # row of the table a chunk
                     layout_span.set(layout="entity_major", chunk=self._em.chunk,
                                     lanes=self._em.lanes, fill=self._em.fill,
+                                    coef_indices=self._em.lanes,
                                     **_way_back_says(self._em, way_back))
                 else:
                     self._x_full_is_t = narrow

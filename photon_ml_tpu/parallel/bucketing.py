@@ -773,7 +773,9 @@ NARROW_SCORE_DIM_MAX = 32  # the narrow layouts only ever help below this width
 #     scoring HLO, an OOM on a 16 GB chip), the design is stored with the
 #     samples on the lanes.  ENTITY-MAJOR (``entity_major_layout`` +
 #     ``score_samples_em``) where the rows of an entity fill chunks of C rows
-#     within 1.3x padding: a coefficient is gathered once per chunk.
+#     within 1.3x padding: the entity's coefficient ROW is gathered once per
+#     chunk, one index for all d columns, where a gather a column cost d x
+#     n / C indices (PERF.md section 6).
 #   - TRANSPOSED [d, n] in sample order (``score_samples_t``) where no C
 #     exists (fewer than ~30 rows an entity): a coefficient is gathered once
 #     per row and column.  The chip showed what that costs (PERF.md section 6,
@@ -817,7 +819,7 @@ def score_samples_t(w_stack: Array, slots: Array, x_t: Array) -> Array:
     coefficient columns, and no padded [n, d] array ever exists.  Each of
     the d gathers has n indices, and on the v5e the indices are the cost
     (7.4 ns each where PR 23 measured them, up to 28.9): ``score_samples_em``
-    issues n / C of them a column.
+    issues n / C of them in all, one ROW of d a chunk.
     """
     safe = jnp.where(slots >= 0, slots, 0)
     w_t = w_stack.T  # [d, E]: entities on lanes, tiny either way
@@ -1333,30 +1335,25 @@ def score_samples_em(w_stack: Array, lane_slot: Array, x_em: Array,
     samples, then the tail's zeros).
 
     The same d products a sample as ``score_samples_t``, summed in the same
-    order in the same promoted dtype; but a coefficient is gathered once per
-    CHUNK (k x R = n / C indices a column, not n) and spread along the chunk
-    in registers; the finished scores go back into sample order by an
-    un-pad where the rows arrive grouped by entity, by ONE n-sized gather
-    where they lie anywhere.
+    order in the same promoted dtype; but each chunk gathers its entity's
+    coefficient ROW once (ONE gather of k x R = n / C indices, where
+    ``score_samples_t`` issues d gathers of n), and each column of the row
+    is spread along the chunk (``_along_chunks``); the finished scores go
+    back into sample order by an un-pad where the rows arrive grouped by
+    entity, by ONE n-sized gather where they lie anywhere.
     """
-    k, _ = lane_slot.shape
+    k = lane_slot.shape[0]
     has = lane_slot >= 0
-    safe = jnp.where(has, lane_slot, 0)
-    w_t = w_stack.T  # [d, E]
-    chunk_of_lane = jax.lax.broadcasted_iota(
-        jnp.int32, (1, EM_ROW), 1) // (EM_ROW // k)
-
-    def along_chunks(v):  # [k, R] a chunk -> [R, EM_ROW] (or [R, 1]) a sample
-        out = v[0][:, None]
-        for i in range(1, k):
-            out = jnp.where(chunk_of_lane == i, v[i][:, None], out)
-        return out
-
+    # ONE gather, a row a chunk, then the block [d, k, R] with the chunks on
+    # the lanes: a column sliced out of [k, R, d] is an array of its own
+    # padded to 128 lanes on a TPU (3.6 ms more a call at 118,528 chunks:
+    # PERF.md section 6)
+    cols = jnp.moveaxis(w_stack[jnp.where(has, lane_slot, 0)], -1, 0)
     acc = jnp.zeros(x_em.shape[1:],
                     jnp.promote_types(x_em.dtype, w_stack.dtype))
     for j in range(x_em.shape[0]):  # d is static and small by contract
-        acc = acc + x_em[j] * along_chunks(w_t[j][safe])
-    acc = jnp.where(along_chunks(has), acc, 0.0).reshape(-1)
+        acc = acc + x_em[j] * _along_chunks(cols[j], k)
+    acc = jnp.where(_along_chunks(has, k), acc, 0.0).reshape(-1)
     return to_sample_order(acc, way_back)
 
 

@@ -214,18 +214,18 @@ def test_traced_sweep_records_its_table(cells, config):
 # -- (ii-b) the entity-major rescore: one gather a chunk, one a sample ----------
 
 @pytest.mark.parametrize("config, want", [
-    # (n-sized gathers, chunk gathers a column) per random-effect scope
-    ("glmix_chip", {"per_user": (0, 4 * [384])}),        # rows entity-major
-    ("glmix3_wide", {"per_user": (1, 16 * [96]),         # rows anywhere
-                     "per_item": (1, 16 * [60])}),
+    # (n-sized gathers, entries of the row gather: chunks x d) a scope
+    ("glmix_chip", {"per_user": (0, [384 * 4])}),        # rows entity-major
+    ("glmix3_wide", {"per_user": (1, [96 * 16]),         # rows anywhere
+                     "per_item": (1, [60 * 16])}),
 ])
 def test_entity_major_rescore_gathers(monkeypatch, config, want):
     """The dry-run sizes sit under the padded-footprint line, so lower it
     here (the program has no knob for it): each random-effect coordinate
     then rescores from the entity-major layout, and its own executable
-    holds, under ``photon.update.<cid>/photon.rescore``, d gathers of one
-    entry a CHUNK and at most ONE gather of n entries, none where the rows
-    arrive entity-major."""
+    holds, under ``photon.update.<cid>/photon.rescore``, ONE gather of a
+    coefficient row (d entries) a CHUNK and at most ONE gather of n
+    entries, none where the rows arrive entity-major."""
     import re
 
     from photon_ml_tpu.parallel import bucketing

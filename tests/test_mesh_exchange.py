@@ -560,6 +560,29 @@ def test_scores_come_back_by_unpad_under_the_mesh(on_mesh):
                    for line in scoped)  # the exchange's one collective
 
 
+def test_the_span_counts_one_coefficient_index_a_chunk(on_mesh):
+    """``coord.rescore_layout``'s ``coef_indices``: the coefficient indices
+    the entity-major scorer gathers a call, ONE row of the table a chunk,
+    so the layout's lanes, under the mesh (every chip's chunks together)
+    and on one device.  The two paths' scores are bitwise equal
+    (``test_scores_come_back_by_unpad_under_the_mesh``)."""
+    data, coords, _, _, records, _ = on_mesh
+    prev = set_tracer(Tracer(capacity=1024, enabled=True))
+    try:
+        alone = build(data, "entity_major", None, dtype=np.float32)
+        one = spans_named(obs.get_tracer().records(), "coord.rescore_layout")
+    finally:
+        set_tracer(prev)
+    for spans, built in ((spans_named(records, "coord.rescore_layout"),
+                          coords), (one, alone)):
+        em = {a["coordinate"]: a for a in spans
+              if a["layout"] == "entity_major"}
+        assert sorted(em) == ["per-item", "per-user"]
+        for cid, a in em.items():
+            assert a["coef_indices"] == a["lanes"] == built[cid]._em.lanes
+            assert built[cid]._full["lane_slot"].size == a["lanes"]
+
+
 # -- (c) tracing ---------------------------------------------------------------
 
 def test_exchange_scopes_are_in_the_op_table(on_mesh):

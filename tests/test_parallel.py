@@ -464,13 +464,14 @@ def test_entity_major_carry_through_scores(rng, monkeypatch):
 
 @pytest.mark.parametrize("line, per_user, shuffled, layout", [
     (1, 32, False, dict(layout="entity_major", chunk=32, lanes=12, fill=1.0,
-                        back="identity")),
+                        coef_indices=12, back="identity")),
     # 30 rows a user in chunks of 32: grouped rows, two slots of padding
     # behind each user, 22 in front of the last: five stages
     (1, 30, False, dict(layout="entity_major", chunk=32, lanes=12,
-                        fill=0.9375, back="unpad", stages=5, slots=384)),
+                        fill=0.9375, coef_indices=12, back="unpad", stages=5,
+                        slots=384)),
     (1, 32, True, dict(layout="entity_major", chunk=32, lanes=12, fill=1.0,
-                       back="gather")),
+                       coef_indices=12, back="gather")),
     (1, 10, False, dict(layout="transposed")),  # 10 rows a user: no chunk
     (None, 32, False, dict(layout="row_major")),
 ], ids=["identity", "unpad", "gather", "transposed", "row_major"])
@@ -478,8 +479,10 @@ def test_rescore_layout_span_says_what_engaged(rng, monkeypatch, line,
                                                per_user, shuffled, layout):
     """``coord.rescore_layout`` (inside ``coord.bucket``): which of the
     three dense layouts the coordinate took, and the entity-major one's
-    chunk, lanes, fill, and the way BACK to the sample order (``back``;
-    un-padded, the compaction's stages and slots)."""
+    chunk, lanes, fill, the coefficient indices its scorer gathers a call
+    (``coef_indices``: one row a chunk, so the lanes), and the way BACK to
+    the sample order (``back``; un-padded, the compaction's stages and
+    slots)."""
     from photon_ml_tpu import obs
     from photon_ml_tpu.game import GameData, RandomEffectConfig
     from photon_ml_tpu.game.coordinate import build_coordinate
@@ -514,6 +517,67 @@ def test_rescore_layout_span_says_what_engaged(rng, monkeypatch, line,
         assert coord._em.back == layout["back"]
         assert isinstance(coord._full["way_back"], bucketing.Unpad) == (
             layout["back"] == "unpad")
+
+
+def _parents_score_samples_em(w_stack, lane_slot, x_em):
+    """The column form ``score_samples_em`` replaced: d gathers of one
+    coefficient a chunk, each out of a column of the table."""
+    from photon_ml_tpu.parallel.bucketing import EM_ROW
+
+    k = lane_slot.shape[0]
+    has = lane_slot >= 0
+    safe = jnp.where(has, lane_slot, 0)
+    w_t = w_stack.T
+    chunk_of_lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1, EM_ROW), 1) // (EM_ROW // k)
+
+    def along_chunks(v):
+        out = v[0][:, None]
+        for i in range(1, k):
+            out = jnp.where(chunk_of_lane == i, v[i][:, None], out)
+        return out
+
+    acc = jnp.zeros(x_em.shape[1:],
+                    jnp.promote_types(x_em.dtype, w_stack.dtype))
+    for j in range(x_em.shape[0]):
+        acc = acc + x_em[j] * along_chunks(w_t[j][safe])
+    return jnp.where(along_chunks(has), acc, 0.0).reshape(-1)
+
+
+def _gathers(jaxpr):
+    """Every ``gather`` equation of a closed jaxpr, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _gathers(sub)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunk", [16, 64, 128])
+@pytest.mark.parametrize("d", [1, 4, 16])
+def test_score_samples_em_gathers_one_row_a_chunk(d, chunk, dtype):
+    """``score_samples_em`` issues ONE gather of the coefficient table a
+    call, a ROW of d a chunk (k x R indices), not d gathers of a column;
+    the scores are bitwise the column form's."""
+    from photon_ml_tpu.parallel.bucketing import EM_ROW, score_samples_em
+
+    k, r, entities = EM_ROW // chunk, 3, 5
+    rng = np.random.default_rng(d * chunk)
+    w = jnp.asarray(rng.normal(size=(entities, d)), dtype)
+    lane_slot = jnp.asarray(rng.integers(-1, entities, size=(k, r)),
+                            jnp.int32)
+    x_em = jnp.asarray(rng.normal(size=(d, r, EM_ROW)), dtype)
+    closed = jax.make_jaxpr(score_samples_em)(w, lane_slot, x_em)
+    (gather,) = _gathers(closed.jaxpr)
+    assert gather.invars[0] is closed.jaxpr.invars[0]  # the table itself
+    assert gather.outvars[0].aval.shape == (k, r, d)
+    if d == 4 and chunk == 64:  # glmix_chip's shape
+        got, want = jax.jit(lambda *a: (score_samples_em(*a),
+                                        _parents_score_samples_em(*a)))(
+            w, lane_slot, x_em)
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint8),
+                                      np.asarray(want).view(np.uint8))
 
 
 def test_scoring_unknown_entity_is_zero(rng):

@@ -629,8 +629,9 @@ def test_the_span_says_which_pairs_engaged(data, monkeypatch):
                                      & (data["val"][mine] != 0)))
     assert layout == dict(
         coordinate="per-user", layout="sparse", pairs="entity_major",
-        chunk=em.chunk, lanes=em.lanes, fill=em.fill, back="unpad",
-        stages=coord._full["way_back"].stages, slots=em.lanes * em.chunk,
+        chunk=em.chunk, lanes=em.lanes, fill=em.fill, coef_indices=em.lanes,
+        back="unpad", stages=coord._full["way_back"].stages,
+        slots=em.lanes * em.chunk,
         blocks=0, table_rows=None, row_width=WIDTH,
         nonzeros=int(np.count_nonzero(data["val"])), pick_columns=16,
         kept_pairs=kept)
